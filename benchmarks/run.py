@@ -59,6 +59,9 @@ def main(argv=None) -> None:
     if "--smoke" in argv:
         os.environ["REPRO_BENCH_SMOKE"] = "1"
 
+    from repro import compile_cache
+    compile_cache.enable()
+
     from . import (bench_cosine, bench_embed_error, bench_frontend,
                    bench_hash_throughput, bench_index,
                    bench_ingest_durability, bench_inplace_ingest, bench_l2,

@@ -1,12 +1,8 @@
-"""Version-portability shims for jax APIs that moved between releases.
+"""The two mesh/SPMD entry points every sharded code path goes through.
 
-The SPMD code was written against the current jax surface (``jax.shard_map``
-with ``check_vma``, ``jax.make_mesh(..., axis_types=...)``); older runtimes
-(<= 0.4.x) expose the same machinery as ``jax.experimental.shard_map``
-(``check_rep``) and ``jax.make_mesh`` without axis types.  Routing every
-call through this module keeps one code path working on both -- use
-``repro.compat.shard_map`` / ``repro.compat.make_mesh`` instead of the jax
-names anywhere mesh/SPMD code runs.
+``make_mesh`` builds meshes with Auto axis types; ``shard_map`` is
+``jax.shard_map`` with the replication checker on by default.  Keeping the
+calls here gives the SPMD code one place that fixes these defaults.
 """
 
 from __future__ import annotations
@@ -18,24 +14,14 @@ import jax
 
 def make_mesh(axis_shapes: Sequence[int], axis_names: Sequence[str],
               **kwargs) -> jax.sharding.Mesh:
-    """``jax.make_mesh`` with Auto axis types where supported."""
-    if hasattr(jax.sharding, "AxisType"):
-        kwargs.setdefault(
-            "axis_types", (jax.sharding.AxisType.Auto,) * len(axis_names))
-        return jax.make_mesh(axis_shapes, axis_names, **kwargs)
+    """``jax.make_mesh`` with Auto axis types."""
+    kwargs.setdefault("axis_types",
+                      (jax.sharding.AxisType.Auto,) * len(axis_names))
     return jax.make_mesh(axis_shapes, axis_names, **kwargs)
 
 
 def shard_map(f, mesh, in_specs: Any, out_specs: Any,
               check_vma: bool = True):
-    """``jax.shard_map`` (new) / ``jax.experimental.shard_map`` (old).
-
-    The replication-checker flag renamed check_rep -> check_vma; callers use
-    the new name.
-    """
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=check_vma)
+    """``jax.shard_map`` over ``mesh``."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma)

@@ -21,7 +21,6 @@ palette" for the closed set and the knobs that pick it.
 from __future__ import annotations
 
 import functools
-import warnings
 
 import jax
 import jax.numpy as jnp
@@ -177,6 +176,16 @@ def candidate_distances(q, emb, ids, p: float = 2.0, use_kernel: bool = True,
 # -- fused gather + rerank + top-k (the query-engine hot path) --------------
 
 
+def _check_topk_width(op: str, k: int, mode: str) -> None:
+    """The kernels keep top-k in ``_FUSED_TOPK_WIDTH`` lanes; a wider k is
+    refused rather than quietly served by the reference path."""
+    if mode != "reference" and k > _FUSED_TOPK_WIDTH:
+        raise ValueError(
+            f"{op}: k={k} exceeds the kernel's {_FUSED_TOPK_WIDTH}-lane "
+            f"top-k scratch (mode {mode!r}); pass backend='reference' for "
+            "the memory-bound jnp path")
+
+
 @functools.partial(jax.jit, static_argnames=("k", "p", "valid_items", "mode"))
 def _fused_query_impl(q, db, ids, k, p, valid_items, mode):
     if mode == "reference":
@@ -206,17 +215,12 @@ def fused_query_topk(q, db, ids, k: int, p: float = 2.0,
         (dists (nq, k) f32 ascending, ids (nq, k) int32), -1/inf padded
         where fewer than k valid candidates exist.
 
-    The kernel's top-k scratch is ``fused_query._KP`` lanes wide; larger k
-    falls back to the reference path (with a warning -- it reintroduces the
-    HBM gather).
+    The kernel's top-k scratch is ``fused_query._KP`` lanes wide; a larger
+    k raises in the kernel modes -- ask for ``backend="reference"`` (the
+    memory-bound HBM-gather path) explicitly.
     """
     mode = dispatch.query_backend(backend)
-    if mode != "reference" and k > _FUSED_TOPK_WIDTH:
-        warnings.warn(
-            f"fused_query_topk: k={k} exceeds the kernel's "
-            f"{_FUSED_TOPK_WIDTH}-lane top-k scratch; falling back to the "
-            "memory-bound reference path", stacklevel=2)
-        mode = "reference"
+    _check_topk_width("fused_query_topk", k, mode)
     return _fused_query_impl(q, db, ids, k, p, valid_items, mode)
 
 
@@ -248,12 +252,7 @@ def quantized_query_topk(q, codes, scale, ids, k: int, p: float = 2.0,
     § "The precision tier".
     """
     mode = dispatch.query_backend(backend)
-    if mode != "reference" and k > _FUSED_TOPK_WIDTH:
-        warnings.warn(
-            f"quantized_query_topk: k={k} exceeds the kernel's "
-            f"{_FUSED_TOPK_WIDTH}-lane top-k scratch; falling back to the "
-            "memory-bound reference path", stacklevel=2)
-        mode = "reference"
+    _check_topk_width("quantized_query_topk", k, mode)
     return _quantized_query_impl(q, codes, scale, ids, k, p, valid_items,
                                  mode)
 

@@ -19,10 +19,12 @@ is the exact ordering up to O(scale) distance ties -- which is why the
 serve layer treats the quantized top-m only as a *survivor set* and
 rescores it exactly from fp32 rows (:func:`rerank_survivors`).
 
-Like fused_query.py, the Pallas variant gathers one candidate row per grid
-step through a scalar-prefetch index map, so the (nq, C, N) candidate
-tensor never exists in HBM -- and here the gathered rows are int8, cutting
-the gather bytes 4x on top of the 4x capacity win.
+The Pallas path is the fused_query.py kernel itself, run on the codes:
+it gathers one candidate's native row tile per grid step through a
+scalar-prefetch index map, so the (nq, C, N) candidate tensor never
+exists in HBM.  A native tile is 32 bytes of row height at every
+precision (8 fp32 rows, 16 bf16, 32 int8), so per-step gather bytes match
+the fp32 path; the tier's win is capacity.
 """
 
 from __future__ import annotations
@@ -31,16 +33,12 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
-from . import merge
+from . import fused_query, merge
 
 Array = jax.Array
 
 PRECISIONS = ("fp32", "bf16", "int8")
-
-_KP = 128   # top-k scratch width, matching fused_query._KP
 
 _DTYPES = {"fp32": jnp.float32, "bf16": jnp.bfloat16, "int8": jnp.int8}
 _WIDTHS = {"fp32": 4, "bf16": 2, "int8": 1}
@@ -135,103 +133,18 @@ def quantized_topk_ref(q: Array, codes: Array, scale: Array, ids: Array,
     return dist, jnp.where(jnp.isinf(dist), -1, out_ids)
 
 
-def _lp(diff: Array, p: float) -> Array:
-    if p == 2.0:
-        return jnp.sqrt(jnp.sum(diff * diff))
-    if p == 1.0:
-        return jnp.sum(jnp.abs(diff))
-    return jnp.sum(jnp.abs(diff) ** p) ** (1.0 / p)
-
-
-def _quantized_query_kernel(ids_ref, q_ref, row_ref, od_ref, oi_ref,
-                            dacc, iacc, *, k: int, p: float, valid: int):
-    i, c = pl.program_id(0), pl.program_id(1)
-
-    @pl.when(c == 0)
-    def _init():
-        dacc[...] = jnp.full_like(dacc, jnp.inf)
-        iacc[...] = jnp.full_like(iacc, -1)
-
-    cid = ids_ref[i, c]
-    # the only dequant in the hot loop is an in-register widening cast --
-    # the scale multiply happens once per output, outside the kernel
-    d = _lp(row_ref[...].astype(jnp.float32) - q_ref[...], p)
-    ok = (cid >= 0) & (cid < valid)
-    d = jnp.where(ok, d, jnp.inf)
-
-    cur = dacc[...]
-    lane = jax.lax.broadcasted_iota(jnp.int32, cur.shape, 1)
-    hit = (lane == jnp.argmax(cur)) & (d < jnp.max(cur))
-    dacc[...] = jnp.where(hit, d, cur)
-    iacc[...] = jnp.where(hit, cid, iacc[...])
-
-    @pl.when(c == pl.num_programs(1) - 1)
-    def _epilogue():
-        dv, iv = dacc[...], iacc[...]
-        il = jax.lax.broadcasted_iota(jnp.int32, dv.shape, 1)
-        out_d, out_i = [], []
-        for _ in range(k):
-            mn = jnp.argmin(dv)
-            one = il == mn
-            dm = jnp.min(dv)
-            im = jnp.sum(jnp.where(one, iv, 0))
-            out_d.append(dm)
-            out_i.append(jnp.where(jnp.isinf(dm), -1, im))
-            dv = jnp.where(one, jnp.inf, dv)
-        od_ref[...] = jnp.stack(out_d).reshape(1, k)
-        oi_ref[...] = jnp.stack(out_i).reshape(1, k).astype(jnp.int32)
-
-
 def quantized_query_topk(q: Array, codes: Array, scale: Array, ids: Array,
                          k: int, p: float = 2.0,
                          valid_items: int | None = None,
                          interpret: bool = True) -> tuple[Array, Array]:
-    """The fused_query kernel over a quantized db: scalar-prefetch row
-    gather (int8/bf16 HBM->VMEM -- 4x/2x fewer gather bytes than fp32),
-    code-space L^p, streaming top-k.  Distances are scaled to the fp32
-    metric after the kernel.  Shapes/contract as ``ops.fused_query_topk``.
-
-    Note: the (1, N) int8 row blocks sit below the (32, 128) native int8
-    tile; Mosaic pads them, which is wasteful but correct -- the capacity
-    win is the point of this tier, and CI validates via interpret mode.
+    """The fused_query kernel over a quantized db: scalar-prefetch tile
+    gather of int8/bf16 codes, code-space L^p, streaming top-k.  Distances
+    are scaled to the fp32 metric after the kernel.  Shapes/contract as
+    ``ops.fused_query_topk``.
     """
-    nq, n = q.shape
-    m, n2 = codes.shape
-    c = ids.shape[1]
-    assert n == n2 and ids.shape == (nq, c)
-    assert k <= c, f"k={k} exceeds candidate count C={c}"
-    assert k <= _KP, f"k={k} exceeds kernel top-k width {_KP}"
-    valid = m if valid_items is None else int(valid_items)
-
     qc, post = _code_query(q.astype(jnp.float32), codes.dtype, scale)
-    npad = -n % 128
-    qp = jnp.pad(qc, ((0, 0), (0, npad)))
-    dbp = jnp.pad(codes, ((0, 0), (0, npad)))
-    nl = n + npad
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(nq, c),
-        in_specs=[
-            pl.BlockSpec((1, nl), lambda i, c, ids: (i, 0)),
-            pl.BlockSpec((1, nl), lambda i, c, ids: (jnp.maximum(ids[i, c], 0), 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, k), lambda i, c, ids: (i, 0)),
-            pl.BlockSpec((1, k), lambda i, c, ids: (i, 0)),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((1, _KP), jnp.float32),
-            pltpu.VMEM((1, _KP), jnp.int32),
-        ],
-    )
-    dists, out_ids = pl.pallas_call(
-        functools.partial(_quantized_query_kernel, k=k, p=p, valid=valid),
-        grid_spec=grid_spec,
-        out_shape=(jax.ShapeDtypeStruct((nq, k), jnp.float32),
-                   jax.ShapeDtypeStruct((nq, k), jnp.int32)),
-        interpret=interpret,
-    )(ids.astype(jnp.int32), qp, dbp)
+    dists, out_ids = fused_query.fused_query_topk(
+        qc, codes, ids, k, p=p, valid_items=valid_items, interpret=interpret)
     return dists * post, out_ids
 
 
@@ -267,7 +180,7 @@ def survivor_width(k: int, survivor_k: int, cap: int) -> int:
     else 4k (the ~4k candidates the rerank stage re-reads at fp32), clipped
     to [k, cap] and to the fused kernel's top-k scratch."""
     m = survivor_k if survivor_k and survivor_k > 0 else 4 * k
-    return max(k, min(int(m), int(cap), _KP))
+    return max(k, min(int(m), int(cap), fused_query._KP))
 
 
 def np_bytes_per_live_item(precision: str, n_dims: int) -> float:

@@ -87,6 +87,31 @@ def default_specs(n_dims=64, segment_capacity=1024, shard_axis=None,
     )
 
 
+def synthetic_inputs(sv, n, rng):
+    """Per-tenant synthetic inputs for ``Servable.embed``.
+
+    Function tenants get random smooth functions sampled at the tenant's
+    node set (mixtures of a few random sines -- bounded, infinitely
+    divisible); the Wasserstein tenant gets raw draws from random 1-D
+    Gaussians (the empirical-distribution ingest path: the embedder
+    computes the clipped quantile function itself).  ``rng`` is a numpy
+    Generator, so a seed fixes the data.
+    """
+    import numpy as np
+
+    if sv.spec.embedder == "wasserstein":
+        mu = rng.uniform(-1.0, 1.0, size=(n, 1))
+        sig = rng.uniform(0.1, 1.0, size=(n, 1))
+        return mu + sig * rng.normal(size=(n, 256))
+    nodes = sv.nodes()
+    amps = rng.normal(size=(n, 3)) / 3.0
+    freqs = rng.uniform(0.5, 4.0, size=(n, 3))
+    phase = rng.uniform(0, 2 * np.pi, size=(n, 3))
+    return np.sum(amps[:, :, None] *
+                  np.sin(freqs[:, :, None] * nodes[None, None, :]
+                         + phase[:, :, None]), axis=1)
+
+
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--steps", type=int, default=60)
@@ -186,11 +211,13 @@ def main():
 
     import numpy as np
 
+    from .. import compile_cache
     from ..obs import Exporter, configure as obs_configure
     from ..serve import ServableRegistry, recall_proxy, run_server
     from ..serve.stats import occupancy_report
     from .mesh import make_serve_mesh
 
+    compile_cache.enable()
     if args.trace_sample is not None or args.trace_deep:
         obs_configure(sample_rate=args.trace_sample,
                       deep=True if args.trace_deep else None)
@@ -289,27 +316,6 @@ def main():
         print("[serve] OK")
         return
 
-    def sample_fvals(sv, n):
-        """Per-tenant synthetic inputs for ``Servable.embed``.
-
-        Function tenants get random smooth functions sampled at the
-        tenant's node set (mixtures of a few random sines -- bounded,
-        infinitely divisible); the Wasserstein tenant gets raw draws from
-        random 1-D Gaussians (the empirical-distribution ingest path: the
-        embedder computes the clipped quantile function itself).
-        """
-        if sv.spec.embedder == "wasserstein":
-            mu = rng.uniform(-1.0, 1.0, size=(n, 1))
-            sig = rng.uniform(0.1, 1.0, size=(n, 1))
-            return mu + sig * rng.normal(size=(n, 256))
-        nodes = sv.nodes()
-        amps = rng.normal(size=(n, 3)) / 3.0
-        freqs = rng.uniform(0.5, 4.0, size=(n, 3))
-        phase = rng.uniform(0, 2 * np.pi, size=(n, 3))
-        return np.sum(amps[:, :, None] *
-                      np.sin(freqs[:, :, None] * nodes[None, None, :]
-                             + phase[:, :, None]), axis=1)
-
     inserted = {name: [] for name in registry.names()}
     futures = []
     compactions = {name: 0 for name in registry.names()}
@@ -318,12 +324,13 @@ def main():
         for name in registry.names():
             sv = registry.get(name)
             # ingest: embed + insert into the delta segment
-            emb = np.asarray(sv.embed(sample_fvals(sv, args.insert_batch)))
+            emb = np.asarray(sv.embed(
+                synthetic_inputs(sv, args.insert_batch, rng)))
             inserted[name].extend(sv.insert(emb).tolist())
             # queries: perturbations of known items -> through the admission
             # queue (several small heterogeneous requests per tick)
             for _ in range(args.queries_per_step):
-                base = sv.embed(sample_fvals(sv, args.query_batch))
+                base = sv.embed(synthetic_inputs(sv, args.query_batch, rng))
                 qs = np.asarray(base) + rng.normal(
                     scale=0.05, size=base.shape).astype(np.float32)
                 futures.append(sv.submit_query(qs, args.k, args.n_probes))
@@ -347,7 +354,7 @@ def main():
             for name in registry.names():
                 sv = registry.get(name)
                 qs = np.asarray(sv.embed(
-                    sample_fvals(sv, args.recall_probe_size)))
+                    synthetic_inputs(sv, args.recall_probe_size, rng)))
                 sv.stats.record_recall(recall_proxy(
                     sv.index, qs, args.k, n_probes=args.n_probes))
         if exporter is not None:
@@ -365,7 +372,8 @@ def main():
     probe = {}
     for name in registry.names():
         sv = registry.get(name)
-        qs = np.asarray(sv.embed(sample_fvals(sv, args.recall_probe_size)))
+        qs = np.asarray(sv.embed(
+            synthetic_inputs(sv, args.recall_probe_size, rng)))
         r = recall_proxy(sv.index, qs, args.k, n_probes=args.n_probes)
         sv.stats.record_recall(r)
         probe[name] = round(r, 3)

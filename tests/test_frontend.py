@@ -52,6 +52,8 @@ def _env():
     env = dict(os.environ, JAX_PLATFORMS="cpu",
                PYTHONPATH=os.path.join(ROOT, "src"))
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=1"
+    # the launcher turns the persistent compile cache on; tests keep it off
+    env["JAX_ENABLE_COMPILATION_CACHE"] = "false"
     return env
 
 

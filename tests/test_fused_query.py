@@ -192,3 +192,29 @@ def test_dispatch_resolution(monkeypatch):
     # per-shape blocks: saturated dims -> 128; small dims -> 8-quantum
     assert dispatch.matmul_blocks(512, 64, 300) == (128, 64, 128)
     assert dispatch.rerank_blocks(4, 200) == (8, 128)
+
+
+@pytest.mark.parametrize("op", ["fused", "quantized"])
+def test_topk_wider_than_kernel_scratch_is_refused(rng_key, op):
+    """k past the kernels' 128-lane top-k scratch raises in the kernel
+    modes (no quiet fall-back to the HBM-gather path); the reference path
+    still serves it when asked for by name."""
+    nq, c, n, m, k = 2, 160, 8, 200, 129
+    q = jax.random.normal(jax.random.fold_in(rng_key, 1), (nq, n))
+    db = jax.random.normal(jax.random.fold_in(rng_key, 2), (m, n))
+    ids = jax.random.randint(jax.random.fold_in(rng_key, 3), (nq, c), -1, m)
+
+    def call(backend):
+        if op == "fused":
+            return ops.fused_query_topk(q, db, ids, k, backend=backend)
+        return ops.quantized_query_topk(q, db.astype(jnp.bfloat16),
+                                        jnp.float32(1.0), ids, k,
+                                        backend=backend)
+
+    for mode in ("interpret", "compiled", "fused"):
+        with pytest.raises(ValueError, match="top-k scratch"):
+            call(mode)
+    d, i = call("reference")
+    assert i.shape == d.shape == (nq, k)
+    fin = np.isfinite(np.asarray(d))
+    assert (np.diff(np.asarray(d)[0][fin[0]]) >= 0).all()

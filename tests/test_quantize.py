@@ -3,7 +3,8 @@
 The tier's whole contract is a *bounded-loss* ladder (invariant 10):
 
 * int8 encode -> decode round-trip error is <= scale/2 per coordinate
-  (symmetric rounding), property-checked by hypothesis over adversarial
+  (symmetric rounding) plus one f32 ulp of the segment's largest
+  magnitude (decode's f32 arithmetic), property-checked by hypothesis over adversarial
   value ranges (tiny scales, huge scales, all-zero segments);
 * code-space scoring equals the reference oracle, and with a wide-enough
   survivor pool the reranked answer equals the exact fp32 answer;
@@ -51,7 +52,11 @@ def test_int8_round_trip_error_bounded(data):
     codes, scale = quantize.encode(jnp.asarray(db), "int8")
     assert codes.dtype == jnp.int8
     back = np.asarray(quantize.decode(codes, scale))
-    bound = float(scale) / 2 + 1e-12
+    # scale/2 is the rounding step of the code; on top of it, decode's
+    # f32 product code*scale (with scale itself rounded from max|x|/127)
+    # may land up to one f32 ulp of the largest magnitude away
+    ulp = float(np.spacing(np.float32(np.max(np.abs(db)))))
+    bound = float(scale) / 2 + ulp
     assert np.max(np.abs(back - db)) <= bound
 
 
