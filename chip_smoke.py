@@ -7,27 +7,32 @@
 
 One chip (the default), all in this one process:
 
-1. build a ``ServableRegistry`` from ``launch.serve.default_specs`` -- the
+1. run the query kernels (``ops.fused_query_topk`` fp32,
+   ``ops.quantized_query_topk`` bf16 and int8; p=1 and 2) at the served
+   shapes against their jnp references on the device: ids equal where the
+   reference's distances are distinct, distances within f32 tolerance;
+2. build a ``ServableRegistry`` from ``launch.serve.default_specs`` -- the
    launcher's deployment: ``l2-basis`` (p=2, Chebyshev basis), ``l1-qmc``
    (p=1, QMC nodes), ``w2-quantile`` (W2 over raw 1-D samples); N=64,
    1,024-row segments, 8 tables x 4 hashes x 2^10 buckets x 32 slots --
    with the write-ahead log on, as ``launch.serve --wal-dir`` does;
-2. bulk-load ``--items`` (2^18) items per tenant through
+3. bulk-load ``ITEMS`` (2^18) items per tenant through
    ``Servable.embed`` then ``Servable.insert``, as the launcher does;
-3. serve that registry with ``serve.frontend.Frontend`` on an asyncio loop
+4. serve that registry with ``serve.frontend.Frontend`` on an asyncio loop
    in a background thread, and drive it through
    ``serve.client.FrontendClient``: query batches per tenant, one insert,
    one delete, one ``maintenance`` compact polled with ``job_status``;
-4. check that every wire answer is bit-identical to the same call made in
+5. check that every wire answer is bit-identical to the same call made in
    process on the same registry, that inserted rows read back as their own
    nearest neighbours and deleted ones never come back, and that recall@10
    against exact brute force over the live items (``serve.stats.
    recall_proxy``) is at least ``RECALL_FLOOR``.
 
-Four chips (``--chips 4``), and nothing else: the same three tenants on
-``make_serve_mesh(4)`` and, in the same process, on one device; both are
-loaded identically, deleted from and compacted once, then asked the same
-queries.  Gids and distances must be bit-identical.
+Four chips (``--chips 4``), and nothing else: the same three tenants at
+``ITEMS`` each on ``make_serve_mesh(4)`` (64 sealed segments per device)
+and, in the same process, on one device; both are loaded identically,
+deleted from and compacted once, then asked the same queries.  Gids and
+distances must be bit-identical.
 
 Earlier lines of output are diagnostics for reading (devices, dispatch
 decisions, load sizes, device bytes in use, compile seconds, per-request
@@ -60,6 +65,7 @@ N_DIMS = 64               # launch.serve --n-dims
 SEGMENT_CAPACITY = 1024   # launch.serve --segment-capacity
 K = 10                    # launch.serve --k
 N_PROBES = 4              # launch.serve --n-probes
+ITEMS = 2 ** 18           # live items per tenant: 256 sealed segments
 LOAD_CHUNK = 8192         # rows per embed + insert call while loading
 QUERY_ROWS = 8            # rows per query request: the palette's 8-chunk
 WIRE_BATCHES = 3          # query requests per tenant and round
@@ -69,8 +75,16 @@ OVERRIDES = ("REPRO_KERNEL_BACKEND", "REPRO_QUERY_BACKEND")
 KERNEL_PATHS = ("kernel_mode", "query_backend", "hash_backend",
                 "embed_backend")
 
+# Kernel parity phase: the query kernels at the served shapes -- the chunk
+# palette's widest batch, 8 tables x 4 probes x 32 slots of candidates.
+KERNEL_NQ = 128
+KERNEL_C = 1024
+KERNEL_ROWS = 2 * SEGMENT_CAPACITY
+KERNEL_RTOL = KERNEL_ATOL = 1e-5   # f32 tolerance on distances
+
+SEED = 0
 # recall@10 floors: this script's own recall on the CPU (JAX_PLATFORMS=cpu,
-# --rehearse, seed 0, 2^18 items per tenant -- the full size: 0.978125,
+# --rehearse, SEED, 2^18 items per tenant -- the full size: 0.978125,
 # 0.421875 and 1.0), less 0.02.
 RECALL_FLOOR = {"l1-qmc": 0.958125, "l2-basis": 0.401875, "w2-quantile": 0.98}
 
@@ -197,6 +211,73 @@ def wire_round(client, registry, label, batches) -> None:
         check(same, f"{label} {name}: wire answer differs from in-process")
 
 
+def kernel_parity(rng) -> None:
+    """``ops.fused_query_topk`` (fp32) and ``ops.quantized_query_topk``
+    (bf16, int8) through the default dispatch, against their jnp references
+    on this device: ids equal wherever the reference's distances are
+    distinct, distances within f32 tolerance, the same empty slots."""
+    import numpy as np
+
+    import jax
+    import jax.numpy as jnp
+    from repro.kernels import ops, quantize, ref
+
+    db = jnp.asarray(rng.standard_normal((KERNEL_ROWS, N_DIMS), np.float32))
+    q = jnp.asarray(rng.standard_normal((KERNEL_NQ, N_DIMS), np.float32))
+    # distinct ids per query, some empty slots, some past the valid prefix
+    ids = np.argsort(rng.random((KERNEL_NQ, KERNEL_ROWS)), axis=1)
+    ids = ids[:, :KERNEL_C].astype(np.int32)
+    ids[rng.random(ids.shape) < 0.05] = -1
+    ids = jnp.asarray(ids)
+    valid = KERNEL_ROWS - 64
+    fused_ref = jax.jit(ref.fused_query_topk_ref, static_argnums=(3, 4, 5))
+    quant_ref = jax.jit(quantize.quantized_topk_ref,
+                        static_argnums=(4, 5, 6))
+    for precision in ("fp32", "bf16", "int8"):
+        if precision == "fp32":
+            k = K
+        else:
+            codes, scale = quantize.encode(db, precision)
+            k = quantize.survivor_width(K, 0, KERNEL_C)
+        for p in (1.0, 2.0):
+            t0 = time.perf_counter()
+            # the reference one slot wider, so a tie across the k-th place
+            # is seen too
+            if precision == "fp32":
+                got = ops.fused_query_topk(q, db, ids, k, p=p,
+                                           valid_items=valid)
+                want = fused_ref(q, db, ids, k + 1, p, valid)
+            else:
+                got = ops.quantized_query_topk(q, codes, scale, ids, k, p=p,
+                                               valid_items=valid)
+                want = quant_ref(q, codes, scale, ids, k + 1, p, valid)
+            (d_k, i_k), (d_w, i_w) = ([np.asarray(a) for a in pair]
+                                      for pair in (got, want))
+            d_r, i_r = d_w[:, :k], i_w[:, :k]
+            fin = np.isfinite(d_r)
+            # distinct: apart from both neighbours by more than the
+            # tolerance, which covers a different summation order
+            tol = KERNEL_ATOL + KERNEL_RTOL * np.abs(d_w)
+            with np.errstate(invalid="ignore"):       # inf - inf
+                apart = np.diff(d_w, axis=1) > tol[:, 1:]
+            distinct = fin & apart
+            distinct[:, 1:] &= apart[:, :-1]
+            ids_ok = bool((i_k[distinct] == i_r[distinct]).all())
+            empty_ok = bool((np.isfinite(d_k) == fin).all()
+                            and (i_k[~fin] == -1).all())
+            err = np.abs(d_k[fin] - d_r[fin])
+            dist_ok = bool((err <= tol[:, :k][fin]).all())
+            label = (f"kernel {precision} p={p:g} nq={KERNEL_NQ} "
+                     f"C={KERNEL_C} N={N_DIMS} k={k}")
+            say(f"{label}: ids_equal_where_distinct={ids_ok} "
+                f"compared={int(distinct.sum())}/{distinct.size} "
+                f"empty_slots_equal={empty_ok} "
+                f"max_abs_dist_err={float(err.max(initial=0.0))!r} "
+                f"seconds={time.perf_counter() - t0:.3f}")
+            check(ids_ok and empty_ok and dist_ok,
+                  f"{label}: kernel differs from its reference")
+
+
 def run_one_chip(args, rng, compiles) -> None:
     import numpy as np
 
@@ -204,6 +285,11 @@ def run_one_chip(args, rng, compiles) -> None:
     from repro.launch.serve import default_specs, synthetic_inputs
     from repro.serve import ServableRegistry, recall_proxy
     from repro.serve.client import FrontendClient
+
+    # its own stream: the load's data, and the floors taken on it, do not
+    # depend on this phase
+    kernel_parity(np.random.default_rng((SEED, 1)))
+    say(f"after kernel parity: {compiles.line()}")
 
     with tempfile.TemporaryDirectory(prefix="chip_smoke_wal_") as wal_dir:
         registry = ServableRegistry(wal_dir=wal_dir)
@@ -353,16 +439,16 @@ def run_four_chips(args, rng, compiles) -> None:
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--chips", type=int, choices=(1, 4), default=1)
-    ap.add_argument("--items", type=int, default=None,
-                    help="items per tenant (default 2^18 on one chip, "
-                         "2^15 on four); smaller only for rehearsals")
-    ap.add_argument("--seed", type=int, default=0)
+    ap.add_argument("--items", type=int, default=ITEMS,
+                    help="items per tenant of a --rehearse run (always "
+                         f"{ITEMS} otherwise)")
     ap.add_argument("--rehearse", action="store_true",
                     help="run the phases without a TPU (the final gate "
                          "still fails)")
     args = ap.parse_args(argv)
-    if args.items is None:
-        args.items = 2 ** 18 if args.chips == 1 else 2 ** 15
+    if args.items != ITEMS and not args.rehearse:
+        ap.error("--items needs --rehearse: the ok line stands for the "
+                 "full load, where the recall floors were taken")
 
     if not os.path.isdir(os.path.join(SRC, "repro")):
         print(f"chip_smoke: no repro package under {SRC}", file=sys.stderr)
@@ -391,7 +477,7 @@ def main(argv=None) -> int:
         return 1
     say(f"dispatch {json.dumps(dispatch.describe(), sort_keys=True)}")
 
-    rng = np.random.default_rng(args.seed)
+    rng = np.random.default_rng(SEED)
     t0 = time.perf_counter()
     if args.chips == 4:
         run_four_chips(args, rng, compiles)
