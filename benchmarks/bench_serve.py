@@ -18,12 +18,11 @@ Two experiments, reported into BENCH_results.json:
    (higher device efficiency) at higher admission latency -- the curve makes
    the trade-off visible per PR.
 
-3. **Tracing overhead** -- batched queries timed in adjacent
-   off/on/deep triples; ``trace_overhead_frac`` (the median per-pair
-   cost of full-rate coarse tracing) is gated absolutely at 5% by
+3. **Tracing overhead** -- batched queries timed in adjacent off/on
+   pairs; ``trace_overhead_frac`` (the median per-pair cost of full-rate
+   tracing) is gated absolutely at 5% by
    ``tools/check_bench_regression.py`` (docs/architecture.md, invariant
-   8).  Deep (staged-engine) tracing is measured too but only reported --
-   it is a profiling mode, not a production path.
+   8).
 
 REPRO_BENCH_SMOKE=1 shrinks both sweeps for CI.
 """
@@ -161,7 +160,7 @@ def _batcher_curve(rng, n_requests: int, segment_capacity: int) -> dict:
 
 
 def _trace_overhead(rng, segment_capacity: int, smoke: bool) -> dict:
-    """Query cost with tracing off / full coarse / full deep.
+    """Query cost with tracing off / on at full sampling.
 
     The dial under test is exactly the production one:
     ``obs.trace.configure``.  The bench host drifts 15-25% across
@@ -169,7 +168,7 @@ def _trace_overhead(rng, segment_capacity: int, smoke: bool) -> dict:
     order of magnitude larger than the effect being measured, so plain
     A-then-B throughput timing flakes the gate no matter how long the
     windows are.  Instead each *single* batched query is timed in an
-    adjacent off/on/deep triple -- drift phases are long, so both sides
+    adjacent off/on pair -- drift phases are long, so both sides
     of a pair see the same machine -- and the gated number is the
     **median of per-pair ratios**, which additionally discards the
     occasional scheduler stall.  Batches are the palette's largest chunk
@@ -188,40 +187,34 @@ def _trace_overhead(rng, segment_capacity: int, smoke: bool) -> dict:
         lambda q, k, npb: tuple(map(np.asarray,
                                     idx.query(q, k, n_probes=npb))),
         chunk_sizes=CHUNK_SIZES, max_delay_ms=2.0)
-    modes = (("off", 0.0, False), ("on", 1.0, False), ("deep", 1.0, True))
+    modes = (("off", 0.0), ("on", 1.0))
 
-    def one(rate: float, deep: bool) -> float:
-        obs_trace.configure(sample_rate=rate, deep=deep)
+    def one(rate: float) -> float:
+        obs_trace.configure(sample_rate=rate)
         try:
             t0 = time.perf_counter()
             batcher.query(qs, K, N_PROBES)
             return time.perf_counter() - t0
         finally:
-            obs_trace.configure(sample_rate=0.0, deep=False)
+            obs_trace.configure(sample_rate=0.0)
 
     for _ in range(6):                      # warm every mode's programs
-        for _, rate, deep in modes:
-            one(rate, deep)
-    total = {name: 0.0 for name, _, _ in modes}
-    on_ratio, deep_ratio = [], []
+        for _, rate in modes:
+            one(rate)
+    total = {name: 0.0 for name, _ in modes}
+    on_ratio = []
     for _ in range(n_pairs):
-        t = {name: one(rate, deep) for name, rate, deep in modes}
+        t = {name: one(rate) for name, rate in modes}
         for name in total:
             total[name] += t[name]
         on_ratio.append(t["on"] / t["off"] - 1.0)
-        deep_ratio.append(t["deep"] / t["off"] - 1.0)
     rows = n_pairs * qs.shape[0]
     return {
         "qps_trace_off": round(rows / total["off"]),
         "qps_trace_on": round(rows / total["on"]),
-        "qps_trace_deep": round(rows / total["deep"]),
-        # the gated number: coarse tracing at sample 1.0 vs off
+        # the gated number: tracing at sample 1.0 vs off
         "trace_overhead_frac": round(
             max(0.0, float(np.median(on_ratio))), 4),
-        # informational: the profiling mode's cost (staged engine + block
-        # per stage); never gated
-        "deep_overhead_frac": round(
-            max(0.0, float(np.median(deep_ratio))), 4),
     }
 
 

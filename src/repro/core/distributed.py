@@ -37,7 +37,7 @@ program -- padded/freed slots are simply inactive in the mask.
 from __future__ import annotations
 
 import functools
-from typing import NamedTuple, Optional, Tuple
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -180,8 +180,10 @@ def _sharded_segment_query_fn(cfg: IndexConfig, k: int, n_probes: int,
                                           n_probes=n_probes, backend=backend,
                                           live_mask=live)
 
-    def shard_fn(sealed_state, sealed_gids, sealed_live, sealed_scales,
-                 active, delta_state, delta_gids, delta_live, q):
+    # the function's name is the program's name in a profiler trace
+    def segment_query_sharded(sealed_state, sealed_gids, sealed_live,
+                              sealed_scales, active, delta_state, delta_gids,
+                              delta_live, q):
         # sealed_* leaves: this device's (per_dev, ...) block; delta_*
         # replicated.  Static unroll over the local segments -- identical
         # shapes, so it is one fused program, not per_dev compilations.
@@ -210,7 +212,7 @@ def _sharded_segment_query_fn(cfg: IndexConfig, k: int, n_probes: int,
     state_sharded = jax.tree.map(lambda _: P(axis), _state_structure())
     state_repl = jax.tree.map(lambda _: P(), _state_structure())
     fn = compat.shard_map(
-        shard_fn, mesh=mesh,
+        segment_query_sharded, mesh=mesh,
         in_specs=(state_sharded, P(axis), P(axis), P(axis), P(axis),
                   state_repl, P(), P(), P()),
         out_specs=(P(), P()),
@@ -267,107 +269,6 @@ def query_segments_sharded(placement, cfg: IndexConfig, queries: Array,
               placement.sealed_live, scales, active, placement.delta_state,
               placement.delta_gids, placement.delta_live,
               jnp.asarray(queries, jnp.float32))
-
-
-class StagedShardedParts(NamedTuple):
-    """The sharded collective split at stage boundaries (deep tracing).
-
-    Four separately-jitted shard_map programs whose composition is, op for
-    op, the fused ``_sharded_segment_query_fn`` body: gather (bucket-slot
-    lookup + dedup + tombstone filter per local instance), rerank (exact
-    re-rank + gid translate + active/rank-0 masking), merge (local
-    cross-instance ``merge_topk``), fanin (all-gather + global
-    ``merge_topk_unique``).  Intermediates stay device-resident sharded
-    arrays between calls, so splitting adds dispatch latency but no data
-    movement.  The serve layer drives these under per-stage spans; results
-    are asserted bitwise-equal to the fused program in tests.
-    """
-
-    gather: object
-    rerank: object
-    merge: object
-    fanin: object
-
-
-@functools.lru_cache(maxsize=64)
-def staged_sharded_parts(cfg: IndexConfig, k: int, backend: Optional[str],
-                         mesh: Mesh, axis: str, per_dev: int
-                         ) -> StagedShardedParts:
-    """Build (and cache) the staged collective for one placement shape.
-
-    Buckets are computed *once* outside, replicated (all segments share one
-    hash family -- the staged path hoists hash+probe out of the per-segment
-    loop, which the fused program cannot), then:
-
-        gather(sealed_table, sealed_live, delta_table, delta_live, buckets)
-            -> (sealed_cands (n_dev*per_dev, nq, C) sharded,
-                delta_cands (nq, C) replicated)
-        rerank(sealed_db, sealed_gids, active, sealed_cands,
-               delta_db, delta_gids, delta_cands, q)
-            -> (parts_g, parts_d) (n_dev, nq, (per_dev+1)*k) sharded
-        merge(parts_g, parts_d) -> (g_loc, d_loc) (n_dev, nq, k) sharded
-        fanin(g_loc, d_loc) -> (gids, dists) (nq, k) replicated
-    """
-
-    def gather_fn(sealed_table, sealed_live, delta_table, delta_live,
-                  buckets):
-        parts = [lsh_index.gather_stage(sealed_table[i], buckets, cfg,
-                                        sealed_live.shape[1],
-                                        live_mask=sealed_live[i])
-                 for i in range(per_dev)]
-        sealed_cands = jnp.stack(parts)                 # (per_dev, nq, C)
-        delta_cands = lsh_index.gather_stage(delta_table, buckets, cfg,
-                                             delta_live.shape[0],
-                                             live_mask=delta_live)
-        return sealed_cands, delta_cands
-
-    def rerank_fn(sealed_db, sealed_gids, active, sealed_cands,
-                  delta_db, delta_gids, delta_cands, q):
-        parts_g, parts_d = [], []
-        for i in range(per_dev):
-            g, d = lsh_index.rerank_stage(sealed_db[i], sealed_gids[i], cfg,
-                                          q, sealed_cands[i], k,
-                                          backend=backend)
-            parts_g.append(jnp.where(active[i], g, -1))
-            parts_d.append(jnp.where(active[i], d, jnp.inf))
-        g, d = lsh_index.rerank_stage(delta_db, delta_gids, cfg, q,
-                                      delta_cands, k, backend=backend)
-        rank = jax.lax.axis_index(axis)
-        parts_g.append(jnp.where(rank == 0, g, -1))
-        parts_d.append(jnp.where(rank == 0, d, jnp.inf))
-        # leading length-1 device axis so out_specs=P(axis) stacks shards
-        return (jnp.concatenate(parts_g, axis=1)[None],
-                jnp.concatenate(parts_d, axis=1)[None])
-
-    def merge_fn(parts_g, parts_d):
-        d_loc, g_loc = ops.merge_topk(parts_d[0], parts_g[0], k)
-        return g_loc[None], d_loc[None]
-
-    def fanin_fn(g_loc, d_loc):
-        all_g = jax.lax.all_gather(g_loc[0], axis)      # (n_dev, nq, k)
-        all_d = jax.lax.all_gather(d_loc[0], axis)
-        nd, nq = all_g.shape[0], all_g.shape[1]
-        flat_g = all_g.transpose(1, 0, 2).reshape(nq, nd * k)
-        flat_d = all_d.transpose(1, 0, 2).reshape(nq, nd * k)
-        d_out, g_out = ops.merge_topk_unique(flat_d, flat_g, k)
-        return g_out, d_out
-
-    def _wrap(fn, in_specs, out_specs):
-        return jax.jit(compat.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                                        out_specs=out_specs,
-                                        check_vma=False))
-
-    return StagedShardedParts(
-        gather=_wrap(gather_fn,
-                     (P(axis), P(axis), P(), P(), P()),
-                     (P(axis), P())),
-        rerank=_wrap(rerank_fn,
-                     (P(axis), P(axis), P(axis), P(axis),
-                      P(), P(), P(), P()),
-                     (P(axis), P(axis))),
-        merge=_wrap(merge_fn, (P(axis), P(axis)), (P(axis), P(axis))),
-        fanin=_wrap(fanin_fn, (P(axis), P(axis)), (P(), P())),
-    )
 
 
 def brute_force_distributed(embeddings: Array, queries: Array, k: int,

@@ -133,10 +133,9 @@ def hash_stage(alpha: Array, b: Array, cfg: IndexConfig, x: Array
                ) -> Tuple[Array, Array]:
     """Stage 1 of the query pipeline: (..., L, K) int32 hashes and
     pre-floor projections (kernel-dispatched).  Takes the family arrays
-    directly so the traced *staged* engine (serve/segments.py) can run it
-    once per query batch -- every segment shares one family -- while the
-    fused path calls it through :func:`_hashes_and_proj` with identical
-    inputs, keeping the two paths parity-by-construction."""
+    directly so a fan-out can run it once per query batch -- every segment
+    shares one family (ROADMAP S2) -- while the fused path calls it
+    through :func:`_hashes_and_proj` with identical inputs."""
     h, proj = ops.pstable_hash_proj(x, alpha, b, cfg.r,
                                     backend=dispatch.hash_backend())
     shape = x.shape[:-1] + (cfg.n_tables, cfg.n_hashes)
@@ -234,12 +233,32 @@ def insert_items(state: LSHIndexState, cfg: IndexConfig, embeddings: Array,
     return dataclasses.replace(state, table=table, counts=counts, db=db)
 
 
+@jax.jit
+def bucket_overflow(state: LSHIndexState, n_items) -> Tuple[Array, Array]:
+    """Bucket health of an index holding items ``0 .. n_items-1``:
+    (placements dropped because their bucket was full, items that no table
+    holds).
+
+    ``counts`` records true occupancy while a bucket keeps at most
+    ``bucket_capacity`` ids, so the first is sum(max(counts - capacity,
+    0)) over every table; an item dropped from all of its buckets is found
+    by no query.  Computed on the device from ``counts`` and ``table``."""
+    dropped = jnp.maximum(state.counts - state.table.shape[-1], 0).sum()
+    n_cap = state.db.shape[0]
+    ids = state.table.reshape(-1)
+    held = jnp.zeros((n_cap,), jnp.bool_).at[
+        jnp.where(ids >= 0, ids, n_cap)].set(True, mode="drop")
+    unreachable = jnp.sum((jnp.arange(n_cap) < n_items) & ~held)
+    return dropped, unreachable
+
+
 def probe_stage(mix: Array, cfg: IndexConfig, hashes: Array,
                 proj: Array, n_probes: int) -> Array:
     """Stage 2: (..., L, T) bucket ids: base bucket + best (T-1)
     single-coordinate perturbations ranked by distance-to-boundary
-    (Lv et al. step-wise probing).  Family-array form for the staged
-    engine; the fused path wraps it via :func:`_probe_buckets`."""
+    (Lv et al. step-wise probing).  Family-array form, like
+    :func:`hash_stage`; the fused path wraps it via
+    :func:`_probe_buckets`."""
     frac = proj - jnp.floor(proj)                                    # (..., L, K)
     # score for delta=+1 is (1 - frac), for delta=-1 is frac; smaller = better.
     scores = jnp.concatenate([1.0 - frac, frac], axis=-1)            # (..., L, 2K)
@@ -438,9 +457,9 @@ def rerank_stage(db: Array, gids: Array, cfg: IndexConfig, q: Array,
                  ) -> Tuple[Array, Array]:
     """Stage 4: exact re-rank + top-k + local-slot -> global-id translation.
 
-    The staged engine's tail: candidates come pre-filtered from
+    The pipeline's tail on its own: candidates come pre-filtered from
     :func:`gather_stage`, the distance/top-k op is the same
-    ``ops.fused_query_topk`` the fused path runs, so staged results are
+    ``ops.fused_query_topk`` the fused path runs, so its results are
     bitwise those of :func:`query_index_gids` on the same segment."""
     dist, ids = ops.fused_query_topk(q, db, cands, k, p=cfg.p,
                                      backend=backend)

@@ -54,8 +54,8 @@ and the span ring are flushed every loop step to ``DIR/metrics.jsonl``
 text), enough for an external reader to reconstruct QPS, per-stage latency,
 device balance, WAL fsync latency and the recall gauge without touching the
 process.  ``--trace-sample RATE`` samples that fraction of query traces
-(``--trace-deep`` additionally runs sampled queries through the staged
-engine for per-stage spans); ``--recall-interval`` / ``--recall-probe-size``
+(each sampled query's fan-out, lock waits, telemetry and result copy get
+spans of their own); ``--recall-interval`` / ``--recall-probe-size``
 drive the periodic sampled recall-vs-brute-force probe behind the
 ``serve_recall_proxy`` gauge.
 """
@@ -158,9 +158,6 @@ def main():
     ap.add_argument("--trace-sample", type=float, default=None,
                     help="fraction of query traces to sample (default "
                          "REPRO_TRACE_SAMPLE or 0 = tracing off)")
-    ap.add_argument("--trace-deep", action="store_true",
-                    help="run sampled queries through the staged engine "
-                         "for per-stage spans (default REPRO_TRACE_DEEP)")
     ap.add_argument("--recall-interval", type=int, default=20,
                     help="probe sampled recall vs brute force every this "
                          "many steps (0 = only the final probe)")
@@ -218,9 +215,8 @@ def main():
     from .mesh import make_serve_mesh
 
     compile_cache.enable()
-    if args.trace_sample is not None or args.trace_deep:
-        obs_configure(sample_rate=args.trace_sample,
-                      deep=True if args.trace_deep else None)
+    if args.trace_sample is not None:
+        obs_configure(sample_rate=args.trace_sample)
     exporter = (Exporter.for_directory(args.metrics_dir)
                 if args.metrics_dir else None)
 

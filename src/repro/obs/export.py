@@ -12,7 +12,8 @@ sidecar), one self-describing object per line:
      "labels": {...}, "buckets": [[le, cumulative], ...], "sum": ...,
      "count": ...}
     {"kind": "span", "ts": ..., "trace_id": ..., "span_id": ...,
-     "parent_id": ..., "name": ..., "t0": ..., "t1": ..., "attrs": {...}}
+     "parent_id": ..., "name": ..., "t0": ..., "t1": ..., "thread": ...,
+     "attrs": {...}}
 
 Every flush writes one full metric snapshot stamped with a shared ``ts``,
 so a reader reconstructs rates (QPS, fsync/s) from counter deltas between

@@ -48,7 +48,7 @@ class MetricSpec:
     labels: Tuple[str, ...] = ()
     required: bool = False         # must appear in the standard telemetry
     #                                smoke export (serve run with WAL +
-    #                                snapshot + shard + recall + deep trace)
+    #                                snapshot + shard + recall + tracing)
     buckets: Tuple[float, ...] = DEFAULT_BUCKETS
 
     def __post_init__(self):
@@ -67,7 +67,7 @@ def _catalog(*specs: MetricSpec) -> Dict[str, MetricSpec]:
 
 #: The documented metric schema.  ``required=True`` entries form the
 #: contract of the CI telemetry smoke: a standard serve run (WAL on,
-#: snapshot at exit, sharded mesh, periodic recall probe, deep tracing)
+#: snapshot at exit, sharded mesh, periodic recall probe, full tracing)
 #: must export every one of them.  Everything else is situational (faults
 #: only fire under an installed plan, restores only happen on recovery,
 #: router load only exists when replication routes).
@@ -120,6 +120,14 @@ CATALOG: Dict[str, MetricSpec] = _catalog(
                ("tenant",)),
     MetricSpec("rerank_survivor_frac", "gauge",
                "Fraction of survivor-rerank slots holding real candidates",
+               ("tenant",)),
+    # Published at seal and compaction (read back once per sealed segment,
+    # never on the query path): what full buckets cost the sealed segments.
+    MetricSpec("index_bucket_overflow_slots", "gauge",
+               "Bucket placements dropped from full buckets, summed over "
+               "sealed segments", ("tenant",)),
+    MetricSpec("index_unreachable_items", "gauge",
+               "Sealed items held by no table (no query can find them)",
                ("tenant",)),
     # -- write path ------------------------------------------------------
     MetricSpec("wal_appends_total", "counter",

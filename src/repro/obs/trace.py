@@ -6,11 +6,6 @@ by the ``REPRO_TRACE_*`` knob family:
 ==========================  =================================================
 ``REPRO_TRACE_SAMPLE``      trace sampling rate in [0, 1] (default 0: off)
 ``REPRO_TRACE_BUFFER``      span ring-buffer capacity (default 4096)
-``REPRO_TRACE_DEEP``        1 -> sampled queries run the *staged* engine
-                            (separate hash/probe/gather/rerank programs with
-                            per-stage device sync) so every pipeline stage
-                            gets its own span; default 0 -> coarse spans
-                            around existing host-call boundaries only
 ==========================  =================================================
 
 Semantics:
@@ -31,12 +26,20 @@ Semantics:
   the exporter drains it.  Stage-taxonomy spans also observe the
   ``serve_stage_latency_s`` histogram so stage timings survive in metrics
   after the ring has rotated.
+- A sampled ``span()`` also enters a ``jax.profiler.TraceAnnotation`` of
+  the same name, so while the JAX profiler records, the span lands on the
+  trace's host plane, on the device trace's clock, on the thread that
+  opened it (the entry's ``thread``).  ``record()`` spans are retroactive
+  and stay host-only.
+- ``locked(lock, name)`` acquires a lock for a ``with`` block; inside a
+  sampled trace the wait to acquire it is a span of its own.
 
 Cost contract (invariant 8, docs/architecture.md): with sampling off every
-hook is a no-op behind one attribute load and the query path executes the
-identical fused programs -- results are bit-identical to an untraced
-process.  With sampling on, overhead is bounded and benched
-(``trace_overhead_frac`` in bench_serve, gated in CI).
+hook is a no-op behind one attribute load (no span, no profiler
+annotation, no thread id, no timed acquire) and the query path executes the
+identical programs -- results are bit-identical to an untraced process.
+With sampling on, overhead is bounded and benched (``trace_overhead_frac``
+in bench_serve, gated in CI).
 """
 
 from __future__ import annotations
@@ -52,14 +55,14 @@ from . import metrics as _metrics
 
 _ENV_SAMPLE = "REPRO_TRACE_SAMPLE"
 _ENV_BUFFER = "REPRO_TRACE_BUFFER"
-_ENV_DEEP = "REPRO_TRACE_DEEP"
 
 #: Span names that feed the ``serve_stage_latency_s{tenant,stage}``
 #: histogram (the stage taxonomy -- see docs/architecture.md).
 STAGE_SPANS = frozenset({
     "request", "admission", "embed", "batch",
-    "hash", "probe", "gather", "rerank", "merge", "fanin",
-    "query.segments", "query.collective",
+    "index.lock_wait", "query.segments", "query.collective",
+    "fanout.telemetry", "survivor.gather", "survivor.rerank", "result.sync",
+    "write.apply",
     "wal.append", "wal.fsync", "seal", "compact",
     "ckpt.save", "ckpt.restore", "recover.restore", "recover.replay",
     "tenant.load", "tenant.unload", "tenant.update",
@@ -104,9 +107,21 @@ class _Noop:
 _NOOP = _Noop()
 
 
+def _annotate(name: str):
+    """An entered ``jax.profiler.TraceAnnotation`` named ``name`` (None
+    where JAX is not installed): the span's mirror in a profiler trace."""
+    try:
+        from jax.profiler import TraceAnnotation
+    except ImportError:
+        return None
+    ann = TraceAnnotation(name)
+    ann.__enter__()
+    return ann
+
+
 class Span:
     __slots__ = ("tracer", "ctx", "name", "attrs", "span_id", "parent_id",
-                 "t0", "t1", "owns_ctx")
+                 "t0", "t1", "owns_ctx", "thread", "annotation")
 
     def __init__(self, tracer: "Tracer", ctx: TraceContext, name: str,
                  attrs: dict, owns_ctx: bool):
@@ -119,6 +134,8 @@ class Span:
         self.parent_id: Optional[int] = None
         self.t0 = 0.0
         self.t1 = 0.0
+        self.thread = threading.get_ident()
+        self.annotation = None
 
     def set(self, **attrs) -> None:
         self.attrs.update(attrs)
@@ -128,11 +145,14 @@ class Span:
             self.tracer._tl.ctx = self.ctx
         self.parent_id = self.ctx.stack[-1] if self.ctx.stack else None
         self.ctx.stack.append(self.span_id)
+        self.annotation = _annotate(self.name)
         self.t0 = self.tracer.clock()
         return self
 
     def __exit__(self, *exc):
         self.t1 = self.tracer.clock()
+        if self.annotation is not None:
+            self.annotation.__exit__(None, None, None)
         if self.ctx.stack and self.ctx.stack[-1] == self.span_id:
             self.ctx.stack.pop()
         if self.owns_ctx:
@@ -179,12 +199,32 @@ class _Attach:
         return False
 
 
+class _TimedAcquire:
+    """Acquires a lock under a span of its own (sampled traces only)."""
+
+    __slots__ = ("tracer", "lock", "name", "attrs")
+
+    def __init__(self, tracer: "Tracer", lock, name: str, attrs: dict):
+        self.tracer = tracer
+        self.lock = lock
+        self.name = name
+        self.attrs = attrs
+
+    def __enter__(self):
+        with self.tracer.span(self.name, **self.attrs):
+            self.lock.acquire()
+        return self
+
+    def __exit__(self, *exc):
+        self.lock.release()
+        return False
+
+
 class Tracer:
     """Process tracer: sampling, context propagation, span ring buffer."""
 
     def __init__(self, sample_rate: Optional[float] = None,
                  buffer: Optional[int] = None,
-                 deep: Optional[bool] = None,
                  clock=time.perf_counter,
                  metrics: Optional[_metrics.MetricsRegistry] = None,
                  seed: int = 0):
@@ -192,10 +232,7 @@ class Tracer:
             sample_rate = float(os.environ.get(_ENV_SAMPLE, "0") or 0)
         if buffer is None:
             buffer = int(os.environ.get(_ENV_BUFFER, "4096") or 4096)
-        if deep is None:
-            deep = os.environ.get(_ENV_DEEP, "0").lower() in ("1", "true")
         self.sample_rate = float(sample_rate)
-        self.deep = bool(deep)
         self.clock = clock
         self.metrics = _metrics.registry() if metrics is None else metrics
         self._seed = seed
@@ -238,6 +275,14 @@ class Tracer:
         ctx = getattr(self._tl, "ctx", None)
         return ctx is not None and ctx.sampled
 
+    def locked(self, lock, name: str, **attrs):
+        """``lock`` for a ``with`` block.  Inside a sampled trace the wait
+        to acquire it is recorded as span ``name``; otherwise this is
+        ``lock`` itself, untimed."""
+        if not self.sampled():
+            return lock
+        return _TimedAcquire(self, lock, name, attrs)
+
     # -- spans -----------------------------------------------------------
 
     def span(self, name: str, **attrs):
@@ -276,6 +321,7 @@ class Tracer:
             "name": span.name,
             "t0": span.t0,
             "t1": span.t1,
+            "thread": span.thread,
             "attrs": span.attrs,
         }
         with self._lock:
@@ -308,7 +354,6 @@ class Tracer:
         with self._lock:
             return {
                 "sample_rate": self.sample_rate,
-                "deep": self.deep,
                 "traces_started": self.n_traces,
                 "spans_recorded": self.n_spans,
                 "spans_buffered": len(self._ring),
@@ -325,16 +370,13 @@ def tracer() -> Tracer:
 
 def configure(sample_rate: Optional[float] = None,
               buffer: Optional[int] = None,
-              deep: Optional[bool] = None,
               clock=None, seed: Optional[int] = None) -> Tracer:
     """Reconfigure the process tracer in place (None keeps the current
-    value).  Used by ``launch/serve --trace-sample/--trace-deep``, benches,
-    and tests; the ring buffer is replaced, not drained."""
+    value).  Used by ``launch/serve --trace-sample``, benches, and tests;
+    the ring buffer is replaced, not drained."""
     t = _tracer
     if sample_rate is not None:
         t.sample_rate = float(sample_rate)
-    if deep is not None:
-        t.deep = bool(deep)
     if clock is not None:
         t.clock = clock
     if seed is not None:

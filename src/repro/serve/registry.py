@@ -249,7 +249,8 @@ class Servable:
 
     def _raw_query(self, queries, k: int, n_probes: int):
         g, d = self.index.query(queries, k, n_probes=n_probes)
-        return np.asarray(g), np.asarray(d)
+        with obs_trace.tracer().span("result.sync", tenant=self.spec.name):
+            return np.asarray(g), np.asarray(d)
 
     def submit_query(self, queries, k: int, n_probes: int = 1):
         """Admission-queue path: returns a Future of (gids, dists)."""
@@ -268,6 +269,9 @@ class Servable:
                             "n_batches": self.batcher.n_batches,
                             "n_requests": self.batcher.n_requests},
                 "occupancy": occupancy_report(self.index),
+                # read back here, not at seal (publishes the bucket gauges
+                # before the metrics summary below is taken)
+                "buckets": self.index.bucket_overflow(),
                 "shard_layout": self.index.shard_layout(),
                 # which kernel/query/hash/embed paths this process resolves
                 # to right now (env overrides included)

@@ -59,6 +59,7 @@ is the ``LSHIndexState`` pytree plus a (capacity,) gid vector and live mask.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import functools
 import threading
@@ -96,6 +97,10 @@ class Segment:
     # fp32 tenants and on the mutable delta, which stays fp32 until sealed):
     scale: Optional[Array] = None     # () f32 symmetric dequant scale
     pool: Optional[np.ndarray] = None  # (capacity, N) f32 survivor side pool
+    # (bucket placements dropped, items no table holds): dispatched on the
+    # device at seal (``core.index.bucket_overflow``), read back to ints
+    # once by ``read_bucket_health``; never serialized
+    bucket_health: Optional[tuple] = None
     # Incremental re-placement fingerprints (``sharding.placement`` diffs):
     # computed lazily, cached only for sealed segments, live half
     # invalidated on tombstone flips.  Never serialized.
@@ -128,6 +133,15 @@ class Segment:
             self._live_key = zlib.crc32(np.asarray(self.live).tobytes())
         return (self._content_key, self._live_key)
 
+    def read_bucket_health(self) -> Tuple[int, int]:
+        """``bucket_health`` as host ints (computed here for a segment
+        sealed without it, e.g. one restored from a snapshot)."""
+        if self.bucket_health is None:
+            self.bucket_health = lidx.bucket_overflow(
+                self.state, jnp.int32(self.n_items))
+        self.bucket_health = tuple(int(x) for x in self.bucket_health)
+        return self.bucket_health
+
     def occupancy(self) -> dict:
         cap = self.capacity
         return {
@@ -149,12 +163,14 @@ def _segment_query_fn(cfg: IndexConfig, k: int, n_probes: int,
     all indexes with the same config, so segment count never multiplies
     compilations."""
 
-    def f(state: LSHIndexState, q: Array, live: Array, gids: Array):
+    # the function's name is the program's name in a profiler trace
+    def segment_query(state: LSHIndexState, q: Array, live: Array,
+                      gids: Array):
         return lidx.query_index_gids(state, cfg, q, k, gids,
                                      n_probes=n_probes, backend=backend,
                                      live_mask=live)
 
-    return jax.jit(f)
+    return jax.jit(segment_query)
 
 
 @functools.lru_cache(maxsize=64)
@@ -164,52 +180,24 @@ def _quantized_segment_query_fn(cfg: IndexConfig, k: int, n_probes: int,
     scored in code space against the segment's int8/bf16 ``db`` with one
     per-segment dequant ``scale`` -- no fp32 decode of the stored rows."""
 
-    def f(state: LSHIndexState, q: Array, live: Array, gids: Array,
-          scale: Array):
+    def segment_query_codes(state: LSHIndexState, q: Array, live: Array,
+                            gids: Array, scale: Array):
         return lidx.query_index_gids_quantized(state, cfg, q, k, gids, scale,
                                                n_probes=n_probes,
                                                backend=backend,
                                                live_mask=live)
 
-    return jax.jit(f)
-
-
-@functools.lru_cache(maxsize=64)
-def _staged_family_fns(cfg: IndexConfig, n_probes: int):
-    """Hash + probe stages as standalone programs (deep-traced queries).
-
-    All segments share one family, so the staged engine runs these ONCE
-    per query batch -- hoisted out of the per-segment loop the fused
-    program repeats them in -- and the stage functions are the very ones
-    the fused ``query_index`` body calls, so staged results stay bitwise
-    equal (asserted in tests/test_obs.py)."""
-    hash_fn = jax.jit(
-        lambda alpha, b, q: lidx.hash_stage(alpha, b, cfg, q))
-    probe_fn = jax.jit(
-        lambda mix, h, pj: lidx.probe_stage(mix, cfg, h, pj, n_probes))
-    return hash_fn, probe_fn
-
-
-@functools.lru_cache(maxsize=64)
-def _staged_segment_fns(cfg: IndexConfig, k: int, backend: Optional[str]):
-    """Gather + rerank stages per segment (deep-traced queries)."""
-    gather_fn = jax.jit(
-        lambda table, live, buckets: lidx.gather_stage(
-            table, buckets, cfg, live.shape[0], live_mask=live))
-    rerank_fn = jax.jit(
-        lambda db, gids, q, cands: lidx.rerank_stage(
-            db, gids, cfg, q, cands, k, backend=backend))
-    return gather_fn, rerank_fn
+    return jax.jit(segment_query_codes)
 
 
 @functools.lru_cache(maxsize=64)
 def _segment_insert_fn(cfg: IndexConfig, chunk: int):
     """One compiled incremental-insert program per (cfg, chunk shape)."""
 
-    def f(state: LSHIndexState, emb: Array, start, n_valid):
+    def segment_insert(state: LSHIndexState, emb: Array, start, n_valid):
         return lidx.insert_items(state, cfg, emb, start, n_valid)
 
-    return jax.jit(f)
+    return jax.jit(segment_insert)
 
 
 class SegmentedIndex:
@@ -348,13 +336,20 @@ class SegmentedIndex:
         with self._lock:
             if self.delta.n_items == 0:
                 return
-            with obs_trace.tracer().span("seal", tenant=self.tenant,
-                                         rows=self.delta.n_items):
+            tr = obs_trace.tracer()
+            with tr.span("seal", tenant=self.tenant,
+                         rows=self.delta.n_items) as sp:
                 self._log(walmod.encode_seal())
                 # mid-seal crash point: the SEAL record is durable-framed
                 # but the segment mutation below has not happened yet
                 faults.fire("seal")
+                sealed = self.delta
                 self._seal()
+                if tr.sampled():
+                    # only a recorded span pays for the readback
+                    dropped, unreachable = sealed.read_bucket_health()
+                    sp.set(overflow_slots=dropped,
+                           unreachable_items=unreachable)
 
     def _seal(self) -> None:
         """Apply a seal (callers hold the lock; never logs).
@@ -372,6 +367,11 @@ class SegmentedIndex:
             return
         if self.precision != "fp32":
             self._quantize_segment(self.delta)
+        # dispatched, not read back: a readback here would stall every
+        # seal of a bulk load until the device drains; the counts are read
+        # at report time (``bucket_overflow``)
+        self.delta.bucket_health = lidx.bucket_overflow(
+            self.delta.state, jnp.int32(self.delta.n_items))
         self.delta.sealed = True
         self._open_segment()
         self._version += 1
@@ -402,6 +402,25 @@ class SegmentedIndex:
         nbytes = sum(int(s.state.db.nbytes) for s in sealed)
         obs_metrics.registry().set("store_bytes_per_item", nbytes / items,
                                    tenant=self.tenant)
+
+    def bucket_overflow(self) -> dict:
+        """Over the sealed segments: bucket placements dropped because the
+        bucket was full (``overflow_slots``) and items no table holds, so
+        no query finds (``unreachable_items``).  Reads each segment's
+        counts back once and publishes them as the gauges
+        ``index_bucket_overflow_slots`` / ``index_unreachable_items``;
+        called at report time, never on the query path."""
+        with self._lock:
+            sealed = [s for s in self.segments[:-1] if s.n_items > 0]
+        health = [s.read_bucket_health() for s in sealed]
+        out = {"overflow_slots": sum(h[0] for h in health),
+               "unreachable_items": sum(h[1] for h in health)}
+        reg = obs_metrics.registry()
+        reg.set("index_bucket_overflow_slots", out["overflow_slots"],
+                tenant=self.tenant)
+        reg.set("index_unreachable_items", out["unreachable_items"],
+                tenant=self.tenant)
+        return out
 
     # -- durability ---------------------------------------------------------
 
@@ -650,7 +669,11 @@ class SegmentedIndex:
                 f"embeddings contain NaN/Inf in {bad} of {emb.shape[0]} "
                 f"rows; rejecting the batch (nothing was inserted)")
         m = emb.shape[0]
-        with self._lock:
+        tr = obs_trace.tracer()
+        with tr.locked(self._lock, "index.lock_wait", op="insert",
+                       tenant=self.tenant), \
+                tr.span("write.apply", tenant=self.tenant, op="insert",
+                        rows=m):
             # gid allocation + uniqueness checks must sit inside the lock or
             # two concurrent inserts hand out the same id range
             if gids is None:
@@ -706,8 +729,12 @@ class SegmentedIndex:
 
     def delete(self, gids: Sequence[int]) -> int:
         """Tombstone items by global id; returns how many were live."""
-        with self._lock:
-            req = np.asarray(gids).ravel().astype(np.int32)
+        req = np.asarray(gids).ravel().astype(np.int32)
+        tr = obs_trace.tracer()
+        with tr.locked(self._lock, "index.lock_wait", op="delete",
+                       tenant=self.tenant), \
+                tr.span("write.apply", tenant=self.tenant, op="delete",
+                        rows=int(req.size)):
             if req.size:
                 # logged as requested (not as applied): deletes are
                 # idempotent, so replaying a delete of already-dead or
@@ -908,67 +935,93 @@ class SegmentedIndex:
         fan-out runs SPMD instead (one collective program over the mesh)
         with bit-identical results.
 
-        Tracing: inside a sampled trace with deep tracing on
-        (``REPRO_TRACE_DEEP``), the query runs the *staged* engine instead
-        -- hash/probe once from the shared family, then per-segment
-        gather/rerank and the merge/fan-in as separately-jitted programs,
-        each under its own span with a device sync so stage wall-clock is
-        real.  Results are bit-identical to the fused path (same stage
-        functions, same op order -- asserted in tests); unsampled queries
-        never touch it, which is what makes invariant 8 structural.
+        Tracing (sampled traces only): ``index.lock_wait``, then
+        ``query.segments`` (the fan-out's dispatch and merge) or
+        ``query.collective`` (the sharded program), then
+        ``fanout.telemetry``.
         """
         q = jnp.asarray(queries, jnp.float32)
         if self.precision != "fp32":
-            # quantized tiers run the survivor-rerank engine; the deep-
-            # trace staged engine stays fp32-only by design (its stage
-            # functions are the exact-path ones)
+            # quantized tiers run the survivor-rerank engine
             return self._query_quantized(q, k, n_probes)
-        tr = obs_trace.tracer()
-        if tr.deep and tr.sampled():
-            return self._query_staged(q, k, n_probes, tr)
-        with self._lock:
-            self.query_shapes.add((int(q.shape[0]), k, n_probes))
-            if self._mesh is not None:
-                pl = self._current_placement()
-                # replica selection per micro-batch: the router activates
-                # one instance per sealed segment so replicated devices
-                # alternate; without a router every instance answers and
-                # the collective fan-in dedups by gid -- both bit-identical
-                plan = self._router.route() if self._router else None
-                g, d = distributed.query_segments_sharded(
-                    pl, self.cfg, q, k, n_probes=n_probes,
-                    backend=self.backend,
-                    active=None if plan is None else plan.active)
-            else:
-                g = None
-                seg_ids = [i for i, s in enumerate(self.segments)
-                           if s.n_live > 0]
-                fn = _segment_query_fn(self.cfg, k, n_probes, self.backend)
-                shards = [fn(self.segments[i].state, q, self.segments[i].live,
-                             self.segments[i].gids) for i in seg_ids]
-        if g is not None:
-            # sharded path: the device->host sync and attribution loop run
-            # OUTSIDE the lock, like the unsharded telemetry below --
-            # writers must not stall behind a collective readback
-            if self._on_fanout is not None:
-                self._fanout_telemetry(np.asarray(g), plan=plan)
-            return g, d
-        if not shards:
+        g, d, seg_ids, shards, plan = self._fan_out(
+            q, k, n_probes, (int(q.shape[0]), k, n_probes))
+        if g is None:
             return (jnp.full((q.shape[0], k), -1, jnp.int32),
                     jnp.full((q.shape[0], k), jnp.inf, jnp.float32))
-        if len(shards) == 1:
-            g, d = _merged(shards[0][1], shards[0][0], k)
-            # single segment is already top-k; merge only to normalise tie
-            # order so results don't depend on the segment count
-        else:
-            g_all = jnp.concatenate([g for g, _ in shards], axis=1)
-            d_all = jnp.concatenate([d for _, d in shards], axis=1)
-            g, d = _merged(d_all, g_all, k)
         if self._on_fanout is not None:
-            self._fanout_telemetry(
-                np.asarray(g), seg_ids,
-                [np.asarray(sg) for sg, _ in shards])
+            self._fanout_telemetry(g, seg_ids, shards, plan=plan)
         return g, d
+
+    def _fan_out(self, q: Array, width: int, n_probes: int, shape: tuple):
+        """Every live segment's top-``width`` for ``q``, merged:
+        ``(gids, dists, seg_ids, shards, plan)``.
+
+        The per-segment programs (or the sharded collective) are dispatched
+        under the index lock; the unsharded merge runs after it is
+        released.  Sealed quantized segments score in code space
+        (``width`` is then the survivor width); fp32 segments score
+        exactly.  ``seg_ids``/``shards`` are the unsharded fan-out's
+        inputs to the telemetry (None when sharded); ``gids`` is None when
+        no segment holds a live item.
+        """
+        tr = obs_trace.tracer()
+        quantized = self.precision != "fp32"
+        # the dispatch span opens under the lock and closes after the merge,
+        # which runs once the lock is released
+        with contextlib.ExitStack() as dispatch_span:
+            with tr.locked(self._lock, "index.lock_wait", op="query",
+                           tenant=self.tenant):
+                self.query_shapes.add(shape)
+                if self._mesh is not None:
+                    pl = self._current_placement()
+                    # replica selection per micro-batch: the router
+                    # activates one instance per sealed segment so
+                    # replicated devices alternate; without a router every
+                    # instance answers and the collective fan-in dedups by
+                    # gid -- both bit-identical
+                    plan = self._router.route() if self._router else None
+                    with tr.span("query.collective", tenant=self.tenant,
+                                 devices=pl.n_dev, per_dev=pl.per_dev):
+                        g, d = distributed.query_segments_sharded(
+                            pl, self.cfg, q, width, n_probes=n_probes,
+                            backend=self.backend,
+                            active=None if plan is None else plan.active,
+                            quantized=quantized)
+                    return g, d, None, None, plan
+                seg_ids = [i for i, s in enumerate(self.segments)
+                           if s.n_live > 0]
+                exact = _segment_query_fn(self.cfg, width, n_probes,
+                                          self.backend)
+                codes = (_quantized_segment_query_fn(
+                    self.cfg, width, n_probes, self.backend)
+                    if quantized else None)
+                # programs: one per segment, then two concatenates and the
+                # merge (the merge alone for a single segment)
+                dispatch_span.enter_context(tr.span(
+                    "query.segments", tenant=self.tenant,
+                    segments=len(seg_ids),
+                    programs=len(seg_ids) + (3 if len(seg_ids) > 1 else 1)))
+                shards = []
+                for i in seg_ids:
+                    seg = self.segments[i]
+                    if codes is not None and seg.scale is not None:
+                        shards.append(codes(seg.state, q, seg.live, seg.gids,
+                                            seg.scale))
+                    else:   # fp32 tiers, and the delta of a quantized one
+                        shards.append(exact(seg.state, q, seg.live,
+                                            seg.gids))
+            if not shards:
+                return None, None, seg_ids, shards, None
+            if len(shards) == 1:
+                # single segment is already top-k; merge only to normalise
+                # tie order so results don't depend on the segment count
+                g, d = _merged(shards[0][1], shards[0][0], width)
+            else:
+                g_all = jnp.concatenate([sg for sg, _ in shards], axis=1)
+                d_all = jnp.concatenate([sd for _, sd in shards], axis=1)
+                g, d = _merged(d_all, g_all, width)
+        return g, d, seg_ids, shards, None
 
     def _query_quantized(self, q: Array, k: int, n_probes: int
                          ) -> Tuple[Array, Array]:
@@ -984,55 +1037,29 @@ class SegmentedIndex:
         (distance, gid) order, so any survivor set containing the true
         top-k yields exactly the fp32 answer.  Sharded and unsharded paths
         agree because the rerank is a pure function of the survivor set.
+        Stage 2 runs under the ``survivor.gather`` and ``survivor.rerank``
+        spans.
         """
         kq = quantize.survivor_width(
             k, self.survivor_k,
             self.cfg.n_tables * n_probes * self.cfg.bucket_capacity)
-        with self._lock:
-            self.query_shapes.add((int(q.shape[0]), k, n_probes))
-            if self._mesh is not None:
-                pl = self._current_placement()
-                plan = self._router.route() if self._router else None
-                g, d = distributed.query_segments_sharded(
-                    pl, self.cfg, q, kq, n_probes=n_probes,
-                    backend=self.backend,
-                    active=None if plan is None else plan.active,
-                    quantized=True)
-            else:
-                g = None
-                seg_ids = [i for i, s in enumerate(self.segments)
-                           if s.n_live > 0]
-                exact = _segment_query_fn(self.cfg, kq, n_probes,
-                                          self.backend)
-                qfn = _quantized_segment_query_fn(self.cfg, kq, n_probes,
-                                                  self.backend)
-                shards = []
-                for i in seg_ids:
-                    seg = self.segments[i]
-                    if seg.scale is not None:
-                        shards.append(qfn(seg.state, q, seg.live, seg.gids,
-                                          seg.scale))
-                    else:   # the delta (and any not-yet-sealed segment)
-                        shards.append(exact(seg.state, q, seg.live,
-                                            seg.gids))
+        g, _, _, _, _ = self._fan_out(q, kq, n_probes,
+                                      (int(q.shape[0]), k, n_probes))
         if g is None:
-            if not shards:
-                return (jnp.full((q.shape[0], k), -1, jnp.int32),
-                        jnp.full((q.shape[0], k), jnp.inf, jnp.float32))
-            if len(shards) == 1:
-                g, _ = _merged(shards[0][1], shards[0][0], kq)
-            else:
-                g_all = jnp.concatenate([sg for sg, _ in shards], axis=1)
-                d_all = jnp.concatenate([sd for _, sd in shards], axis=1)
-                g, _ = _merged(d_all, g_all, kq)
+            return (jnp.full((q.shape[0], k), -1, jnp.int32),
+                    jnp.full((q.shape[0], k), jnp.inf, jnp.float32))
         # survivor rescore: host-gather the exact rows, rerank on device
-        g_np = np.asarray(g).copy()
-        rows = self._survivor_rows(g_np)
-        g, d = quantize.rerank_survivors(q, jnp.asarray(rows),
-                                         jnp.asarray(g_np), k,
-                                         p=self.cfg.p)
+        tr = obs_trace.tracer()
+        with tr.span("survivor.gather", tenant=self.tenant,
+                     rows=int(q.shape[0]), width=kq):
+            g_np = np.asarray(g).copy()
+            rows = self._survivor_rows(g_np)
+        with tr.span("survivor.rerank", tenant=self.tenant, width=kq):
+            g, d = quantize.rerank_survivors(q, jnp.asarray(rows),
+                                             jnp.asarray(g_np), k,
+                                             p=self.cfg.p)
         if self._on_fanout is not None:
-            self._fanout_telemetry(np.asarray(g))
+            self._fanout_telemetry(g)
         if g_np.size:
             obs_metrics.registry().set("rerank_survivor_frac",
                                        float((g_np >= 0).mean()),
@@ -1051,7 +1078,8 @@ class SegmentedIndex:
         """
         nq, m = g_np.shape
         rows = np.zeros((nq, m, self.cfg.n_dims), np.float32)
-        with self._lock:
+        with obs_trace.tracer().locked(self._lock, "index.lock_wait",
+                                       op="gather", tenant=self.tenant):
             host_db: dict = {}
             for qi in range(nq):
                 for j in range(m):
@@ -1074,152 +1102,77 @@ class SegmentedIndex:
                         rows[qi, j] = db[slot]
         return rows
 
-    def _query_staged(self, q: Array, k: int, n_probes: int,
-                      tr) -> Tuple[Array, Array]:
-        """Deep-traced query: the fused pipeline split at stage boundaries.
-
-        Same lock discipline, same telemetry, same results as
-        :meth:`query` -- only the program granularity differs (and hash +
-        probe run once instead of once per segment, since every segment
-        shares ``self.family``).  Each stage ends with a
-        ``block_until_ready`` so its span measures device time, not
-        dispatch time."""
-        alpha, b, mix = self.family
-        hash_fn, probe_fn = _staged_family_fns(self.cfg, n_probes)
-        with tr.span("hash", tenant=self.tenant, rows=int(q.shape[0]),
-                     backend=dispatch.hash_backend()):
-            h, pj = hash_fn(alpha, b, q)
-            jax.block_until_ready((h, pj))
-        with tr.span("probe", tenant=self.tenant, n_probes=n_probes):
-            buckets = probe_fn(mix, h, pj)
-            jax.block_until_ready(buckets)
-        plan = None
-        with self._lock:
-            self.query_shapes.add((int(q.shape[0]), k, n_probes))
-            if self._mesh is not None:
-                pl = self._current_placement()
-                plan = self._router.route() if self._router else None
-                active = jnp.ones((pl.n_dev * pl.per_dev,), jnp.bool_) \
-                    if plan is None else jnp.asarray(plan.active, jnp.bool_)
-                parts = distributed.staged_sharded_parts(
-                    self.cfg, k, self.backend, pl.mesh, pl.axis, pl.per_dev)
-                with tr.span("gather", tenant=self.tenant,
-                             segments=pl.n_sealed, devices=pl.n_dev):
-                    sc, dc = parts.gather(pl.sealed_state.table,
-                                          pl.sealed_live,
-                                          pl.delta_state.table,
-                                          pl.delta_live, buckets)
-                    jax.block_until_ready((sc, dc))
-                with tr.span("rerank", tenant=self.tenant,
-                             backend=self.backend):
-                    pg, pd = parts.rerank(pl.sealed_state.db, pl.sealed_gids,
-                                          active, sc, pl.delta_state.db,
-                                          pl.delta_gids, dc, q)
-                    jax.block_until_ready((pg, pd))
-                with tr.span("merge", tenant=self.tenant):
-                    g_loc, d_loc = parts.merge(pg, pd)
-                    jax.block_until_ready((g_loc, d_loc))
-                with tr.span("fanin", tenant=self.tenant, devices=pl.n_dev):
-                    g, d = parts.fanin(g_loc, d_loc)
-                    jax.block_until_ready((g, d))
-                seg_ids = None
-            else:
-                g = None
-                seg_ids = [i for i, s in enumerate(self.segments)
-                           if s.n_live > 0]
-                gather_fn, rerank_fn = _staged_segment_fns(self.cfg, k,
-                                                           self.backend)
-                with tr.span("gather", tenant=self.tenant,
-                             segments=len(seg_ids)):
-                    cands = [gather_fn(self.segments[i].state.table,
-                                       self.segments[i].live, buckets)
-                             for i in seg_ids]
-                    jax.block_until_ready(cands)
-                with tr.span("rerank", tenant=self.tenant,
-                             backend=self.backend):
-                    shards = [rerank_fn(self.segments[i].state.db,
-                                        self.segments[i].gids, q, c)
-                              for i, c in zip(seg_ids, cands)]
-                    jax.block_until_ready(shards)
-        if g is not None:
-            if self._on_fanout is not None:
-                self._fanout_telemetry(np.asarray(g), plan=plan)
-            return g, d
-        if not shards:
-            return (jnp.full((q.shape[0], k), -1, jnp.int32),
-                    jnp.full((q.shape[0], k), jnp.inf, jnp.float32))
-        with tr.span("merge", tenant=self.tenant, shards=len(shards)):
-            if len(shards) == 1:
-                g, d = _merged(shards[0][1], shards[0][0], k)
-            else:
-                g_all = jnp.concatenate([g for g, _ in shards], axis=1)
-                d_all = jnp.concatenate([d for _, d in shards], axis=1)
-                g, d = _merged(d_all, g_all, k)
-            jax.block_until_ready((g, d))
-        if self._on_fanout is not None:
-            self._fanout_telemetry(
-                np.asarray(g), seg_ids,
-                [np.asarray(sg) for sg, _ in shards])
-        return g, d
-
-    def _fanout_telemetry(self, g_np: np.ndarray,
+    def _fanout_telemetry(self, g: Array,
                           seg_ids: Optional[List[int]] = None,
-                          shard_gs: Optional[List[np.ndarray]] = None,
+                          shards: Optional[list] = None,
                           plan=None) -> None:
         """Attribute one merged top-k back to segments/devices and feed the
         ``on_fanout`` hook (ServingStats.record_fanout signature).
 
         Wins come from the merged gids via the locator (gids are globally
         unique, so the winning segment is unambiguous); candidate counts
-        are the valid rows each unsharded shard offered the merge; device
-        wins map segments through the live placement's assignment (delta ->
-        rank 0, matching the collective program).  When a router ``plan``
-        routed this batch, the win goes to the replica that actually
-        answered and the hook additionally receives the plan's per-device
-        instance load (4th argument -- only ever passed on routed batches,
-        so factor-1 deployments keep the 3-argument hook contract).
+        are the valid rows each unsharded shard (``shards``, the fan-out's
+        device (gids, dists) pairs) offered the merge; device wins map
+        segments through the live placement's assignment (delta -> rank 0,
+        matching the collective program).  When a router ``plan`` routed
+        this batch, the win goes to the replica that actually answered and
+        the hook additionally receives the plan's per-device instance load
+        (4th argument -- only ever passed on routed batches, so factor-1
+        deployments keep the 3-argument hook contract).  Runs under the
+        ``fanout.telemetry`` span, host copies included.
         """
-        with self._lock:
-            n_segs = len(self.segments)
-            wins = [0] * n_segs
-            for gid in g_np.ravel().tolist():
-                if gid < 0:
-                    continue
-                loc = self._locator.get(int(gid))
-                if loc is not None:
-                    wins[loc[0]] += 1
-            cands = None
-            if seg_ids is not None:
-                cands = [0] * n_segs
-                for si, sg in zip(seg_ids, shard_gs):
-                    if si < n_segs:     # a concurrent compact may have
-                        cands[si] = int((sg >= 0).sum())  # shrunk the list
-            dev_wins = None
-            if self._mesh is not None and self._placement is not None:
-                pl = self._placement
-                sealed_pos = [i for i, s in enumerate(self.segments[:-1])
-                              if s.n_live > 0]
-                dev_of = {n_segs - 1: 0}          # delta contributes on rank 0
-                if plan is not None:
-                    # routed batch: attribute to the chosen replica
-                    for fi, dev in plan.dev_of.items():
-                        if fi < len(sealed_pos):
-                            dev_of[sealed_pos[fi]] = dev
-                else:
-                    for dev, block in enumerate(pl.assignment):
-                        for fi in block:
-                            if fi < len(sealed_pos):  # placement may lag a
-                                # concurrent mutation; replicas (instance
-                                # duplicates) attribute to the first holder
-                                dev_of.setdefault(sealed_pos[fi], dev)
-                dev_wins = [0] * pl.n_dev
-                for si, w in enumerate(wins):
-                    if w:
-                        dev_wins[dev_of.get(si, 0)] += w
-        if plan is not None:
-            self._on_fanout(wins, dev_wins, cands, plan.per_device_active)
-        else:
-            self._on_fanout(wins, dev_wins, cands)
+        tr = obs_trace.tracer()
+        with tr.span("fanout.telemetry", tenant=self.tenant,
+                     shards=len(shards) if shards else 0):
+            g_np = np.asarray(g)
+            shard_gs = (None if seg_ids is None
+                        else [np.asarray(sg) for sg, _ in shards])
+            with tr.locked(self._lock, "index.lock_wait", op="telemetry",
+                           tenant=self.tenant):
+                n_segs = len(self.segments)
+                wins = [0] * n_segs
+                for gid in g_np.ravel().tolist():
+                    if gid < 0:
+                        continue
+                    loc = self._locator.get(int(gid))
+                    if loc is not None:
+                        wins[loc[0]] += 1
+                cands = None
+                if seg_ids is not None:
+                    cands = [0] * n_segs
+                    for si, sg in zip(seg_ids, shard_gs):
+                        if si < n_segs:   # a concurrent compact may have
+                            # shrunk the list
+                            cands[si] = int((sg >= 0).sum())
+                dev_wins = None
+                if self._mesh is not None and self._placement is not None:
+                    pl = self._placement
+                    sealed_pos = [i for i, s in
+                                  enumerate(self.segments[:-1])
+                                  if s.n_live > 0]
+                    dev_of = {n_segs - 1: 0}   # delta contributes on rank 0
+                    if plan is not None:
+                        # routed batch: attribute to the chosen replica
+                        for fi, dev in plan.dev_of.items():
+                            if fi < len(sealed_pos):
+                                dev_of[sealed_pos[fi]] = dev
+                    else:
+                        for dev, block in enumerate(pl.assignment):
+                            for fi in block:
+                                # placement may lag a concurrent mutation;
+                                # replicas (instance duplicates) attribute
+                                # to the first holder
+                                if fi < len(sealed_pos):
+                                    dev_of.setdefault(sealed_pos[fi], dev)
+                    dev_wins = [0] * pl.n_dev
+                    for si, w in enumerate(wins):
+                        if w:
+                            dev_wins[dev_of.get(si, 0)] += w
+            if plan is not None:
+                self._on_fanout(wins, dev_wins, cands,
+                                plan.per_device_active)
+            else:
+                self._on_fanout(wins, dev_wins, cands)
 
     def occupancy(self) -> List[dict]:
         return [s.occupancy() for s in self.segments]

@@ -11,7 +11,9 @@ Additionally, every ``REPRO_*`` environment knob the Markdown docs mention
 must correspond to a string literal in the Python tree (``src/``,
 ``benchmarks/``, ``tools/`` -- i.e. a grep-able ``os.environ`` read) -- a
 documented knob nobody reads is exactly the kind of rot this check exists
-for.
+for.  A knob the code stopped reading is listed in
+``docs/retired-knobs.md``: older text (change logs, task statements) may
+still name it, and the check fails if code reads one again.
 
 CI runs this in the docs job so README/docs can't rot silently:
 
@@ -64,6 +66,7 @@ def check_file(path: str, root: str):
 
 _KNOB_RE = re.compile(r"\bREPRO_[A-Z0-9_]+\b")
 _CODE_DIRS = ("src", "benchmarks", "tools")
+RETIRED_KNOBS = os.path.join("docs", "retired-knobs.md")
 
 
 def knobs_in_code(root: str) -> set:
@@ -83,19 +86,30 @@ def knobs_in_code(root: str) -> set:
     return found
 
 
+def retired_knobs(root: str) -> set:
+    """The knobs ``docs/retired-knobs.md`` lists (empty without it)."""
+    path = os.path.join(root, RETIRED_KNOBS)
+    if not os.path.exists(path):
+        return set()
+    with open(path, encoding="utf-8") as f:
+        return set(_KNOB_RE.findall(f.read()))
+
+
 def check_env_knobs(root: str):
-    """-> (stale [(relpath, lineno, knob)], n_knob_mentions_checked)."""
+    """-> (stale [(relpath, lineno, knob)], n_knob_mentions_checked,
+    revived [knob]: retired knobs the code reads again)."""
     known = knobs_in_code(root)
+    retired = retired_knobs(root)
     stale, n_mentions = [], 0
     for md in iter_markdown(root):
         with open(md, encoding="utf-8") as f:
             for lineno, line in enumerate(f, 1):
                 for knob in _KNOB_RE.findall(line):
                     n_mentions += 1
-                    if knob not in known:
+                    if knob not in known and knob not in retired:
                         stale.append((os.path.relpath(md, root), lineno,
                                       knob))
-    return stale, n_mentions
+    return stale, n_mentions, sorted(retired & known)
 
 
 def main(argv=None) -> int:
@@ -110,14 +124,17 @@ def main(argv=None) -> int:
         n_links += file_links
     for path, lineno, target in broken:
         print(f"BROKEN {path}:{lineno}: {target}")
-    stale, n_knobs = check_env_knobs(root)
+    stale, n_knobs, revived = check_env_knobs(root)
     for path, lineno, knob in stale:
         print(f"STALE-KNOB {path}:{lineno}: {knob} is documented but no "
               f"code under {'/'.join(_CODE_DIRS)} reads it")
+    for knob in revived:
+        print(f"RETIRED-KNOB {knob} is listed in {RETIRED_KNOBS} but code "
+              f"under {'/'.join(_CODE_DIRS)} reads it")
     print(f"# checked {n_files} markdown files, {n_links} intra-repo links "
           f"({len(broken)} broken), {n_knobs} env-knob mentions "
           f"({len(stale)} stale)")
-    return 1 if broken or stale else 0
+    return 1 if broken or stale or revived else 0
 
 
 if __name__ == "__main__":

@@ -17,19 +17,21 @@ JSON).  What it asserts:
   drift gate;
 * every catalog entry with ``required=True`` actually appears -- the
   standard smoke exercises queries, WAL, snapshot, sharding, recall and
-  deep tracing, so a required metric missing means an instrumentation
+  full-rate tracing, so a required metric missing means an instrumentation
   point silently dropped off;
 * extra per-leg requirements via ``--require`` (e.g. the 8-device CI leg
   requires ``serve_device_load_total`` and ``router_device_load``, which a
   single-device run legitimately never emits);
 * the export is *sufficient*: QPS reconstructs from ``serve_queries_total``
   deltas between snapshots (> 0), per-stage latency histograms
-  (``serve_stage_latency_s``) have observations for the deep-trace stages,
+  (``serve_stage_latency_s``) have observations for the served-path
+  stages every sampled query and write passes through,
   per-device win/load balance, WAL fsync latency and the recall gauge are
   all readable.
 
 Span lines (``kind: span``) are validated structurally (ids, t1 >= t0)
-and must include at least one query-stage span when deep tracing was on.
+and must include a fan-out span (``query.segments`` unsharded,
+``query.collective`` sharded) when tracing was on.
 
 Exit 0 on a clean export; 1 with a findings list otherwise.
 """
@@ -46,8 +48,11 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 from repro.obs.metrics import CATALOG  # noqa: E402
 
 # stages an out-of-process reader must see latency histograms for after a
-# deep-traced smoke (the staged engine's per-stage spans feed these)
-DEEP_STAGES = ("hash", "probe", "gather", "rerank", "merge")
+# traced smoke, sharded or not (the served path's spans feed these)
+SERVED_STAGES = ("batch", "index.lock_wait", "fanout.telemetry",
+                 "result.sync", "write.apply")
+# one of these is the fan-out of every sampled query
+FANOUT_SPANS = ("query.segments", "query.collective")
 
 SPAN_FIELDS = ("trace_id", "span_id", "name", "t0", "t1")
 
@@ -145,7 +150,7 @@ def reconstruct(seen: dict) -> tuple:
     for m in seen.get("serve_stage_latency_s", []):
         stage_counts[m["labels"].get("stage", "?")] = m.get("count", 0)
     summary["stage_observations"] = stage_counts
-    missing = [s for s in DEEP_STAGES if stage_counts.get(s, 0) <= 0]
+    missing = [s for s in SERVED_STAGES if stage_counts.get(s, 0) <= 0]
     if missing:
         findings.append(f"no latency observations for stage(s) "
                         f"{missing} in serve_stage_latency_s")
@@ -188,11 +193,11 @@ def check_spans(spans: list, want_stage_spans: bool) -> list:
         else:
             if s["t1"] < s["t0"]:
                 findings.append(f"span {s['name']}: t1 < t0")
-            if s["name"] in DEEP_STAGES:
+            if s["name"] in FANOUT_SPANS:
                 stage_seen = True
     if want_stage_spans and not stage_seen:
-        findings.append("no query-stage spans exported (deep tracing was "
-                        "expected to be on)")
+        findings.append("no fan-out spans exported (tracing was expected "
+                        "to be on)")
     return sorted(set(findings))
 
 
@@ -205,8 +210,8 @@ def main(argv=None) -> int:
                     help="extra metric names that must appear (per-leg "
                          "requirements, e.g. sharded-only series)")
     ap.add_argument("--no-spans", action="store_true",
-                    help="don't require query-stage spans (run was not "
-                         "deep-traced)")
+                    help="don't require fan-out spans (run was not "
+                         "traced)")
     args = ap.parse_args(argv)
 
     path = os.path.join(args.metrics_dir, "metrics.jsonl")
