@@ -162,7 +162,7 @@ def _sharded_segment_query_fn(cfg: IndexConfig, k: int, n_probes: int,
 
     ``quantized=True`` is the precision tier's collective: sealed segments
     score through the dequant-free code-space tail
-    (``query_index_gids_quantized``, fed per-instance scales sharded like
+    (``query_index_gids`` with a scale, fed per-instance scales sharded like
     the sealed stack) while the replicated fp32 delta keeps the exact tail,
     and ``k`` is the serve layer's survivor width m rather than the user's
     k -- the merged (nq, m) survivors are rescored exactly on the host
@@ -171,14 +171,11 @@ def _sharded_segment_query_fn(cfg: IndexConfig, k: int, n_probes: int,
 
     def one_segment(state: LSHIndexState, gids: Array, live: Array, q: Array,
                     scale: Optional[Array] = None):
-        # same program body as the unsharded fan-out -- parity by construction
-        if scale is not None:
-            return lsh_index.query_index_gids_quantized(
-                state, cfg, q, k, gids, scale, n_probes=n_probes,
-                backend=backend, live_mask=live)
+        # same per-segment body as the unsharded fan-out -- parity by
+        # construction
         return lsh_index.query_index_gids(state, cfg, q, k, gids,
                                           n_probes=n_probes, backend=backend,
-                                          live_mask=live)
+                                          live_mask=live, scale=scale)
 
     # the function's name is the program's name in a profiler trace
     def segment_query_sharded(sealed_state, sealed_gids, sealed_live,

@@ -134,8 +134,8 @@ def hash_stage(alpha: Array, b: Array, cfg: IndexConfig, x: Array
     """Stage 1 of the query pipeline: (..., L, K) int32 hashes and
     pre-floor projections (kernel-dispatched).  Takes the family arrays
     directly so a fan-out can run it once per query batch -- every segment
-    shares one family (ROADMAP S2) -- while the fused path calls it
-    through :func:`_hashes_and_proj` with identical inputs."""
+    shares one family (:func:`probe_queries`) -- while the build and
+    insert paths call it through :func:`_hashes_and_proj`."""
     h, proj = ops.pstable_hash_proj(x, alpha, b, cfg.r,
                                     backend=dispatch.hash_backend())
     shape = x.shape[:-1] + (cfg.n_tables, cfg.n_hashes)
@@ -257,8 +257,7 @@ def probe_stage(mix: Array, cfg: IndexConfig, hashes: Array,
     """Stage 2: (..., L, T) bucket ids: base bucket + best (T-1)
     single-coordinate perturbations ranked by distance-to-boundary
     (Lv et al. step-wise probing).  Family-array form, like
-    :func:`hash_stage`; the fused path wraps it via
-    :func:`_probe_buckets`."""
+    :func:`hash_stage`; :func:`probe_queries` runs the two."""
     frac = proj - jnp.floor(proj)                                    # (..., L, K)
     # score for delta=+1 is (1 - frac), for delta=-1 is frac; smaller = better.
     scores = jnp.concatenate([1.0 - frac, frac], axis=-1)            # (..., L, 2K)
@@ -275,14 +274,10 @@ def probe_stage(mix: Array, cfg: IndexConfig, hashes: Array,
     return jnp.concatenate([base, pb], axis=-1)
 
 
-def _probe_buckets(state: LSHIndexState, cfg: IndexConfig, hashes: Array,
-                   proj: Array, n_probes: int) -> Array:
-    return probe_stage(state.mix, cfg, hashes, proj, n_probes)
-
-
 def _dedup_candidates(cands: Array, buckets: Array, cfg: IndexConfig,
-                      n_cap: int) -> Array:
-    """Mark duplicate candidate ids as -1 (first occurrence survives).
+                      n_cap: int, live: Optional[Array] = None) -> Array:
+    """Mark duplicate candidate ids as -1 (first occurrence survives), and
+    with ``live`` ((n_cap,) bool) the tombstoned ones too.
 
     Replaces the old full sort of the (nq, C) id list (O(C log^2 C)
     compare-exchange lanes on TPU) with two cheap passes:
@@ -295,7 +290,9 @@ def _dedup_candidates(cands: Array, buckets: Array, cfg: IndexConfig,
     2. *Cross-table*: scatter-min each id's position into a (nq, n_cap)
        first-seen table, keep a slot iff it scattered first.  O(C) work and
        exact; falls back to the sort when the table itself (nq * n_cap)
-       would out-eat the memory it saves.
+       would out-eat the memory it saves.  A dead item's entry starts
+       below every position, so none of its slots is kept: the tombstone
+       filter rides on the same gather instead of one of its own.
     """
     nq, c = cands.shape
     dup_b = (buckets[..., :, None] == buckets[..., None, :])         # (nq,L,T,T)
@@ -309,14 +306,20 @@ def _dedup_candidates(cands: Array, buckets: Array, cfg: IndexConfig,
         cs = jnp.sort(cands, axis=-1)
         dup = jnp.concatenate([jnp.zeros_like(cs[:, :1], dtype=bool),
                                cs[:, 1:] == cs[:, :-1]], axis=-1)
-        return jnp.where(dup, -1, cs)
+        cs = jnp.where(dup, -1, cs)
+        if live is not None:
+            cs = jnp.where((cs >= 0) & live[jnp.clip(cs, 0, n_cap - 1)],
+                           cs, -1)
+        return cs
 
     rows = jnp.arange(nq)[:, None]
     pos = jnp.arange(c, dtype=jnp.int32)
     # -1 slots must not scatter: negative indices WRAP in jnp.at, so send
     # them to n_cap where mode="drop" discards them.
     scat = jnp.where(cands >= 0, cands, n_cap)
-    first = jnp.full((nq, n_cap), c, jnp.int32).at[rows, scat].min(
+    unseen = (jnp.full((n_cap,), c, jnp.int32) if live is None
+              else jnp.where(live, c, -1).astype(jnp.int32))
+    first = jnp.broadcast_to(unseen, (nq, n_cap)).at[rows, scat].min(
         pos, mode="drop")
     seen_at = jnp.take_along_axis(first, jnp.clip(cands, 0, n_cap - 1), axis=1)
     keep = (cands >= 0) & (seen_at == pos)
@@ -324,27 +327,92 @@ def _dedup_candidates(cands: Array, buckets: Array, cfg: IndexConfig,
 
 
 def gather_stage(table: Array, buckets: Array, cfg: IndexConfig,
-                 n_cap: int, live_mask: Optional[Array] = None) -> Array:
+                 n_cap: int, live_mask: Optional[Array] = None,
+                 slot: Optional[Array] = None) -> Array:
     """Stage 3: gather bucket slots + dedup (+ optional tombstone filter):
     (nq, L*T*S) candidate ids, -1 = empty/dup/dead.  The live filter sits
-    here (not in rerank) to mirror the fused path's op order exactly."""
+    here (not in rerank), inside the dedup's first-seen table.
+
+    With ``slot``, ``table`` (n_slots, L, B, S) and ``live_mask``
+    (n_slots, n_cap) are stacks of segments and the one at ``slot`` is
+    read in place: the slot is one more coordinate of the table gather, so
+    no segment's tables are sliced out (a slice would be a copy); only its
+    (n_cap,) live row is."""
     nq = buckets.shape[0]
-    cands = table[jnp.arange(cfg.n_tables)[:, None, None],
-                  buckets.transpose(1, 0, 2)]                        # (L, nq, T, S)
+    at = (jnp.arange(cfg.n_tables)[:, None, None], buckets.transpose(1, 0, 2))
+    if slot is not None:
+        at = (jnp.full(at[1].shape, slot, jnp.int32),) + at
+    cands = table[at]                                                # (L, nq, T, S)
     cands = cands.transpose(1, 0, 2, 3).reshape(nq, -1)              # (nq, L*T*S)
-    cands = _dedup_candidates(cands, buckets, cfg, n_cap)
-    if live_mask is not None:
-        safe = jnp.clip(cands, 0, live_mask.shape[0] - 1)
-        cands = jnp.where((cands >= 0) & live_mask[safe], cands, -1)
-    return cands
+    if live_mask is not None and slot is not None:
+        live_mask = live_mask[slot]                                  # (n_cap,)
+    return _dedup_candidates(cands, buckets, cfg, n_cap, live_mask)
+
+
+def probe_queries(family: Tuple[Array, Array, Array], cfg: IndexConfig,
+                  q: Array, n_probes: int) -> Array:
+    """Stages 1-2 for one query batch under a hash family
+    ``(alpha, b, mix)``: (nq, L, T) probed bucket ids.  Every segment of a
+    serve index shares one family, so a fan-out runs this once per batch
+    and hands the buckets to each segment's :func:`segment_topk`."""
+    alpha, b, mix = family
+    hashes, proj = hash_stage(alpha, b, cfg, q)
+    return probe_stage(mix, cfg, hashes, proj, n_probes)
+
+
+def segment_topk(table: Array, db: Array, gids: Array, live: Array,
+                 cfg: IndexConfig, q: Array, buckets: Array, k: int, *,
+                 slot: Optional[Array] = None, scale: Optional[Array] = None,
+                 backend: Optional[str] = None) -> Tuple[Array, Array]:
+    """The serve layer's per-segment body, from probed ``buckets`` to the
+    segment's top-k: gather + dedup + tombstone filter (stage 3), the
+    query kernel over the candidates' rows ``db``, then slot -> global id.
+
+    Args:
+        table, gids, live: one segment's (L, B, S) tables and (n_cap,)
+            global ids and live mask; or, with ``slot``, stacks of segments
+            -- (n_slots, L, B, S) tables, (n_slots, n_cap) gids and live --
+            read in place at ``slot``.
+        db: the segment's own (n_cap, N) rows, whichever form the rest
+            takes: the kernel gathers one row tile per grid step, so its
+            rows belong where XLA can stage a segment (fast memory), not in
+            a stack of them.
+        q, buckets: (nq, N) queries and their :func:`probe_queries`.
+        k: results per query (static).
+        scale: the segment's dequant scale when ``db`` holds int8/bf16
+            codes (scored dequant-free by ``ops.quantized_query_topk``);
+            None scores fp32 rows exactly (``ops.fused_query_topk``).
+        backend: the query kernel's mode (``dispatch.query_backend``).
+    Returns:
+        (gids (nq, k) int32, dists (nq, k) f32), -1/inf padded.
+
+    Every fan-out runs this one body -- the per-segment programs and the
+    stacked program of ``serve/segments.py``, and the SPMD collective of
+    ``core/distributed.py`` -- so their answers agree by construction.
+    """
+    n_cap = gids.shape[-1]
+    cands = gather_stage(table, buckets, cfg, n_cap, live_mask=live,
+                         slot=slot)
+    if scale is None:
+        dist, ids = ops.fused_query_topk(q, db, cands, k, p=cfg.p,
+                                         backend=backend)
+    else:
+        dist, ids = ops.quantized_query_topk(q, db, scale, cands, k,
+                                             p=cfg.p, backend=backend)
+    at = jnp.clip(ids, 0, n_cap - 1)
+    g = (gids[at] if slot is None
+         else gids[jnp.full(at.shape, slot, jnp.int32), at])
+    return jnp.where(ids >= 0, g, -1), dist
 
 
 def _candidate_ids(state: LSHIndexState, cfg: IndexConfig, q: Array,
-                   n_probes: int) -> Array:
-    """hash -> probe -> gather bucket slots -> dedup: (nq, L*T*S) ids."""
-    hashes, proj = _hashes_and_proj(state, cfg, q)
-    buckets = _probe_buckets(state, cfg, hashes, proj, n_probes)     # (nq, L, T)
-    return gather_stage(state.table, buckets, cfg, state.db.shape[0])
+                   n_probes: int, live_mask: Optional[Array] = None
+                   ) -> Array:
+    """hash -> probe -> gather bucket slots -> dedup (-> tombstone
+    filter): (nq, L*T*S) ids."""
+    buckets = probe_queries(hash_family(state), cfg, q, n_probes)   # (nq, L, T)
+    return gather_stage(state.table, buckets, cfg, state.db.shape[0],
+                        live_mask)
 
 
 def query_index(state: LSHIndexState, cfg: IndexConfig, queries: Array,
@@ -374,10 +442,7 @@ def query_index(state: LSHIndexState, cfg: IndexConfig, queries: Array,
         ids are -1 (dist +inf) where fewer than k candidates were found.
     """
     q = queries.astype(jnp.float32)
-    cands = _candidate_ids(state, cfg, q, n_probes)
-    if live_mask is not None:
-        safe = jnp.clip(cands, 0, live_mask.shape[0] - 1)
-        cands = jnp.where((cands >= 0) & live_mask[safe], cands, -1)
+    cands = _candidate_ids(state, cfg, q, n_probes, live_mask)
     dist, ids = ops.fused_query_topk(q, state.db, cands, k, p=cfg.p,
                                      valid_items=valid_items, backend=backend)
     return ids, dist
@@ -386,85 +451,30 @@ def query_index(state: LSHIndexState, cfg: IndexConfig, queries: Array,
 def query_index_gids(state: LSHIndexState, cfg: IndexConfig, queries: Array,
                      k: int, gids: Array, n_probes: int = 1,
                      backend: Optional[str] = None,
-                     live_mask: Optional[Array] = None
+                     live_mask: Optional[Array] = None,
+                     scale: Optional[Array] = None
                      ) -> Tuple[Array, Array]:
-    """:func:`query_index` + local-slot -> global-id translation.
+    """One segment's k-NN in global ids: :func:`probe_queries` then
+    :func:`segment_topk` on the segment's own arrays.
 
     Args:
         gids: (n_items_cap,) int32 global id per slot (-1 = empty).
+        scale: the dequant scale of a quantized segment (int8/bf16
+            ``state.db``): candidates are scored in code space and the
+            distances are approximate within O(scale), so serve callers
+            rescore survivors exactly (``kernels.quantize.rerank_survivors``).
+            None (fp32 rows) scores exactly.
         Everything else as in :func:`query_index`.
     Returns:
         (gids (nq, k) int32, dists (nq, k) f32), -1/inf padded.
 
-    The one shared per-segment program body of the serve layer: both the
-    unsharded fan-out (serve/segments.py) and the SPMD collective
-    (core/distributed.py) call this, so the sharding parity invariant holds
-    by construction instead of by keeping two copies in sync.
-    """
-    ids, dist = query_index(state, cfg, queries, k, n_probes=n_probes,
-                            backend=backend, live_mask=live_mask)
-    g = jnp.where(ids >= 0, gids[jnp.clip(ids, 0, gids.shape[0] - 1)], -1)
-    return g, dist
-
-
-def query_index_quantized(state: LSHIndexState, cfg: IndexConfig,
-                          queries: Array, k: int, scale: Array,
-                          n_probes: int = 1,
-                          valid_items: Optional[int] = None,
-                          backend: Optional[str] = None,
-                          live_mask: Optional[Array] = None
-                          ) -> Tuple[Array, Array]:
-    """:func:`query_index` over a quantized segment (int8/bf16 ``state.db``).
-
-    The candidate pipeline (hash -> probe -> gather -> dedup) is byte-for-
-    byte the fp32 one -- hashing reads only the family leaves, which stay
-    fp32 at every tier -- and only the scoring tail switches to the
-    dequant-free code-space path (``ops.quantized_query_topk``).  Returned
-    distances are in the fp32 metric (scaled once), approximate within
-    O(scale); serve callers rescore survivors exactly
-    (``kernels.quantize.rerank_survivors``).
+    The per-segment program of the serve layer's fan-out (serve/segments.py)
+    and of each instance in the SPMD collective (core/distributed.py).
     """
     q = queries.astype(jnp.float32)
-    cands = _candidate_ids(state, cfg, q, n_probes)
-    if live_mask is not None:
-        safe = jnp.clip(cands, 0, live_mask.shape[0] - 1)
-        cands = jnp.where((cands >= 0) & live_mask[safe], cands, -1)
-    dist, ids = ops.quantized_query_topk(q, state.db, scale, cands, k,
-                                         p=cfg.p, valid_items=valid_items,
-                                         backend=backend)
-    return ids, dist
-
-
-def query_index_gids_quantized(state: LSHIndexState, cfg: IndexConfig,
-                               queries: Array, k: int, gids: Array,
-                               scale: Array, n_probes: int = 1,
-                               backend: Optional[str] = None,
-                               live_mask: Optional[Array] = None
-                               ) -> Tuple[Array, Array]:
-    """:func:`query_index_quantized` + local-slot -> global-id translation
-    -- the quantized analogue of :func:`query_index_gids`, and like it the
-    ONE shared per-segment program body: the unsharded fan-out and the SPMD
-    collective both call this for quantized sealed segments."""
-    ids, dist = query_index_quantized(state, cfg, queries, k, scale,
-                                      n_probes=n_probes, backend=backend,
-                                      live_mask=live_mask)
-    g = jnp.where(ids >= 0, gids[jnp.clip(ids, 0, gids.shape[0] - 1)], -1)
-    return g, dist
-
-
-def rerank_stage(db: Array, gids: Array, cfg: IndexConfig, q: Array,
-                 cands: Array, k: int, backend: Optional[str] = None
-                 ) -> Tuple[Array, Array]:
-    """Stage 4: exact re-rank + top-k + local-slot -> global-id translation.
-
-    The pipeline's tail on its own: candidates come pre-filtered from
-    :func:`gather_stage`, the distance/top-k op is the same
-    ``ops.fused_query_topk`` the fused path runs, so its results are
-    bitwise those of :func:`query_index_gids` on the same segment."""
-    dist, ids = ops.fused_query_topk(q, db, cands, k, p=cfg.p,
-                                     backend=backend)
-    g = jnp.where(ids >= 0, gids[jnp.clip(ids, 0, gids.shape[0] - 1)], -1)
-    return g, dist
+    buckets = probe_queries(hash_family(state), cfg, q, n_probes)
+    return segment_topk(state.table, state.db, gids, live_mask, cfg, q,
+                        buckets, k, scale=scale, backend=backend)
 
 
 @functools.lru_cache(maxsize=32)

@@ -101,6 +101,10 @@ CATALOG: Dict[str, MetricSpec] = _catalog(
     MetricSpec("serve_segment_wins_total", "counter",
                "Merged top-k slots won per segment", ("tenant", "segment"),
                required=True),
+    MetricSpec("serve_fanout_batches_total", "counter",
+               "Unsharded query batches by fan-out path (stacked: one "
+               "program over the stacked sealed segments; per_segment: one "
+               "program per segment)", ("tenant", "path")),
     MetricSpec("serve_device_wins_total", "counter",
                "Merged top-k slots won per device (sharded serve)",
                ("tenant", "device"), required=True),

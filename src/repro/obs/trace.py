@@ -61,7 +61,7 @@ _ENV_BUFFER = "REPRO_TRACE_BUFFER"
 STAGE_SPANS = frozenset({
     "request", "admission", "embed", "batch",
     "index.lock_wait", "query.segments", "query.collective",
-    "fanout.telemetry", "survivor.gather", "survivor.rerank", "result.sync",
+    "fanout.wait", "fanout.telemetry", "survivor.gather", "survivor.rerank", "result.sync",
     "write.apply",
     "wal.append", "wal.fsync", "seal", "compact",
     "ckpt.save", "ckpt.restore", "recover.restore", "recover.replay",

@@ -24,7 +24,10 @@ module turns it into a *living* index the way LSM storage engines do:
   ``compact()`` and ``set_replication()`` and serialises them against each
   other.  The old direct methods survive as ``DeprecationWarning`` shims;
 * **query()** fans out to all segments and merges per-segment top-k via
-  ``kernels.ops.merge_topk``;
+  ``kernels.ops.merge_topk`` -- on one device in ONE program per batch:
+  the sealed segments are stacked (``sharding.placement``, capacity-
+  doubling headroom, per-slot diffs) and a loop over the occupied slots
+  scores each, then the delta, then the merge;
 * **shard(mesh)** moves the fan-out onto a device mesh: sealed segments
   round-robin over the mesh's serve axis, delta + hash family replicated,
   collective top-k fan-in (``core.distributed.query_segments_sharded`` via
@@ -50,11 +53,12 @@ capacity, a cross-segment query returns ids *bit-identical* to a single
 ``build_index`` over the union of live items -- segmentation is invisible to
 callers.
 
-All segments share the same (capacity, cfg) shapes, so the per-segment query
-program is compiled once and reused for every segment and every insert-order
-history (the padded-chunk shape palette -- docs/architecture.md has the full
-table).  Host-side bookkeeping (gid maps, live masks) is numpy; device state
-is the ``LSHIndexState`` pytree plus a (capacity,) gid vector and live mask.
+All segments share the same (capacity, cfg) shapes, so the stacked query
+program is compiled once per stack width and reused for every seal into
+headroom and every insert-order history (the padded-chunk shape palette --
+docs/architecture.md has the full table).  Host-side bookkeeping (gid maps,
+live masks) is numpy; device state is the ``LSHIndexState`` pytree plus a
+(capacity,) gid vector and live mask.
 """
 
 from __future__ import annotations
@@ -70,6 +74,7 @@ from typing import List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import Mesh
 
 from ..core import distributed, index as lidx
 from ..core.index import IndexConfig, LSHIndexState
@@ -106,10 +111,22 @@ class Segment:
     # invalidated on tombstone flips.  Never serialized.
     _content_key: Optional[tuple] = None
     _live_key: Optional[int] = None
+    # Host copy of ``live``, written with it at every mutation (see
+    # ``live_np``).  Never serialized.
+    _live_host: Optional[np.ndarray] = None
 
     @property
     def capacity(self) -> int:
         return self.gids.shape[0]
+
+    def live_np(self) -> np.ndarray:
+        """``live`` on the host, kept in step by every mutation, so a
+        delete reads liveness without waiting for the device (whose queue
+        may hold a whole batch's fan-out program).  Read back once for a
+        segment built elsewhere (a restored snapshot)."""
+        if self._live_host is None:
+            self._live_host = np.array(self.live)
+        return self._live_host
 
     def placement_key(self) -> tuple:
         """``(content, live)`` fingerprint for placement diffing.
@@ -130,7 +147,7 @@ class Segment:
             self._content_key = (int(self.n_items),
                                  zlib.crc32(np.asarray(self.gids).tobytes()))
         if self._live_key is None:
-            self._live_key = zlib.crc32(np.asarray(self.live).tobytes())
+            self._live_key = zlib.crc32(self.live_np().tobytes())
         return (self._content_key, self._live_key)
 
     def read_bucket_health(self) -> Tuple[int, int]:
@@ -182,12 +199,105 @@ def _quantized_segment_query_fn(cfg: IndexConfig, k: int, n_probes: int,
 
     def segment_query_codes(state: LSHIndexState, q: Array, live: Array,
                             gids: Array, scale: Array):
-        return lidx.query_index_gids_quantized(state, cfg, q, k, gids, scale,
-                                               n_probes=n_probes,
-                                               backend=backend,
-                                               live_mask=live)
+        return lidx.query_index_gids(state, cfg, q, k, gids,
+                                     n_probes=n_probes, backend=backend,
+                                     live_mask=live, scale=scale)
 
     return jax.jit(segment_query_codes)
+
+
+@functools.lru_cache(maxsize=64)
+def _stacked_query_fn(cfg: IndexConfig, k: int, n_probes: int,
+                      backend: Optional[str], quantized: bool):
+    """One compiled program per (cfg, k, n_probes, backend, tier) for a
+    batch's whole unsharded fan-out: every sealed segment of the one-device
+    stack (``sharding.placement``; slot i holds sealed segment i), then the
+    delta, then the merge -- one dispatch instead of one per segment.
+
+    Hash and probe run once (every segment shares the family).  A
+    ``lax.fori_loop`` then runs the per-segment body
+    (``core.index.segment_topk``) for slots ``0 .. n_sealed-1``; the trip
+    count is traced, so headroom slots cost nothing and a seal into
+    headroom reuses the program.  A slot's tables, gids and live mask are
+    gathered from the stacks in place; its rows are sliced out, so the
+    query kernel gets the operands it gets per segment, rows XLA can stage
+    in fast memory for the kernel's per-step tile gathers (read from the
+    whole stack in HBM, the fp32 kernel ran 2.2x slower on a v5e).  Sealed
+    slots score in code space when ``quantized`` (int8/bf16 codes, one
+    scale each); the delta always scores exactly.
+
+    Returns the merged ``(gids, dists)`` and the valid candidates each
+    segment offered the merge, ``(n_slots + 1,)``: the sealed segments'
+    in slot order, then the delta's at ``n_sealed`` (the telemetry's
+    per-segment candidate counts in one copy).  The merge
+    is a total (distance, gid) order over every slot's top-k, so the answer
+    is bit-identical to the per-segment programs' merge."""
+
+    # the function's name is the program's name in a profiler trace
+    def segment_query_stacked(stack: LSHIndexState, gids: Array, live: Array,
+                              scales: Array, n_sealed: Array,
+                              delta: LSHIndexState, delta_gids: Array,
+                              delta_live: Array, q: Array):
+        buckets = lidx.probe_queries(lidx.hash_family(delta), cfg, q,
+                                     n_probes)
+        n_slots = gids.shape[0]
+        nq = q.shape[0]
+
+        def one_slot(slot, acc):
+            out_g, out_d, counts = acc
+            rows = jax.lax.dynamic_index_in_dim(stack.db, slot,
+                                                keepdims=False)
+            g, d = lidx.segment_topk(
+                stack.table, rows, gids, live, cfg, q, buckets, k,
+                slot=slot, scale=scales[slot] if quantized else None,
+                backend=backend)
+            return (out_g.at[slot].set(g), out_d.at[slot].set(d),
+                    counts.at[slot].set(jnp.sum(g >= 0, dtype=jnp.int32)))
+
+        out_g, out_d, counts = jax.lax.fori_loop(
+            0, n_sealed, one_slot,
+            (jnp.full((n_slots, nq, k), -1, jnp.int32),
+             jnp.full((n_slots, nq, k), jnp.inf, jnp.float32),
+             jnp.zeros((n_slots + 1,), jnp.int32)))
+        g, d = lidx.segment_topk(delta.table, delta.db, delta_gids,
+                                 delta_live, cfg, q, buckets, k,
+                                 backend=backend)
+        g_all = jnp.concatenate(
+            [out_g.transpose(1, 0, 2).reshape(nq, n_slots * k), g], axis=1)
+        d_all = jnp.concatenate(
+            [out_d.transpose(1, 0, 2).reshape(nq, n_slots * k), d], axis=1)
+        d_m, g_m = ops.merge_topk(d_all, g_all, k)
+        counts = counts.at[n_sealed].set(jnp.sum(g >= 0, dtype=jnp.int32))
+        return g_m, d_m, counts
+
+    return jax.jit(segment_query_stacked)
+
+
+@functools.lru_cache(maxsize=1)
+def _local_mesh() -> Mesh:
+    """The one-device mesh the unsharded stack is placed on."""
+    return Mesh(np.array(jax.devices()[:1]), ("stack",))
+
+
+def _stackable(sealed: Sequence[Segment]) -> bool:
+    """True iff the sealed segments share one storage dtype and tier, so
+    their leaves stack (fp32 segments sealed before a tenant's precision
+    changed cannot stack with its int8 ones)."""
+    return len({(s.state.db.dtype, s.scale is None) for s in sealed}) <= 1
+
+
+@dataclasses.dataclass
+class _Fanout:
+    """One batch's fan-out on the device: the merged ``gids``/``dists``
+    and, for the telemetry, the valid candidates ``counts[j]`` that
+    segment ``seg_ids[j]`` offered the merge (unsharded only), and the
+    router's ``plan`` (routed sharded batches only)."""
+
+    gids: Array
+    dists: Array
+    counts: Optional[Array] = None
+    seg_ids: Optional[List[int]] = None
+    plan: object = None
 
 
 @functools.lru_cache(maxsize=64)
@@ -203,10 +313,11 @@ def _segment_insert_fn(cfg: IndexConfig, chunk: int):
 class SegmentedIndex:
     """Mutable, queryable, compactable index built from fixed-shape segments.
 
-    Thread-safety: mutators and query take an internal lock; queries
-    themselves are pure jax calls, so readers only contend for the brief
-    host-side fan-out loop (the micro-batcher serialises heavy traffic
-    anyway).
+    Thread-safety: mutators and query take an internal lock; a query holds
+    it only to dispatch the stacked fan-out program (one asynchronous jax
+    call) and waits for the device after releasing it, so readers and
+    writers contend for that one dispatch (the micro-batcher serialises
+    heavy traffic anyway).
     """
 
     def __init__(self, cfg: IndexConfig, *, segment_capacity: int = 1024,
@@ -289,7 +400,8 @@ class SegmentedIndex:
                                   self.segment_capacity, family=self.family)
         seg = Segment(state=state,
                       gids=jnp.full((self.segment_capacity,), -1, jnp.int32),
-                      live=jnp.zeros((self.segment_capacity,), jnp.bool_))
+                      live=jnp.zeros((self.segment_capacity,), jnp.bool_),
+                      _live_host=np.zeros((self.segment_capacity,), bool))
         self.segments.append(seg)
         return seg
 
@@ -572,7 +684,14 @@ class SegmentedIndex:
         return self._replication
 
     def _current_placement(self):
-        """The up-to-date SegmentPlacement.
+        """The up-to-date SegmentPlacement, or None when unsharded sealed
+        segments cannot stack (:func:`_stackable`).
+
+        Sharded, the live sealed segments are placed over the mesh.
+        Unsharded, every sealed segment is stacked on the one device, slot
+        i = sealed segment i, for the stacked fan-out program; a segment
+        whose items are all deleted keeps its slot (its candidates are all
+        dead), so a delete never moves a slot.
 
         Sealed-set changes rebuild *through the previous placement*
         (``place_segments(..., prev=...)``): slots whose fingerprint is
@@ -580,14 +699,24 @@ class SegmentedIndex:
         O(that segment's bytes), not O(all sealed bytes) -- the actual vs
         full-restack transfer is published as the
         ``placement_replaced_bytes_total`` / ``placement_restack_bytes_total``
-        counters.  Delta-only mutations -- the streaming write hot path --
-        just re-replicate the one mutable segment.
+        counters.  The stack grows by capacity doubling, so a seal lands in
+        headroom and reuses the compiled programs.  Sharded, delta-only
+        mutations -- the streaming write hot path -- just re-replicate the
+        one mutable segment; the unsharded program reads the delta itself.
         """
         if (self._placement is None
                 or self._placement.version != self._sealed_version):
-            sealed = [s for s in self.segments[:-1] if s.n_live > 0]
+            if self._mesh is None:
+                sealed = self.segments[:-1]
+                if not _stackable(sealed):
+                    self._placement = None
+                    return None
+                mesh, axis = _local_mesh(), "stack"
+            else:
+                sealed = [s for s in self.segments[:-1] if s.n_live > 0]
+                mesh, axis = self._mesh, self._shard_axis
             self._placement = seg_placement.place_segments(
-                sealed, self.delta, self._mesh, self._shard_axis,
+                sealed, self.delta, mesh, axis,
                 self._sealed_version, replication=self._replication,
                 prev=self._placement)
             self._delta_synced = self._version
@@ -607,7 +736,7 @@ class SegmentedIndex:
             self._router = (QueryRouter(pl.layout(), tenant=self.tenant)
                             if any(f > 1 for f in pl.replication)
                             else None)
-        elif self._delta_synced != self._version:
+        elif self._mesh is not None and self._delta_synced != self._version:
             self._placement = seg_placement.refresh_delta(self._placement,
                                                           self.delta)
             self._delta_synced = self._version
@@ -618,10 +747,11 @@ class SegmentedIndex:
 
         Maintenance workers call this after seal/compact so the device
         transfer (the diff) happens on the worker thread; the next query
-        finds the placement already current.  No-op when unsharded.
+        finds the placement already current.  Unsharded, it only updates a
+        stack that a query has already built.
         """
         with self._lock:
-            if self._mesh is not None:
+            if self._mesh is not None or self._placement is not None:
                 self._current_placement()
 
     def shard_layout(self) -> Optional[dict]:
@@ -718,6 +848,7 @@ class SegmentedIndex:
                 seg.gids = seg.gids.at[sl].set(
                     jnp.asarray(out_gids[pos:pos + take]))
                 seg.live = seg.live.at[sl].set(True)
+                seg.live_np()[seg.n_items:seg.n_items + take] = True
                 si = len(self.segments) - 1
                 for j in range(take):
                     self._locator[int(out_gids[pos + j])] = (si, seg.n_items + j)
@@ -760,12 +891,13 @@ class SegmentedIndex:
             for si, slot_set in by_seg.items():
                 slots = sorted(slot_set)
                 seg = self.segments[si]
-                was_live = np.asarray(seg.live)[slots]
-                hits = int(was_live.sum())
+                live_np = seg.live_np()
+                hits = int(live_np[slots].sum())
                 if hits == 0:        # retried/idempotent delete: no change
                     continue
                 seg.live = seg.live.at[jnp.asarray(slots, jnp.int32)].set(
                     False)
+                live_np[slots] = False
                 seg._live_key = None      # mask changed: re-fingerprint
                 seg.n_live -= hits
                 n += hits
@@ -773,8 +905,31 @@ class SegmentedIndex:
             if n:
                 self._version += 1
             if sealed_hit:
-                self._sealed_version += 1
+                self._tombstone_stack(req, delta_si)
             return n
+
+    def _tombstone_stack(self, req: np.ndarray, delta_si: int) -> None:
+        """Make a sealed-segment delete visible to the next query.
+
+        A current unsharded stack takes one scatter of every requested
+        gid's (slot, row) -- slot = segment index there -- with the gids
+        that no sealed segment holds sent out of range, so the scatter has
+        one shape per gid count whichever segments the gids fall in.
+        Otherwise (sharded, or no current stack) the placement rebuilds
+        on the next query."""
+        pl = self._placement
+        if (self._mesh is not None or pl is None
+                or pl.version != self._sealed_version):
+            self._sealed_version += 1
+            return
+        off = pl.n_dev * pl.per_dev            # out of range: dropped
+        slots = np.full(req.shape, off, np.int32)
+        rows = np.zeros(req.shape, np.int32)
+        for j, g in enumerate(req.tolist()):
+            loc = self._locator.get(int(g))
+            if loc is not None and loc[0] < delta_si:
+                slots[j], rows[j] = loc
+        self._placement = seg_placement.clear_live(pl, slots, rows)
 
     def live_items(self) -> Tuple[np.ndarray, np.ndarray]:
         """Host copies of every live item: (embeddings (n_live, N),
@@ -785,7 +940,7 @@ class SegmentedIndex:
             for seg in self.segments:
                 if seg.n_items == 0:
                     continue
-                live = np.asarray(seg.live)[:seg.n_items]
+                live = seg.live_np()[:seg.n_items]
                 if not live.any():
                     continue
                 # quantized sealed segments read their exact fp32 rows from
@@ -863,8 +1018,8 @@ class SegmentedIndex:
         for seg in frozen:
             if seg.n_items == 0:
                 continue
-            live = np.asarray(seg.live)[:seg.n_items]
-            if not live.any():
+            live = np.asarray(seg.live)[:seg.n_items]   # an immutable
+            if not live.any():                           # snapshot: no lock
                 continue
             db = (seg.pool if seg.pool is not None
                   else np.asarray(seg.state.db))
@@ -914,8 +1069,9 @@ class SegmentedIndex:
                 if loc is None:
                     continue
                 seg = self.segments[loc[0]]
-                if bool(np.asarray(seg.live[loc[1]])):
+                if seg.live_np()[loc[1]]:
                     seg.live = seg.live.at[loc[1]].set(False)
+                    seg.live_np()[loc[1]] = False
                     seg.n_live -= 1
                     seg._live_key = None
             self._version += 1
@@ -929,46 +1085,47 @@ class SegmentedIndex:
               ) -> Tuple[Array, Array]:
         """Cross-segment k-NN: (nq, N) -> (gids (nq, k), dists (nq, k)).
 
-        Fans out one fused-kernel query per non-empty segment (identical
-        shapes -> one compiled program total) and merges the per-segment
-        top-k shards with ``ops.merge_topk``.  After ``shard(mesh)`` the
-        fan-out runs SPMD instead (one collective program over the mesh)
-        with bit-identical results.
+        Unsharded, one program (:func:`_stacked_query_fn`) queries the
+        stacked sealed segments and the delta and merges their top-k with
+        ``ops.merge_topk``; sealed segments that cannot stack fall back to
+        one program per live segment and a separate merge.  After
+        ``shard(mesh)`` the fan-out runs SPMD instead (one collective
+        program over the mesh).  All three answer bit-identically.
 
         Tracing (sampled traces only): ``index.lock_wait``, then
-        ``query.segments`` (the fan-out's dispatch and merge) or
-        ``query.collective`` (the sharded program), then
-        ``fanout.telemetry``.
+        ``query.segments`` (the fan-out's dispatch) or ``query.collective``
+        (the sharded program), then ``fanout.wait`` (the first copy of the
+        result to the host) and ``fanout.telemetry``.
         """
         q = jnp.asarray(queries, jnp.float32)
         if self.precision != "fp32":
             # quantized tiers run the survivor-rerank engine
             return self._query_quantized(q, k, n_probes)
-        g, d, seg_ids, shards, plan = self._fan_out(
-            q, k, n_probes, (int(q.shape[0]), k, n_probes))
-        if g is None:
+        fan = self._fan_out(q, k, n_probes, (int(q.shape[0]), k, n_probes))
+        if fan is None:
             return (jnp.full((q.shape[0], k), -1, jnp.int32),
                     jnp.full((q.shape[0], k), jnp.inf, jnp.float32))
         if self._on_fanout is not None:
-            self._fanout_telemetry(g, seg_ids, shards, plan=plan)
-        return g, d
+            self._fanout_telemetry(fan.gids, fan)
+        return fan.gids, fan.dists
 
-    def _fan_out(self, q: Array, width: int, n_probes: int, shape: tuple):
-        """Every live segment's top-``width`` for ``q``, merged:
-        ``(gids, dists, seg_ids, shards, plan)``.
+    def _fan_out(self, q: Array, width: int, n_probes: int, shape: tuple
+                 ) -> Optional[_Fanout]:
+        """Every live segment's top-``width`` for ``q``, merged (None when
+        no segment holds a live item).
 
-        The per-segment programs (or the sharded collective) are dispatched
-        under the index lock; the unsharded merge runs after it is
-        released.  Sealed quantized segments score in code space
-        (``width`` is then the survivor width); fp32 segments score
-        exactly.  ``seg_ids``/``shards`` are the unsharded fan-out's
-        inputs to the telemetry (None when sharded); ``gids`` is None when
-        no segment holds a live item.
+        The index lock is held for the dispatch only.  Unsharded, that is
+        one call of the stacked program; sealed segments that cannot stack
+        instead dispatch one program per live segment under the lock, and
+        their two concatenates and merge after it.  Sealed quantized
+        segments score in code space (``width`` is then the survivor
+        width); fp32 segments score exactly.  Unsharded batches count in
+        ``serve_fanout_batches_total{path}``.
         """
         tr = obs_trace.tracer()
         quantized = self.precision != "fp32"
-        # the dispatch span opens under the lock and closes after the merge,
-        # which runs once the lock is released
+        # the fallback's dispatch span opens under the lock and closes
+        # after the merge, which runs once the lock is released
         with contextlib.ExitStack() as dispatch_span:
             with tr.locked(self._lock, "index.lock_wait", op="query",
                            tenant=self.tenant):
@@ -988,7 +1145,25 @@ class SegmentedIndex:
                             backend=self.backend,
                             active=None if plan is None else plan.active,
                             quantized=quantized)
-                    return g, d, None, None, plan
+                    return _Fanout(g, d, plan=plan)
+                if self.n_live == 0:
+                    return None
+                pl = self._current_placement()
+                if pl is not None:
+                    fn = _stacked_query_fn(
+                        self.cfg, width, n_probes, self.backend,
+                        pl.n_sealed > 0 and self.segments[0].scale is not None)
+                    with tr.span("query.segments", tenant=self.tenant,
+                                 segments=pl.n_sealed + 1, programs=1):
+                        g, d, counts = fn(
+                            pl.sealed_state, pl.sealed_gids, pl.sealed_live,
+                            pl.sealed_scales, np.int32(pl.n_sealed),
+                            self.delta.state, self.delta.gids,
+                            self.delta.live, q)
+                    self._count_fanout("stacked")
+                    seg_ids = list(range(pl.n_sealed))
+                    return _Fanout(g, d, counts,
+                                   seg_ids + [len(self.segments) - 1])
                 seg_ids = [i for i, s in enumerate(self.segments)
                            if s.n_live > 0]
                 exact = _segment_query_fn(self.cfg, width, n_probes,
@@ -1011,17 +1186,21 @@ class SegmentedIndex:
                     else:   # fp32 tiers, and the delta of a quantized one
                         shards.append(exact(seg.state, q, seg.live,
                                             seg.gids))
-            if not shards:
-                return None, None, seg_ids, shards, None
-            if len(shards) == 1:
-                # single segment is already top-k; merge only to normalise
-                # tie order so results don't depend on the segment count
-                g, d = _merged(shards[0][1], shards[0][0], width)
-            else:
-                g_all = jnp.concatenate([sg for sg, _ in shards], axis=1)
-                d_all = jnp.concatenate([sd for _, sd in shards], axis=1)
-                g, d = _merged(d_all, g_all, width)
-        return g, d, seg_ids, shards, None
+            self._count_fanout("per_segment")
+            # a single segment is already top-k; it is merged only to
+            # normalise tie order so results don't depend on the count
+            g_all = jnp.concatenate([sg for sg, _ in shards], axis=1)
+            d_all = jnp.concatenate([sd for _, sd in shards], axis=1)
+            g, d = _merged(d_all, g_all, width)
+        counts = None
+        if self._on_fanout is not None:
+            counts = (g_all >= 0).reshape(
+                q.shape[0], len(seg_ids), width).sum(axis=(0, 2))
+        return _Fanout(g, d, counts, seg_ids)
+
+    def _count_fanout(self, path: str) -> None:
+        obs_metrics.registry().inc("serve_fanout_batches_total",
+                                   tenant=self.tenant, path=path)
 
     def _query_quantized(self, q: Array, k: int, n_probes: int
                          ) -> Tuple[Array, Array]:
@@ -1029,7 +1208,7 @@ class SegmentedIndex:
         a survivor pool of ``m >= k``, then an exact fp32 rescore of just
         those survivors.
 
-        Stage 1 runs the same fan-out shapes as :meth:`query` but asks each
+        Stage 1 runs the same fan-out as :meth:`query` but asks each
         segment for the top ``m = survivor_width(k, survivor_k, C)``
         candidates scored against the int8/bf16 codes (the delta, still
         fp32, is scored exactly).  Stage 2 gathers the survivors' exact
@@ -1037,22 +1216,22 @@ class SegmentedIndex:
         (distance, gid) order, so any survivor set containing the true
         top-k yields exactly the fp32 answer.  Sharded and unsharded paths
         agree because the rerank is a pure function of the survivor set.
-        Stage 2 runs under the ``survivor.gather`` and ``survivor.rerank``
-        spans.
+        The host waits for stage 1 in ``fanout.wait``; stage 2 runs under
+        the ``survivor.gather`` and ``survivor.rerank`` spans.
         """
         kq = quantize.survivor_width(
             k, self.survivor_k,
             self.cfg.n_tables * n_probes * self.cfg.bucket_capacity)
-        g, _, _, _, _ = self._fan_out(q, kq, n_probes,
-                                      (int(q.shape[0]), k, n_probes))
-        if g is None:
+        fan = self._fan_out(q, kq, n_probes, (int(q.shape[0]), k, n_probes))
+        if fan is None:
             return (jnp.full((q.shape[0], k), -1, jnp.int32),
                     jnp.full((q.shape[0], k), jnp.inf, jnp.float32))
         # survivor rescore: host-gather the exact rows, rerank on device
         tr = obs_trace.tracer()
+        with tr.span("fanout.wait", tenant=self.tenant):
+            g_np = np.asarray(fan.gids).copy()
         with tr.span("survivor.gather", tenant=self.tenant,
                      rows=int(q.shape[0]), width=kq):
-            g_np = np.asarray(g).copy()
             rows = self._survivor_rows(g_np)
         with tr.span("survivor.rerank", tenant=self.tenant, width=kq):
             g, d = quantize.rerank_survivors(q, jnp.asarray(rows),
@@ -1103,30 +1282,35 @@ class SegmentedIndex:
         return rows
 
     def _fanout_telemetry(self, g: Array,
-                          seg_ids: Optional[List[int]] = None,
-                          shards: Optional[list] = None,
-                          plan=None) -> None:
+                          fan: Optional[_Fanout] = None) -> None:
         """Attribute one merged top-k back to segments/devices and feed the
         ``on_fanout`` hook (ServingStats.record_fanout signature).
 
         Wins come from the merged gids via the locator (gids are globally
         unique, so the winning segment is unambiguous); candidate counts
-        are the valid rows each unsharded shard (``shards``, the fan-out's
-        device (gids, dists) pairs) offered the merge; device wins map
+        are the valid rows each unsharded segment offered the merge
+        (``fan.counts``, one device array copied once); device wins map
         segments through the live placement's assignment (delta -> rank 0,
-        matching the collective program).  When a router ``plan`` routed
-        this batch, the win goes to the replica that actually answered and
-        the hook additionally receives the plan's per-device instance load
-        (4th argument -- only ever passed on routed batches, so factor-1
-        deployments keep the 3-argument hook contract).  Runs under the
-        ``fanout.telemetry`` span, host copies included.
+        matching the collective program).  When a router ``fan.plan``
+        routed this batch, the win goes to the replica that actually
+        answered and the hook additionally receives the plan's per-device
+        instance load (4th argument -- only ever passed on routed batches,
+        so factor-1 deployments keep the 3-argument hook contract).  Given
+        ``fan``, the first copy of its result waits for the fan-out program
+        in ``fanout.wait``; the rest runs under ``fanout.telemetry``.
         """
         tr = obs_trace.tracer()
+        counts = None
+        if fan is not None:
+            with tr.span("fanout.wait", tenant=self.tenant):
+                g_np = np.asarray(g)
+                if fan.counts is not None:
+                    counts = np.asarray(fan.counts)
+        plan = fan.plan if fan is not None else None
         with tr.span("fanout.telemetry", tenant=self.tenant,
-                     shards=len(shards) if shards else 0):
-            g_np = np.asarray(g)
-            shard_gs = (None if seg_ids is None
-                        else [np.asarray(sg) for sg, _ in shards])
+                     shards=len(fan.seg_ids) if counts is not None else 0):
+            if fan is None:
+                g_np = np.asarray(g)
             with tr.locked(self._lock, "index.lock_wait", op="telemetry",
                            tenant=self.tenant):
                 n_segs = len(self.segments)
@@ -1138,12 +1322,12 @@ class SegmentedIndex:
                     if loc is not None:
                         wins[loc[0]] += 1
                 cands = None
-                if seg_ids is not None:
+                if counts is not None:
                     cands = [0] * n_segs
-                    for si, sg in zip(seg_ids, shard_gs):
+                    for si, c in zip(fan.seg_ids, counts.tolist()):
                         if si < n_segs:   # a concurrent compact may have
                             # shrunk the list
-                            cands[si] = int((sg >= 0).sum())
+                            cands[si] = c
                 dev_wins = None
                 if self._mesh is not None and self._placement is not None:
                     pl = self._placement

@@ -479,6 +479,35 @@ def _place_diff(prev: SegmentPlacement, segments: Sequence, delta,
     )
 
 
+@functools.lru_cache(maxsize=16)
+def _live_clearer(mesh: Mesh, axis: str):
+    """One jitted tombstone scatter per (mesh, axis): clear the stacked
+    live mask at ``(slot, row)`` pairs, dropping out-of-range pairs.  Like
+    :func:`_slot_writer` it does not donate, for the same reason."""
+    shard = NamedSharding(mesh, P(axis))
+
+    @jax.jit
+    def clear(live, slots, rows):
+        out = live.at[slots, rows].set(False, mode="drop")
+        return jax.lax.with_sharding_constraint(out, shard)
+
+    return clear
+
+
+def clear_live(pl: SegmentPlacement, slots, rows) -> SegmentPlacement:
+    """Tombstone stacked slots in place, instead of a rebuild: one
+    scatter of the ``(slot, row)`` pairs into ``sealed_live``.  A pair
+    whose slot is out of range (``n_dev * per_dev``) is dropped, so a
+    caller can pad to a fixed count: the scatter compiles once per count,
+    whichever slots it touches.  ``slot_keys`` keep the old live
+    fingerprints; the next rebuild's diff rewrites those mask rows with
+    what they already hold."""
+    live = _live_clearer(pl.mesh, pl.axis)(
+        pl.sealed_live, jnp.asarray(slots, jnp.int32),
+        jnp.asarray(rows, jnp.int32))
+    return dataclasses.replace(pl, sealed_live=live)
+
+
 def refresh_delta(pl: SegmentPlacement, delta) -> SegmentPlacement:
     """Re-replicate only the delta leaves of an existing placement.
 

@@ -112,3 +112,55 @@ def test_quantized_query_topk_int8_compiles(one_chip, nq, k):
                          _spec(one_chip, (), jnp.float32),
                          _spec(one_chip, (nq, C), jnp.int32))
     assert "tpu_custom_call" in txt
+
+
+@pytest.mark.parametrize("precision,p,slots", [("fp32", 2.0, 510),
+                                               ("int8", 1.0, 255)])
+def test_stacked_fan_out_compiles(one_chip, precision, p, slots):
+    """The batch's one fan-out program over the stacked sealed segments
+    (255 slots: 2^18 items loaded; 510: grown once by a seal): the query
+    kernel runs once in the slot loop, on one segment's 2-D rows as per
+    segment, and once for the delta; the stacked tables are read in place,
+    neither copied nor sliced per slot."""
+    import re
+
+    from repro.core.index import IndexConfig, LSHIndexState
+    from repro.serve import segments as segmod
+
+    nq, lk, tables, buckets = PALETTE[0], LK, 8, 1024
+    cfg = IndexConfig(n_dims=N, n_tables=tables, n_hashes=lk // tables,
+                      log2_buckets=10, bucket_capacity=32, r=0.5, p=p)
+    quantized = precision == "int8"
+    width = quantize.survivor_width(K, 0, C) if quantized else K
+
+    def state(*lead, db=jnp.float32):
+        return LSHIndexState(
+            alpha=_spec(one_chip, lead + (N, lk)),
+            b=_spec(one_chip, lead + (lk,)),
+            mix=_spec(one_chip, lead + (tables, lk // tables), jnp.uint32),
+            table=_spec(one_chip, lead + (tables, buckets, 32), jnp.int32),
+            counts=_spec(one_chip, lead + (tables, buckets), jnp.int32),
+            db=_spec(one_chip, lead + (SEG, N), db))
+
+    fn = segmod._stacked_query_fn(cfg, width, 4, "compiled", quantized)
+    txt = fn.lower(
+        state(slots, db=jnp.int8 if quantized else jnp.float32),
+        _spec(one_chip, (slots, SEG), jnp.int32),
+        _spec(one_chip, (slots, SEG), jnp.bool_),
+        _spec(one_chip, (slots,)), _spec(one_chip, (), jnp.int32),
+        state(), _spec(one_chip, (SEG,), jnp.int32),
+        _spec(one_chip, (SEG,), jnp.bool_),
+        _spec(one_chip, (nq, N))).compile().as_text()
+    calls = re.findall(
+        r"%_(fused|quantized)_query_impl[.\d]* = .*operand_layout_"
+        r"constraints=\{s32\[(\d+)\]\{0\}, f32\[(\d+),1,64\]\{2,1,0\}, "
+        r"(f32|s8)\[(\d+),64\]\{1,0\}\}", txt)
+    assert txt.count("tpu_custom_call") == len(calls) == 2
+    for _, ids, q_rows, _, n_rows in calls:
+        assert (int(ids), int(q_rows), int(n_rows)) == (nq * C, nq, SEG)
+    assert {c[0] for c in calls} == (
+        {"quantized", "fused"} if quantized else {"fused"})
+    assert not re.search(rf"s32\[{slots},{tables},{buckets},32\]\{{[^}}]*\}} "
+                         r"copy\(", txt)
+    assert not re.search(rf"s32\[(1,)?{tables},{buckets},32\]\{{[^}}]*\}} "
+                         r"dynamic-slice\(", txt)

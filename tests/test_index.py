@@ -97,3 +97,35 @@ def test_bucket_distribution_uniformity(rng_key):
         lidx2.create_index(jax.random.fold_in(rng_key, 2), cfg, 4096), cfg, db)
     np.testing.assert_array_equal(np.asarray(state.table),
                                   np.asarray(state2.table))
+
+
+def test_dedup_drops_duplicates_and_tombstones_on_both_paths(rng_key,
+                                                             monkeypatch):
+    """The scatter dedup (tombstones ride in its first-seen table) and the
+    sort fallback keep the same candidates: each live item once, no dead
+    item, whichever slot of the stack is read."""
+    cfg, db, state = _build(rng_key, n_db=512, r=4.0)
+    q = jax.random.normal(jax.random.fold_in(rng_key, 3), (8, 32))
+    live = jnp.asarray(np.random.default_rng(0).random(512) > 0.3)
+    buckets = lidx.probe_queries(lidx.hash_family(state), cfg, q, 4)
+
+    def kept(slot=None):
+        table, mask = state.table, live
+        if slot is not None:       # the segment at ``slot`` of a stack
+            table = jnp.stack([jnp.full_like(table, -1), table])
+            mask = jnp.stack([jnp.zeros_like(live), live])
+        c = np.asarray(lidx.gather_stage(table, buckets, cfg, 512, mask,
+                                         slot=slot))
+        return [sorted(row[row >= 0].tolist()) for row in c]
+
+    scatter = kept()
+    assert kept(slot=jnp.int32(1)) == scatter
+    monkeypatch.setattr(lidx, "DEDUP_SCATTER_MAX_ELEMS", 0)
+    assert kept() == scatter
+    raw = np.asarray(state.table)[np.arange(cfg.n_tables)[:, None, None],
+                                  np.asarray(buckets).transpose(1, 0, 2)]
+    raw = raw.transpose(1, 0, 2, 3).reshape(8, -1)
+    alive = np.asarray(live)
+    for got, row in zip(scatter, raw):
+        want = sorted({int(i) for i in row if i >= 0 and alive[i]})
+        assert got == want and got

@@ -160,6 +160,24 @@ def test_unsampled_context_suppresses_descendants():
     assert tr.spans() == []
 
 
+def test_fanout_counter_and_wait_span_are_declared():
+    from repro.obs.trace import STAGE_SPANS
+
+    spec = CATALOG["serve_fanout_batches_total"]
+    assert spec.type == "counter"
+    assert sorted(spec.labels) == ["path", "tenant"]
+    assert "fanout.wait" in STAGE_SPANS
+    reg = MetricsRegistry()
+    reg.inc("serve_fanout_batches_total", tenant="t", path="stacked")
+    assert reg.value("serve_fanout_batches_total", tenant="t",
+                     path="stacked") == 1
+    tr = Tracer(sample_rate=1.0, metrics=reg)
+    with tr.span("fanout.wait", tenant="t"):
+        pass
+    assert reg.value("serve_stage_latency_s", tenant="t",
+                     stage="fanout.wait")["count"] == 1
+
+
 def test_stage_spans_feed_latency_histogram():
     reg = MetricsRegistry()
     tr = Tracer(sample_rate=1.0, metrics=reg)
@@ -395,16 +413,20 @@ def _inside(spans, outer):
 
 def test_fp32_batch_names_its_host_work():
     sv, q = _served("t32")
-    live = sum(1 for s in sv.index.segments if s.n_live > 0)
+    n_segs = len(sv.index.segments)
     _, spans = _traced_batch(sv, q)
     batch, = [s for s in spans if s["name"] == "batch"]
     inner = _inside(spans, batch)
     names = {s["name"] for s in inner}
-    assert {"index.lock_wait", "query.segments", "fanout.telemetry",
-            "result.sync"} <= names
+    assert {"index.lock_wait", "query.segments", "fanout.wait",
+            "fanout.telemetry", "result.sync"} <= names
     seg, = [s for s in inner if s["name"] == "query.segments"]
-    assert seg["attrs"]["segments"] == live > 1
-    assert seg["attrs"]["programs"] == live + 3
+    # the stacked program reads every segment in one dispatch
+    assert seg["attrs"]["segments"] == n_segs > 1
+    assert seg["attrs"]["programs"] == 1
+    wait, = [s for s in inner if s["name"] == "fanout.wait"]
+    tele, = [s for s in inner if s["name"] == "fanout.telemetry"]
+    assert seg["t1"] <= wait["t0"] <= wait["t1"] <= tele["t0"]
     # every span of the batch opened on the batcher's thread, inside it
     assert {s["thread"] for s in inner} == {batch["thread"]}
     for s in inner:
@@ -421,9 +443,11 @@ def test_int8_batch_names_the_survivor_gather_and_rerank():
         np.testing.assert_array_equal(a, b)
     batch, = [s for s in spans if s["name"] == "batch"]
     inner = {s["name"]: s for s in _inside(spans, batch)}
-    assert {"survivor.gather", "survivor.rerank", "query.segments"} <= set(
-        inner)
+    assert {"survivor.gather", "survivor.rerank", "query.segments",
+            "fanout.wait"} <= set(inner)
     assert inner["survivor.gather"]["attrs"]["rows"] == 8
+    # the wait for the fan-out program is not the gather's
+    assert inner["fanout.wait"]["t1"] <= inner["survivor.gather"]["t0"]
     assert inner["survivor.gather"]["t1"] <= inner["survivor.rerank"]["t0"]
     ops = {s["attrs"]["op"] for s in _inside(spans, batch)
            if s["name"] == "index.lock_wait"}
@@ -480,7 +504,7 @@ def test_sampled_spans_land_in_the_profiler_trace(tmp_path):
             if plane.name.startswith("/host:")
             for line in plane.lines for ev in line.events]
     for name in ("batch", "index.lock_wait", "query.segments",
-                 "fanout.telemetry", "result.sync"):
+                 "fanout.wait", "fanout.telemetry", "result.sync"):
         assert host.count(name) == sum(s["name"] == name for s in spans), \
             name
     # retroactive spans have no mirror

@@ -12,6 +12,7 @@ Two halves of the contract:
   WAL replay keep working, and the sealed store actually shrinks >= 3x.
 """
 
+import dataclasses
 import os
 import subprocess
 import sys
@@ -19,7 +20,9 @@ import textwrap
 
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
+from _fanout_support import fanout_batches, lifecycle_parity
 from repro.core.index import IndexConfig
 from repro.serve import SegmentedIndex, ServableRegistry, ServableSpec
 
@@ -103,6 +106,36 @@ def test_survivor_k_knob_widens_pool():
     # both are valid answers; the knob must at least be accepted and
     # produce full top-k result sets
     assert (np.asarray(gn) >= 0).all() and (np.asarray(gw) >= 0).all()
+
+
+@pytest.mark.parametrize("p", [1.0, 2.0])
+def test_int8_stacked_fan_out_matches_per_segment_path(p, tmp_path):
+    """The stacked program scores the int8 codes slot by slot exactly as
+    the per-segment programs do: same survivors, same exact rerank."""
+    lifecycle_parity(dataclasses.replace(CFG, p=p), "int8",
+                     str(tmp_path / "q.wal"), np.random.default_rng(12))
+
+
+def test_mixed_precision_tenant_falls_back_to_per_segment():
+    """fp32 segments sealed before the tenant's tier changed cannot stack
+    with its int8 ones: the batch takes one program per segment."""
+    rng = np.random.default_rng(5)
+    db = rng.normal(size=(300, CFG.n_dims)).astype(np.float32)
+    q = rng.normal(size=(5, CFG.n_dims)).astype(np.float32)
+    si = SegmentedIndex(CFG, segment_capacity=64, seed=1, tenant="mixed")
+    si.insert(db[:150])                      # two fp32 seals
+    si.precision = "int8"                    # later seals encode to int8
+    si.insert(db[150:])
+    assert {s.state.db.dtype for s in si.segments if s.sealed} == {
+        jnp.dtype(jnp.float32), jnp.dtype(jnp.int8)}
+    g, _ = si.query(q, 10, n_probes=4)
+    assert fanout_batches("mixed", "per_segment") == 1
+    assert fanout_batches("mixed", "stacked") == 0
+    assert si._placement is None
+    base = SegmentedIndex(CFG, segment_capacity=64, seed=1)
+    base.insert(db)
+    gb, _ = base.query(q, 10, n_probes=4)
+    assert _recall(np.asarray(g), np.asarray(gb)) >= 0.98
 
 
 def test_registry_resolves_env_override_once(monkeypatch):

@@ -17,11 +17,15 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from _fanout_support import (assert_paths_agree, both_paths,
+                             fanout_batches, lifecycle_parity)
 from repro.core import index as lidx
 from repro.kernels import ops
 from repro.serve import (MicroBatcher, SegmentedIndex, ServableRegistry,
                          ServableSpec, ServingStats, occupancy_report,
                          recall_proxy)
+from repro.serve import segments as segmod
+from repro.sharding import placement as seg_placement
 
 N_DIMS = 16
 
@@ -95,6 +99,81 @@ def test_parity_survives_compaction():
     np.testing.assert_array_equal(np.asarray(after), want)
     # compacted segments are repacked to standard capacity (shape reuse)
     assert all(s.capacity == 128 for s in si.segments)
+
+
+@pytest.mark.parametrize("p", [1.0, 2.0])
+def test_stacked_fan_out_matches_per_segment_path(p, tmp_path):
+    """The one stacked program answers bit-identically to one program per
+    segment, across seal, a delete spanning two segments, compaction and
+    WAL replay."""
+    lifecycle_parity(_cfg(p), "fp32", str(tmp_path / "t.wal"),
+                     np.random.default_rng(11))
+
+
+def test_seal_into_headroom_adds_no_compilation():
+    """The stack grows by doubling; a seal into its headroom is a slot
+    write and reuses the compiled program, and a sealed delete is one
+    scatter whose shape depends only on the gid count."""
+    si = SegmentedIndex(_cfg(), segment_capacity=64, insert_chunk=32, seed=3,
+                        tenant="headroom")
+    gids = si.insert(_data(200, seed=1))        # 3 sealed + delta
+    q = _data(8, seed=2, scale=0.9)
+    si.query(q, 10, n_probes=4)                  # a stack of 3 slots
+    si.insert(_data(64, seed=4))                 # 4 sealed: grows to 6
+    si.query(q, 10, n_probes=4)
+    width = si._placement.per_dev
+    assert width == 6
+    fn = segmod._stacked_query_fn(si.cfg, 10, 4, si.backend, False)
+    programs = fn._cache_size()
+    for seed in (5, 6):                          # 5 and 6 sealed
+        si.insert(_data(64, seed=seed))
+        assert_paths_agree(si, q)
+        pl = si._placement
+        assert pl.per_dev == width and pl.diffed
+        assert pl.n_sealed == sum(s.sealed for s in si.segments)
+    assert fn._cache_size() == programs
+
+    clear = seg_placement._live_clearer(si._placement.mesh,
+                                        si._placement.axis)
+    si.delete(gids[:3])                          # three rows of segment 0
+    cleared = clear._cache_size()
+    si.delete(gids[[70, 140, 199]])              # segments 1, 2 and 3
+    assert clear._cache_size() == cleared
+    got = assert_paths_agree(si, q)
+    assert not np.isin(got[0], gids[[0, 1, 2, 70, 140, 199]]).any()
+    assert fn._cache_size() == programs
+
+
+def test_one_fan_out_program_per_batch():
+    reg = ServableRegistry()
+    sv = reg.register(_spec("one-program", segment_capacity=64,
+                            chunk_sizes=(8,)))
+    sv.insert(_data(300, seed=1))
+    before = fanout_batches("one-program", "stacked")
+    sv.query(_data(5, seed=2), 10, n_probes=4)
+    sv.query(_data(8, seed=3), 10, n_probes=4)
+    assert fanout_batches("one-program", "stacked") == before + 2
+    assert fanout_batches("one-program", "per_segment") == 0
+
+
+def test_fanout_telemetry_matches_per_segment_path():
+    """Wins and per-segment candidate counts come out the same from the
+    stacked program's one count vector as from the per-segment shards."""
+    calls = []
+    si = SegmentedIndex(_cfg(), segment_capacity=64, insert_chunk=32, seed=3,
+                        tenant="tele-paths",
+                        on_fanout=lambda *a: calls.append(a))
+    gids = si.insert(_data(300, seed=1))
+    si.delete(gids[::9])
+    stacked, per_segment = both_paths(si, _data(9, seed=2, scale=0.9))
+    for a, b in zip(stacked, per_segment):
+        np.testing.assert_array_equal(a, b)
+    (wins, dev_wins, cands), again = calls
+    assert again == (wins, dev_wins, cands)
+    assert dev_wins is None
+    assert len(wins) == len(cands) == len(si.segments)
+    assert sum(wins) == int((stacked[0] >= 0).sum())
+    assert all(c >= w for c, w in zip(cands, wins)) and sum(cands) > 0
 
 
 def test_segment_lifecycle_and_occupancy():
