@@ -12,8 +12,9 @@ reference (``chipbench.reference``):
   empty slots (-1 / inf) anywhere but at the end (limit 0);
 * ``bad_gids``: served gids never acknowledged, or deleted by an
   acknowledgement that came before the query was sent (limit 0);
-* ``readback_miss``: sampled acknowledged inserts not served as their own
-  nearest neighbour at distance 0 (limit per configuration,
+* ``readback_miss``: sampled acknowledged inserts (the window's, and the
+  bulk load's where the traffic sets ``check_loaded``) not served as their
+  own nearest neighbour at distance 0 (limit per configuration,
   ``limits.readback_miss``: the index drops an item from every bucket that
   is already at capacity, so a sound run misses the few whose buckets are
   all full; the limit lies below what a loss of one insert in eight reads);

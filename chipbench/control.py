@@ -36,7 +36,7 @@ def readings(bench, cell, seed: int, seconds: float, **kw) -> dict:
     """One run's compared numbers and its controls' readings."""
     controls = {}
 
-    def inspect(answers, ledger, config, inputs):
+    def inspect(answers, ledger, config, inputs, **_):
         for kind in checkmod.CONTROLS[config["precision"]]:
             low = checkmod.control_answers(answers, ledger, p=inputs["p"],
                                            kind=kind)

@@ -11,7 +11,10 @@ where the work is produced:
 * ``drop_one_insert_in_8``: every eighth write-sized insert (at most
   ``WRITE_ROWS_MAX`` rows; the bulk load's calls are larger) is
   acknowledged and never applied: a partial loss;
-* ``ignore_deletes``: every delete is acknowledged and never applied.
+* ``ignore_deletes``: every delete is acknowledged and never applied;
+* ``drop_exchange``: the sharded fan-out's ``all_gather`` between chips is
+  left out (each chip keeps its own top-k), so the merged answer is chip
+  0's alone: the sealed segments on the other chips are never searched.
 
 ``python3 -m chipbench.control --fault <name>`` reads one on the chip;
 ``tests/chipbench/test_faults.py`` plants each under a whole CPU run.
@@ -88,5 +91,17 @@ def ignore_deletes(patch) -> None:
     patch(_servable(), "delete", lambda self, gids: len(gids))
 
 
+def drop_exchange(patch) -> None:
+    import jax
+    from repro.core import distributed
+
+    # the sharded program is traced (and cached) on its first call: plant
+    # the fault before it, and forget a program built without it
+    distributed._sharded_segment_query_fn.cache_clear()
+    patch(jax.lax, "all_gather",
+          lambda x, axis_name, **_: x[None])
+
+
 FAULTS = {f.__name__: f for f in (alter_answers, half_batch, drop_inserts,
-                                  drop_one_insert_in_8, ignore_deletes)}
+                                  drop_one_insert_in_8, ignore_deletes,
+                                  drop_exchange)}

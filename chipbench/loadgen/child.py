@@ -11,7 +11,8 @@ starts the window when it reads ``go`` on its standard input; the garbage
 collector stays off in the window.  It prints ``closed`` when the window
 ends, waits for every request still outstanding (at most ``DRAIN_S``
 more), writes ``results.json`` (the records, the lateness, and how often
-the child was preempted in the window) and prints ``done``.
+the child was preempted, the CPU time it used and its page faults in the
+window) and prints ``done``.
 
 Every time is ``time.perf_counter()``, which on Linux reads the same
 monotonic clock in every process, so the server's spans and these records
@@ -94,6 +95,7 @@ def run_open(conns, plan, pools, t0: float) -> list:
         wait = rec["due"] - time.perf_counter()
         if wait > 0:
             time.sleep(wait)
+        rec["put"] = time.perf_counter()     # handed to the workers
         todo.put(rec)
     return records, threads, todo
 
@@ -140,14 +142,19 @@ def main(argv=None) -> int:
         return 2
     gc.freeze()
     gc.disable()
-    switches0 = resource.getrusage(resource.RUSAGE_SELF).ru_nivcsw
+    use0 = resource.getrusage(resource.RUSAGE_SELF)
     t0 = time.perf_counter()
     run = run_open if plan["mode"] == "open" else run_closed
     records, threads, todo = run(conns, plan, pools, t0)
     left = t0 + plan["seconds"] - time.perf_counter()
     if left > 0:
         time.sleep(left)
-    preempted = resource.getrusage(resource.RUSAGE_SELF).ru_nivcsw - switches0
+    use1 = resource.getrusage(resource.RUSAGE_SELF)
+    preempted = use1.ru_nivcsw - use0.ru_nivcsw
+    cpu_ms = (use1.ru_utime + use1.ru_stime
+              - use0.ru_utime - use0.ru_stime) * 1e3
+    faults = (use1.ru_majflt - use0.ru_majflt,
+              use1.ru_minflt - use0.ru_minflt)
     gc.enable()
     print("closed", flush=True)
     if todo is not None:
@@ -163,14 +170,19 @@ def main(argv=None) -> int:
         if "ok" not in r:
             r.update(ok=False, recv=None, code="lost: no answer")
     late = schedule.lateness(records)
+    # the schedule thread's own lateness: where it is small and the send
+    # was late, the request waited for a free connection, not for the core
+    late["put_max_ms"] = max(((r["put"] - r["due"]) * 1e3 for r in records
+                              if "put" in r), default=0.0)
     print(f"[chipbench] load generator lateness: sent={late['n']} "
           f"p50_ms={late['p50_ms']!r} p99_ms={late['p99_ms']!r} "
-          f"max_ms={late['max_ms']!r} preempted={preempted}",
-          file=sys.stderr, flush=True)
+          f"max_ms={late['max_ms']!r} put_max_ms={late['put_max_ms']!r} "
+          f"preempted={preempted}", file=sys.stderr, flush=True)
     with open(os.path.join(plan_dir, "results.json"), "w",
               encoding="utf-8") as f:
         json.dump({"t0": t0, "records": records, "lateness": late,
-                   "preempted": preempted}, f)
+                   "preempted": preempted, "cpu_ms": cpu_ms,
+                   "faults": faults}, f)
     for c in conns:
         c.close()
     print("done", flush=True)

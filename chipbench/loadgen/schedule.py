@@ -23,7 +23,9 @@ Traffic file keys (``chipbench/traffic/<name>.json``):
 * ``probe_rows`` / ``probe_request_rows``: the held-out recall probe asked
   after the window;
 * ``check_rows`` / ``check_deletes``: acknowledged inserted rows and
-  deleted gids read back after it, drawn from the seed.
+  deleted gids read back after it, drawn from the seed;
+* ``check_loaded`` (optional): loaded items still live, read back after
+  it in requests of ``probe_request_rows``, drawn from the seed.
 """
 
 from __future__ import annotations

@@ -12,9 +12,9 @@ imports JAX sends the cell's traffic over the wire:
 2. the window: ``--seconds`` of traffic (``chipbench.loadgen``); with
    ``--trace 1`` the profiler records it;
 3. after it: the held-out recall probe and the read-backs through the same
-   wire entry, the device's peak memory, then the program's state is
-   dropped and the plain reference (``chipbench.reference``) judges every
-   answer (``chipbench.check``).
+   wire entry, the peak memory of each chip the cell uses, then the
+   program's state is dropped and the plain reference
+   (``chipbench.reference``) judges every answer (``chipbench.check``).
 
 The load generator gets a core of its own and the server's threads the
 others, so the server's host work cannot hold back a send.
@@ -22,10 +22,12 @@ others, so the server's host work cannot hold back a send.
 The last line of standard output is one JSON object: ``correct``,
 ``attempted``, ``failed``, ``metrics`` (the cell's end-to-end metrics, or
 with ``--trace 1`` its per-layer metrics), ``device``, with ``--trace 1``
-``breakdown``, ``load`` (how late the load generator sent, and how often
-each side was preempted in the window), and last ``checks``: each compared
-number with its limit, which also end standard error.  With no TPU, or fewer chips than the cell
-asks for, it exits 1 and prints no result.
+``breakdown``, ``load`` (how late the load generator sent, how often each
+side was preempted, the sender's CPU time and page faults, how late the
+server's ticker woke and its garbage collections; ``chipbench.host``),
+and last ``checks``: each compared number with its limit, which also end
+standard error.  With no TPU, or fewer chips than the cell asks for, it
+exits 1 and prints no result.
 """
 
 from __future__ import annotations
@@ -50,6 +52,7 @@ import threading  # noqa: E402
 
 from . import bench as benchmod  # noqa: E402
 from . import check as checkmod  # noqa: E402
+from . import host as hostmod  # noqa: E402
 from . import reference, server, wire  # noqa: E402
 from .loadgen import child, schedule  # noqa: E402
 from .trace import reduce, xplane  # noqa: E402
@@ -255,9 +258,10 @@ def run_cell(bench, cell, seed: int, seconds: float, trace: bool, *,
     """One run of ``cell``; returns the result object (``checks`` last).
 
     ``require_tpu=False`` lets the tests drive a run on the CPU;
-    ``inspect(answers=, ledger=, config=, inputs=)``, when given, sees what
-    the reference judged and the other inputs of ``check.check``
-    (``chipbench.control`` reads its controls there)."""
+    ``inspect(answers=, ledger=, config=, inputs=, spans=, layout=)``, when
+    given, sees what the reference judged, the other inputs of
+    ``check.check``, the program's spans of the window and the index's
+    ``shard_layout`` (``chipbench.control`` reads its controls there)."""
     src = os.path.join(bench.root, "src")
     if not os.path.isdir(os.path.join(src, "repro")):
         raise FileNotFoundError(f"no program under {src}")
@@ -313,10 +317,14 @@ def run_cell(bench, cell, seed: int, seconds: float, trace: bool, *,
     proc = None
     all_cpus = os.sched_getaffinity(0)
     try:
-        registry, sv = server.build_registry(config,
-                                             os.path.join(workdir, "wal"))
+        registry, sv = server.build_registry(
+            config, os.path.join(workdir, "wal"), cell.chips)
         loaded = server.bulk_load(sv, rows["items"],
                                   int(config["load_rows_per_call"]), say)
+        layout = sv.index.shard_layout()
+        if layout is not None:
+            say(f"serve mesh n_dev={layout['n_dev']} "
+                f"per_dev={layout['per_dev']} n_sealed={layout['n_sealed']}")
         ledger = checkmod.Ledger()
         ledger.acknowledge(loaded, rows["items"])
         answers = checkmod.Answers()
@@ -369,7 +377,8 @@ def run_cell(bench, cell, seed: int, seconds: float, trace: bool, *,
             proc.stdin.flush()
             t_window0 = time.perf_counter()
             switches0 = preempted()
-            child_says("closed", seconds + 60.0)
+            with hostmod.Watch() as watch:
+                child_says("closed", seconds + 60.0)
             t_window1 = time.perf_counter()
             server_switches = preempted() - switches0
         if trace:
@@ -401,7 +410,8 @@ def run_cell(bench, cell, seed: int, seconds: float, trace: bool, *,
                             r["send"])
 
         # after the window, through the same entry: the recall probe and
-        # the read-backs of sampled acknowledged writes
+        # the read-backs of sampled acknowledged writes (and of loaded
+        # items, where the traffic asks)
         probe_gids = _query_all(conn, tenant, rows["probe"],
                                 int(traffic["probe_request_rows"]), k,
                                 n_probes, answers)
@@ -431,8 +441,22 @@ def run_cell(bench, cell, seed: int, seconds: float, trace: bool, *,
             got = _query_all(conn, tenant, ledger.rows_of(gone), w_rows, k,
                              n_probes, answers)
             deleted_served = int((got == gone[:, None]).any(axis=1).sum())
-        stats = dev.memory_stats() or {}
-        peak = stats.get("peak_bytes_in_use")
+        n_check_loaded = int(traffic.get("check_loaded", 0))
+        if n_check_loaded:
+            live = loaded[~np.isin(loaded, list(ledger.deleted_at))]
+            want = live[pick.choice(len(live), min(n_check_loaded,
+                                                   len(live)),
+                                    replace=False)]
+            got = _query_all(conn, tenant, ledger.rows_of(want),
+                             int(traffic["probe_request_rows"]), k,
+                             n_probes, answers)
+            dists = np.concatenate(answers.dists)[-len(want):]
+            readback_miss += int(((got[:, 0] != want)
+                                  | (dists[:, 0] != 0)).sum())
+        # the peak of every chip the cell uses; the fullest is reported
+        peaks_each = [(d.memory_stats() or {}).get("peak_bytes_in_use")
+                      for d in devices[:cell.chips]]
+        peak = max((b for b in peaks_each if b is not None), default=None)
         spans = [s for s in obs_trace.tracer().spans()
                  if t_window0 <= s["t0"] <= t_window1]
         conn.close()
@@ -474,7 +498,8 @@ def run_cell(bench, cell, seed: int, seconds: float, trace: bool, *,
             f" lost={lost} failed={failed} recall_at_10={recall!r}")
 
         device = {"platform": dev.platform, "kind": dev.device_kind,
-                  "count": len(devices), "memory_peak_bytes": peak}
+                  "count": len(devices), "memory_peak_bytes": peak,
+                  "memory_peak_bytes_per_device": peaks_each}
         out = {"correct": all(c["ok"] for c in checks.values()),
                "attempted": attempted, "failed": failed}
         if not trace:
@@ -512,14 +537,18 @@ def run_cell(bench, cell, seed: int, seconds: float, trace: bool, *,
         out["load"] = {"late_p50_ms": late["p50_ms"],
                        "late_p99_ms": late["p99_ms"],
                        "late_max_ms": late["max_ms"],
+                       "late_put_max_ms": late.get("put_max_ms"),
                        "sender_preempted": results["preempted"],
-                       "server_preempted": server_switches}
+                       "server_preempted": server_switches,
+                       "sender_cpu_ms": results.get("cpu_ms"),
+                       "sender_faults": results.get("faults"),
+                       **watch.summary()}
         say("load " + json.dumps(out["load"]))
         out["checks"] = {name: {"value": c["value"], "limit": c["limit"]}
                          for name, c in checks.items()}
         if inspect is not None:
             inspect(answers=answers, ledger=ledger, config=config,
-                    inputs=inputs)
+                    inputs=inputs, spans=spans, layout=layout)
         for name, c in checks.items():
             say(f"check {name}={c['value']!r} limit={c['limit']!r} "
                 f"{'ok' if c['ok'] else 'FAILED'}")

@@ -1,10 +1,11 @@
 """The system under test, as the benchmark runs it in its own process.
 
 The chip belongs to one process, so the server runs here: the tenant's
-``ServableRegistry`` (write-ahead log on), loaded in bulk through
-``Servable.insert``, served by ``repro.serve.frontend.Frontend`` on an
-asyncio loop in a daemon thread.  ``CompileLog`` and ``BackgroundFrontend``
-are copies of the same pieces of ``chip_smoke.py``.
+``ServableRegistry`` (write-ahead log on, over a serve mesh where the
+configuration states one), loaded in bulk through ``Servable.insert``,
+served by ``repro.serve.frontend.Frontend`` on an asyncio loop in a daemon
+thread.  ``CompileLog`` and ``BackgroundFrontend`` are copies of the same
+pieces of ``chip_smoke.py``.
 """
 
 from __future__ import annotations
@@ -77,18 +78,44 @@ class BackgroundFrontend:
             self.loop.close()
 
 
-def build_registry(config: dict, wal_dir: str):
-    """The configuration's one tenant, registered with its WAL."""
+def build_registry(config: dict, wal_dir: str, chips: int = 1):
+    """The configuration's one tenant, registered with its WAL.
+
+    A configuration with ``"mesh": {"axis": ..., "devices": n}`` serves the
+    tenant SPMD over a serve mesh of ``n`` devices, as ``launch.serve
+    --shard n`` does (``launch.mesh.make_serve_mesh``); the run is refused
+    unless ``n`` is the cell's ``chips``, the spec shards over that axis,
+    and the registered index is laid out over all ``n`` devices.  Without
+    ``mesh`` the tenant stays on one device."""
     from repro.serve import ServableRegistry, ServableSpec
 
     spec = dict(config["spec"])
     spec["chunk_sizes"] = tuple(spec["chunk_sizes"])
+    axis = config["mesh"]["axis"] if "mesh" in config else None
+    if spec.get("shard_axis") != axis:
+        raise RuntimeError(f"the spec shards over {spec.get('shard_axis')!r},"
+                           f" the configuration's mesh axis is {axis!r}")
+    mesh = None
+    if axis is not None:
+        from repro.launch.mesh import make_serve_mesh
+
+        n_dev = int(config["mesh"]["devices"])
+        if n_dev != chips:
+            raise RuntimeError(f"the configuration's mesh has {n_dev} "
+                               f"devices, the cell runs on {chips} chip(s)")
+        mesh = make_serve_mesh(n_dev, axis)
     registry = ServableRegistry(
-        wal_dir=wal_dir, fsync_every=config["guarantees"]["fsync_every"])
+        mesh=mesh, wal_dir=wal_dir,
+        fsync_every=config["guarantees"]["fsync_every"])
     sv = registry.register(ServableSpec(**spec))
     if sv.spec.precision != config["precision"]:
         raise RuntimeError(f"tenant serves {sv.spec.precision}, the "
                            f"configuration states {config['precision']}")
+    if mesh is not None:
+        layout = sv.index.shard_layout()
+        if layout is None or layout["n_dev"] != n_dev:
+            raise RuntimeError(f"the tenant is not sharded over {n_dev} "
+                               f"devices: layout {layout}")
     return registry, sv
 
 

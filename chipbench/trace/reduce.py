@@ -10,8 +10,10 @@ the window marker, which both clocks saw open.
 
 from __future__ import annotations
 
-import bisect
+import heapq
 from typing import Dict, Iterable, List, Sequence, Tuple
+
+import numpy as np
 
 OPS_LINE = "XLA Ops"
 MODULES_LINE = "XLA Modules"
@@ -43,21 +45,42 @@ def device_lines(plain: dict, line: str) -> Dict[str, List[list]]:
     return out
 
 
+def _union(intervals) -> Tuple[np.ndarray, np.ndarray]:
+    """(starts, ends) of the union of [start, end) intervals (each start
+    <= end; pairs or an (n, 2) array), sorted: intervals that overlap or
+    touch join.  Vectorised, for traces of millions of events."""
+    iv = np.asarray(intervals if isinstance(intervals, np.ndarray)
+                    else list(intervals), np.float64).reshape(-1, 2)
+    if not len(iv):
+        return iv[:, 0], iv[:, 1]
+    iv = iv[np.lexsort((iv[:, 1], iv[:, 0]))]
+    starts, reach = iv[:, 0], np.maximum.accumulate(iv[:, 1])
+    first = np.flatnonzero(np.r_[True, starts[1:] > reach[:-1]])
+    last = np.r_[first[1:] - 1, len(starts) - 1]
+    return starts[first], reach[last]
+
+
 def merged(intervals: Iterable[Tuple[float, float]]) -> List[List[float]]:
     """Union of [start, end) intervals, sorted and non-overlapping."""
-    out: List[List[float]] = []
-    for s, e in sorted(intervals):
-        if out and s <= out[-1][1]:
-            out[-1][1] = max(out[-1][1], e)
-        else:
-            out.append([s, e])
-    return out
+    return np.stack(_union(intervals), axis=1).tolist()
+
+
+def clipped(events: Sequence[list], t0: float, t1: float) -> np.ndarray:
+    """(n, 2) [start, end) of each event cut to [t0, t1), the events that
+    leave nothing there dropped."""
+    n = len(events)
+    start = np.fromiter((ev[1] for ev in events), np.float64, n)
+    end = np.minimum(start + np.fromiter((ev[2] for ev in events),
+                                         np.float64, n), t1)
+    start = np.maximum(start, t0)
+    keep = end > start
+    return np.stack([start[keep], end[keep]], axis=1)
 
 
 def busy_ns(events: Sequence[list], t0: float, t1: float) -> float:
     """Nanoseconds of [t0, t1) in which some event ran."""
-    iv = ((max(s, t0), min(s + d, t1)) for _, s, d in events)
-    return sum(e - s for s, e in merged((s, e) for s, e in iv if e > s))
+    starts, ends = _union(clipped(events, t0, t1))
+    return sum((ends - starts).tolist())
 
 
 def busy_s(plain: dict) -> float:
@@ -81,16 +104,10 @@ def idle_percent(plain, busy: float, window_s: float):
 def idle_gaps(events: Sequence[list], t0: float, t1: float
               ) -> List[Tuple[float, float]]:
     """The [start, end) stretches of the window in which no event ran."""
-    gaps, cur = [], t0
-    for s, e in merged((max(s, t0), min(s + d, t1)) for _, s, d in events):
-        if e <= s:
-            continue
-        if s > cur:
-            gaps.append((cur, s))
-        cur = max(cur, e)
-    if cur < t1:
-        gaps.append((cur, t1))
-    return gaps
+    starts, ends = _union(clipped(events, t0, t1))
+    lo, hi = np.r_[t0, ends], np.r_[starts, t1]
+    keep = hi > lo
+    return list(zip(lo[keep].tolist(), hi[keep].tolist()))
 
 
 def label_gaps(gaps: Sequence[Tuple[float, float]],
@@ -100,18 +117,25 @@ def label_gaps(gaps: Sequence[Tuple[float, float]],
     midpoint (the latest-opened one, i.e. the innermost; ``"no span"``
     when none is).  Gaps with the same label are summed."""
     spans = sorted(host_spans, key=lambda sp: sp[1])
-    starts = [sp[1] for sp in spans]
+    mids = [(s + e) / 2 for s, e in gaps]
+    label = ["no span"] * len(mids)
+    # midpoints in time order; ``opened`` holds every span opened at or
+    # before the midpoint, the latest-opened on top, and a span on top
+    # that has closed is dropped for good (later midpoints lie later)
+    opened: List[Tuple[int, float]] = []
+    nxt = 0
+    for j in sorted(range(len(mids)), key=mids.__getitem__):
+        mid = mids[j]
+        while nxt < len(spans) and spans[nxt][1] <= mid:
+            heapq.heappush(opened, (-nxt, spans[nxt][2]))
+            nxt += 1
+        while opened and opened[0][1] <= mid:
+            heapq.heappop(opened)
+        if opened:
+            label[j] = spans[-opened[0][0]][0]
     totals: Dict[str, float] = {}
-    for s, e in gaps:
-        mid = (s + e) / 2
-        best = "no span"
-        # the covering span that opened last: scan back from the last
-        # span opened before the midpoint
-        for i in range(bisect.bisect_right(starts, mid) - 1, -1, -1):
-            if spans[i][2] > mid:
-                best = spans[i][0]
-                break
-        totals[best] = totals.get(best, 0.0) + (e - s)
+    for j, (s, e) in enumerate(gaps):
+        totals[label[j]] = totals.get(label[j], 0.0) + (e - s)
     ranked = sorted(totals.items(), key=lambda kv: -kv[1])[:top]
     return [[name, ns / 1e9] for name, ns in ranked]
 

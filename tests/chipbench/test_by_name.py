@@ -55,3 +55,40 @@ def test_new_cell_from_new_files_only(tmp_path):
     assert out["correct"], out["checks"]
     assert out["metrics"]["batcher.batches"]["value"] >= 1
     assert list(out)[-1] == "checks"
+
+
+COLLECTIVES = '''"""fanout.collectives: ``query.collective`` spans in the window."""
+
+
+def read(ctx):
+    n = sum(1 for s in ctx.spans if s["name"] == "query.collective")
+    return n or None
+'''
+
+
+def test_new_mesh_cell_from_new_files_only(tmp_path):
+    """A configuration that states a serve mesh, added by files alone, runs
+    sharded: here over a one-device mesh, the degenerate case of the same
+    SPMD program."""
+    root = tinybench.make_root(tmp_path)
+    with open(os.path.join(root, "chipbench", "configs",
+                           "w2-quantile-262k.json"), encoding="utf-8") as f:
+        config = json.load(f)
+    config["name"] = "w2-quantile-tiny-mesh1"
+    traffic = {"mode": "closed", "clients": 1, "query": {"rows": 1},
+               "pool_per_client": 4, "warm_chunks": [8], "probe_rows": 8,
+               "probe_request_rows": 8, "check_rows": 0}
+    tinybench.add_cell(root, "w2q-mesh1-search-one", config["name"],
+                       "one-client-mesh", config, traffic,
+                       "fanout.collectives", COLLECTIVES, chips=1,
+                       mesh={"axis": "serve", "devices": 1})
+
+    bench = benchmod.Benchmark(root)
+    cell = bench.cell("w2q-mesh1-search-one")
+    assert cell.chips == 1
+    assert cell.config["mesh"] == {"axis": "serve", "devices": 1}
+    assert cell.config["spec"]["shard_axis"] == "serve"
+    out = run.run_cell(bench, cell, 6, 1.0, True, require_tpu=False,
+                       t_start=time.perf_counter())
+    assert out["correct"], out["checks"]
+    assert out["metrics"]["fanout.collectives"]["value"] >= 1

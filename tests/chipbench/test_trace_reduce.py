@@ -57,6 +57,80 @@ def test_busy_union_matches_a_timeline(fixture):
     assert 0 < busy < t1 - t0
 
 
+def _plain_merged(intervals):
+    """The union the slow way, one interval at a time."""
+    out = []
+    for s, e in sorted(intervals):
+        if out and s <= out[-1][1]:
+            out[-1][1] = max(out[-1][1], e)
+        else:
+            out.append([s, e])
+    return out
+
+
+@pytest.mark.parametrize("seed", [0, 1, 2])
+def test_vectorised_union_equals_the_plain_one(seed):
+    """Overlapping, nested, touching, empty and out-of-window events, on
+    a coarse grid so that ties happen: every number comes out exactly as
+    the plain loop gives it."""
+    rng = np.random.default_rng(seed)
+    starts = rng.integers(0, 400, 3000).astype(float) * 0.5
+    durs = rng.choice([0.0, 0.5, 1.0, 3.5, 40.0], 3000)
+    events = [["op", s, d] for s, d in zip(starts, durs)]
+    t0, t1 = 20.0, 180.0
+    cut = [(max(s, t0), min(s + d, t1)) for _, s, d in events]
+    cut = [(s, e) for s, e in cut if e > s]
+    want = _plain_merged(cut)
+    assert reduce.merged(cut) == want
+    assert reduce.merged(reduce.clipped(events, t0, t1)) == want
+    assert reduce.busy_ns(events, t0, t1) == sum(e - s for s, e in want)
+    gaps, cur = [], t0
+    for s, e in want:
+        if s > cur:
+            gaps.append((cur, s))
+        cur = max(cur, e)
+    if cur < t1:
+        gaps.append((cur, t1))
+    assert reduce.idle_gaps(events, t0, t1) == gaps
+    assert reduce.merged([]) == []
+    assert reduce.idle_gaps([], t0, t1) == [(t0, t1)]
+
+
+def _plain_labels(gaps, host_spans):
+    """Each gap's label the slow way: scan back from the last span opened
+    at or before its midpoint to the first one still open."""
+    spans = sorted(host_spans, key=lambda sp: sp[1])
+    out = []
+    for s, e in gaps:
+        mid = (s + e) / 2
+        best = "no span"
+        for sp in reversed([sp for sp in spans if sp[1] <= mid]):
+            if sp[2] > mid:
+                best = sp[0]
+                break
+        out.append((best, e - s))
+    return out
+
+
+@pytest.mark.parametrize("seed", [0, 1, 2])
+def test_gap_labels_equal_the_plain_scan(seed):
+    """Nested, tied and closed spans, gaps in any order."""
+    rng = np.random.default_rng(seed)
+    host = []
+    for i in range(300):
+        a = float(rng.integers(0, 200))
+        host.append((f"s{i % 7}", a, a + float(rng.choice([0.0, 1.0, 5.0,
+                                                           30.0]))))
+    gaps = [(float(a), float(a) + float(rng.choice([0.0, 1.0, 2.0])))
+            for a in rng.integers(-10, 240, 500)]
+    totals = {}
+    for name, sec in _plain_labels(gaps, host):
+        totals[name] = totals.get(name, 0.0) + sec
+    want = [[n, ns / 1e9] for n, ns in
+            sorted(totals.items(), key=lambda kv: -kv[1])[:10]]
+    assert reduce.label_gaps(gaps, host) == want
+
+
 def test_idle_gaps_cover_what_busy_leaves(fixture):
     t0, t1 = reduce.window(fixture)
     ops = _ops(fixture)

@@ -1,10 +1,11 @@
 """A benchmark root at a size the CPU runs in seconds, for the tests.
 
 ``make_root(tmp)`` copies the ``chipbench`` package into ``tmp``, links the
-repository's ``src`` beside it, and writes a ``BENCHMARK.json`` whose two
-cells keep the committed configurations' shapes and traffic mixes but hold
-2,048 items and send a few requests.  New cells, configurations, traffic
-mixes and metrics are added as files, exactly as a later change would.
+repository's ``src`` beside it, and writes a ``BENCHMARK.json`` whose cells
+keep the committed configurations' shapes (a serve mesh among them), chips
+and traffic mixes but hold 2,048 items (two sealed segments per device of
+a mesh) and send a few requests.  New cells, configurations, traffic mixes
+and metrics are added as files, exactly as a later change would.
 """
 
 from __future__ import annotations
@@ -38,6 +39,9 @@ def make_root(tmp: str) -> str:
         with open(path, encoding="utf-8") as f:
             cfg = json.load(f)
         cfg["items"] = TINY_ITEMS
+        if "mesh" in cfg:
+            cfg["items"] = (2 * int(cfg["mesh"]["devices"])
+                            * int(cfg["spec"]["segment_capacity"]))
         cfg["load_rows_per_call"] = 1024
         _write(path, cfg)
     tdir = os.path.join(root, "chipbench", "traffic")
@@ -55,6 +59,8 @@ def make_root(tmp: str) -> str:
             traffic["clients"] = 2
             traffic["pool_per_client"] = 8
         traffic["probe_rows"] = 16
+        if "check_loaded" in traffic:
+            traffic["check_loaded"] = 64
         _write(path, traffic)
     _write(os.path.join(root, "BENCHMARK.json"), bench)
     return root
@@ -62,10 +68,16 @@ def make_root(tmp: str) -> str:
 
 def add_cell(root: str, cell: str, config: str, traffic: str,
              config_obj: dict, traffic_obj: dict, metric: str,
-             metric_src: str) -> None:
+             metric_src: str, chips: int = 1, mesh=None) -> None:
     """A new cell from new files only: configuration, traffic mix and a
-    per-layer metric, plus their entries in ``BENCHMARK.json``."""
+    per-layer metric, plus their entries in ``BENCHMARK.json``.  A
+    ``mesh`` (``{"axis": ..., "devices": n}``) is written into the
+    configuration, and its spec shards over that axis."""
     pkg = os.path.join(root, "chipbench")
+    if mesh is not None:
+        config_obj = copy.deepcopy(config_obj)
+        config_obj["mesh"] = mesh
+        config_obj["spec"]["shard_axis"] = mesh["axis"]
     _write(os.path.join(pkg, "configs", f"{config}.json"), config_obj)
     _write(os.path.join(pkg, "traffic", f"{traffic}.json"), traffic_obj)
     with open(os.path.join(pkg, "metrics", f"{metric}.py"), "w",
@@ -79,7 +91,7 @@ def add_cell(root: str, cell: str, config: str, traffic: str,
         "file": f"chipbench/configs/{config}.json", "reduced": ["items"],
         "why": "a configuration added by files alone"})
     bench["workloads"].append({
-        "name": cell, "config": config, "traffic": traffic, "chips": 1,
+        "name": cell, "config": config, "traffic": traffic, "chips": chips,
         "why": "a cell added by files alone"})
     metric_entry = copy.deepcopy(bench["per_layer"][0])
     metric_entry.update(name=metric, workloads=[cell])
