@@ -15,11 +15,13 @@ Design (TPU adaptation of the classical pointer-based LSH table):
 
 Kernel dispatch: hashing goes through kernels/ops.pstable_hash{,_proj}
 (hash_mm on TPU) and the re-rank/top-k tail goes through
-ops.fused_query_topk (kernels/fused_query on TPU: candidate rows are
-gathered HBM->VMEM by a scalar-prefetch index map, so the (nq, C, N)
-candidate tensor never exists in HBM).  On CPU both default to the jnp
-reference; pass ``backend="interpret"`` (or set REPRO_QUERY_BACKEND) to
-run the fused kernel under the Pallas interpreter for validation.
+ops.fused_query_topk (kernels/fused_query on TPU: a segment's rows sit in
+VMEM and a block of candidates' rows is gathered there per grid step; a
+database past VMEM has each candidate's row tile DMA'd per grid step; the
+(nq, C, N) candidate tensor never exists in HBM).  On CPU both default to
+the jnp reference; pass ``backend="interpret"`` (or set
+REPRO_QUERY_BACKEND) to run the fused kernel under the Pallas interpreter
+for validation.
 
 Hashing is deliberately NOT switchable per call: build- and query-time
 bucket ids must match bitwise, so both sides use the process-constant
@@ -374,9 +376,9 @@ def segment_topk(table: Array, db: Array, gids: Array, live: Array,
             -- (n_slots, L, B, S) tables, (n_slots, n_cap) gids and live --
             read in place at ``slot``.
         db: the segment's own (n_cap, N) rows, whichever form the rest
-            takes: the kernel gathers one row tile per grid step, so its
-            rows belong where XLA can stage a segment (fast memory), not in
-            a stack of them.
+            takes: the kernel takes a segment's rows whole into VMEM and
+            gathers each candidate's row there, so it gets one segment's
+            rows, not a stack of them.
         q, buckets: (nq, N) queries and their :func:`probe_queries`.
         k: results per query (static).
         scale: the segment's dequant scale when ``db`` holds int8/bf16

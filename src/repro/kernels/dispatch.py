@@ -16,10 +16,10 @@ Two independent choices are made here:
 * **query backend** -- which re-rank path ``core.index.query_index`` takes:
   ``"fused"`` (the gather+rerank+top-k kernel in fused_query.py) or
   ``"reference"`` (gather to HBM + jnp re-rank + ``lax.top_k``).
-  Default: fused on TPU, reference on CPU -- interpret-mode execution of a
-  per-candidate grid is correct but far too slow to be a production CPU
-  path (it exists for parity tests and benchmarks).  Override with
-  ``REPRO_QUERY_BACKEND`` or ``backend=``.
+  Default: fused on TPU, reference on CPU -- interpret-mode execution of
+  the kernel's per-candidate gather loop is correct but far too slow to be
+  a production CPU path (it exists for parity tests and benchmarks).
+  Override with ``REPRO_QUERY_BACKEND`` or ``backend=``.
 
 Block sizes: MXU/VPU-aligned 128 tiles when a dimension is large enough,
 else the dimension rounded up to the 8-sublane quantum so small problems

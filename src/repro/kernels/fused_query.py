@@ -1,36 +1,59 @@
-"""Fused gather + masked L^p re-rank + partial top-k query kernel.
+"""Fused gather + masked L^p re-rank + top-k query kernel.
 
 The classical LSH query tail -- gather candidate embeddings, compute exact
 distances, select top-k -- is memory-bound: the naive jnp path materializes
 a ``(nq, C, N)`` candidate tensor in HBM (C = tables x probes x capacity,
 routinely 10^3), then a same-shape difference tensor, then sorts.  This
-kernel never builds either:
+module never builds either.  It has two kernels, chosen by the database's
+size (``_resident``), and both answer ``ref.fused_query_topk_ref``'s ids
+when distances are distinct.
 
-* the grid is ``(nq, C)`` -- one candidate per step;
-* candidate **ids** ride in scalar-prefetch memory (SMEM), and the aligned
-  row tile holding the candidate for step ``(i, c)`` is DMA'd HBM->VMEM by
-  the BlockSpec index map ``ids[i, c] // TR`` itself (the block-sparse
-  scalar-prefetch idiom), so Pallas double-buffers the gather against the
-  distance math of the previous step;
-* the masked L^p distance and a running top-k (replace-worst-if-better,
-  provably exact for "k smallest seen so far") live in VMEM scratch;
-* the epilogue selection-sorts the k best and writes ``(nq, k)`` ids +
-  distances -- the only HBM traffic besides the row gathers themselves.
+**Block kernel** (the served path: a segment's 1,024 rows; any database
+whose rows, widened to f32, fit ``_RESIDENT_BYTES`` of VMEM):
 
-TPU block rule: a block's last two dims must be multiples of the native
-tile or equal the array's.  So the gather moves a ``(TR, N)`` tile -- TR
-rows of one native tile height for the db dtype (8 for f32, 16 for bf16,
-32 for int8) -- and the candidate's row is selected inside the kernel;
-queries and outputs travel as ``(nq, 1, N)`` / ``(nq, 1, k)`` so their
-row blocks equal the array in the last two dims.
+* the grid is ``(nq, ceil(C / B))`` -- a block of ``B`` candidates per
+  step (``_block``: 128 at the served C = 1,024; the wrapper pads the ids
+  with -1 to whole blocks, which at C = 1,024 pads nothing);
+* the rows come in as one VMEM block, widened to f32 once a call beside a
+  row of +inf;
+* candidate **ids** ride in scalar-prefetch memory (SMEM).  A scalar loop
+  reads each of the block's ids and copies that candidate's row, or the
+  +inf row where ``id < 0`` or ``id >= valid_items``, into a ``(B, N)``
+  block; the block's L^p distances are then computed at once;
+* the distances land, in candidate order, in an ``(nq, ceil(C / B), B)``
+  scratch; there is no per-candidate top-k update.  At a query's last
+  step the epilogue takes its k smallest by k rounds of min, the lowest
+  candidate position first among equal distances -- ``lax.top_k``'s
+  order, so the ids match the reference even on ties.  The kernel answers
+  candidate positions; the wrapper maps them to ids.
 
-Invalid candidates (id < 0, or id >= valid_items for partially-filled
-databases) are forced to +inf / id -1, matching ``ref.fused_query_topk_ref``
-bit-for-bit on ids when distances are distinct.
+**Tile kernel** (a larger database: a whole index queried at once,
+``query_index`` / ``query_distributed``).  Its cost does not grow with the
+database: the grid is ``(nq, C)``, one candidate per step, and the aligned
+native row tile holding it (8 f32 rows, 16 bf16, 32 int8) is DMA'd
+HBM->VMEM by the BlockSpec index map ``ids[i, c] // TR`` (the block-sparse
+scalar-prefetch idiom), so Pallas double-buffers the gather against the
+previous step's math.  A running top-k (replace the worst slot when
+better) lives in VMEM scratch and the epilogue selection-sorts it; among
+equal distances the order is the scratch's, not ``lax.top_k``'s.
 
-The db rows may be f32, bf16 or int8 (the quantized tier scores in code
-space through this same kernel; see ``quantize.quantized_query_topk``):
-the only dequant in the hot loop is an in-register widening cast.
+Why the block kernel does not gather by one DMA per candidate from HBM:
+Mosaic refuses a DMA of any slice of a row array whose lane width (N = 64
+on the served path) pads to 128, and XLA already stages a segment's rows
+in VMEM for the call, so the one copy in moves the bytes the per-row
+gathers would.  Why a larger database does not stream through VMEM a
+chunk at a time: every call would then read the whole database and score
+every candidate once per chunk, a cost that grows with the database where
+the tile kernel's does not.  Why there is no dense scan of the segment:
+scoring every row and masking by candidate is a different algorithm, not
+a faster gather, and ``chipbench/work/fused_query.py`` counts every
+candidate row once per query.
+
+The rows may be f32, bf16 or int8 (the quantized tier scores in code space
+through these same kernels; see ``quantize.quantized_query_topk``); the
+only dequant is the widening cast.  Queries and outputs travel as
+``(nq, 1, N)`` / ``(nq, 1, k)`` so their blocks equal the array in the
+last two dims.
 
 SMEM holds the (flattened) id table of one call; the wrapper splits the
 queries into calls whose table fits ``_SMEM_ID_BYTES`` (a v5e core has
@@ -48,12 +71,27 @@ from jax.experimental.pallas import tpu as pltpu
 
 Array = jax.Array
 
-_KP = 128  # top-k scratch width: lane-aligned; k <= _KP enforced by wrapper
+_KP = 128  # widest top-k either kernel selects; k <= _KP enforced by wrapper
 _SMEM_ID_BYTES = 512 * 1024  # id-table budget per call (half of v5e SMEM)
+_BLOCK = 128  # candidates per grid step at most: one lane row of the scratch
+_UNROLL = 8  # candidates per iteration of the gather loop
+_RESIDENT_BYTES = 2 << 20  # widened rows the block kernel holds: 4,096 at N=64
+
+
+def _resident(m: int, n: int) -> bool:
+    """Whether M rows of width N, widened to f32 and lane-padded, fit the
+    block kernel's VMEM budget; past it the tile kernel runs."""
+    return m * (-(-n // 128) * 128) * 4 <= _RESIDENT_BYTES
+
+
+def _block(c: int) -> int:
+    """Candidates per grid step for C candidates: ``_BLOCK``, or C rounded
+    up to a whole sublane group when fewer."""
+    return min(_BLOCK, -(-c // _UNROLL) * _UNROLL)
 
 
 def _lp_rows(diff: Array, p: float) -> Array:
-    """Row-wise L^p norm of a (TR, N) tile -> (TR, 1)."""
+    """Row-wise L^p norm of a (R, N) block -> (R, 1)."""
     if p == 2.0:
         return jnp.sqrt(jnp.sum(diff * diff, axis=1, keepdims=True))
     if p == 1.0:
@@ -61,9 +99,98 @@ def _lp_rows(diff: Array, p: float) -> Array:
     return jnp.sum(jnp.abs(diff) ** p, axis=1, keepdims=True) ** (1.0 / p)
 
 
-def _fused_query_kernel(ids_ref, q_ref, tile_ref, od_ref, oi_ref, dacc, iacc,
-                        *, k: int, p: float, valid: int, c_total: int,
-                        tr: int):
+def _fused_query_kernel(ids_ref, q_ref, db_ref, od_ref, oi_ref, wide, buf,
+                        dacc, *, k: int, p: float, valid: int, bsz: int):
+    i, j = pl.program_id(0), pl.program_id(1)
+    n_blk = pl.num_programs(1)
+    m = db_ref.shape[0]
+
+    @pl.when((i == 0) & (j == 0))
+    def _widen():
+        # the rows as f32, then a row of +inf that invalid candidates
+        # gather instead
+        wide[pl.ds(0, m), :] = db_ref[...].astype(jnp.float32)
+        wide[pl.ds(m, 8), :] = jnp.full((8, wide.shape[1]), jnp.inf,
+                                        jnp.float32)
+
+    base = (i * n_blk + j) * bsz
+
+    def gather(u, carry):
+        for v in range(_UNROLL):
+            t = u * _UNROLL + v
+            cid = ids_ref[base + t]
+            ok = (cid >= 0) & (cid < valid)
+            buf[pl.ds(t, 1), :] = wide[pl.ds(jnp.where(ok, cid, m), 1), :]
+        return carry
+
+    jax.lax.fori_loop(0, bsz // _UNROLL, gather, 0)
+
+    dr = _lp_rows(buf[...] - q_ref[0], p)                    # (B, 1)
+    # (B, 1) -> (1, B): each lane takes its own row, every other term 0
+    sub = jax.lax.broadcasted_iota(jnp.int32, (bsz, bsz), 0)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (bsz, bsz), 1)
+    dacc[i, pl.ds(j, 1), :] = jnp.sum(jnp.where(sub == lane, dr, 0.0),
+                                      axis=0, keepdims=True)
+
+    @pl.when(j == n_blk - 1)
+    def _epilogue():
+        # k rounds of min over every candidate; among equal distances the
+        # lowest candidate position first (k static => unrolled)
+        dv = dacc[i]                                         # (n_blk, B)
+        pos = (jax.lax.broadcasted_iota(jnp.int32, dv.shape, 0) * bsz
+               + jax.lax.broadcasted_iota(jnp.int32, dv.shape, 1))
+        big = jnp.int32(dv.size)
+        out_d, out_p = [], []
+        for _ in range(k):
+            dm = jnp.min(dv)
+            pm = jnp.min(jnp.where(dv == dm, pos, big))
+            out_d.append(dm)
+            out_p.append(jnp.where(jnp.isinf(dm), -1, pm))
+            dv = jnp.where(pos == pm, jnp.inf, dv)
+        od_ref[...] = jnp.stack(out_d).reshape(1, 1, k)
+        oi_ref[...] = jnp.stack(out_p).reshape(1, 1, k).astype(jnp.int32)
+
+
+def _block_call(q3: Array, db: Array, ids: Array, k: int, p: float,
+                valid: int, interpret: bool, *, bsz: int
+                ) -> tuple[Array, Array]:
+    """One block-kernel call; returns (dists, ids) -- the kernel itself
+    answers candidate positions, mapped back to ids here."""
+    nq, _, n = q3.shape
+    m = db.shape[0]
+    n_blk = ids.shape[1] // bsz
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(nq, n_blk),
+        in_specs=[
+            pl.BlockSpec((1, 1, n), lambda i, j, ids: (i, 0, 0)),
+            pl.BlockSpec((m, n), lambda i, j, ids: (0, 0)),
+        ],
+        out_specs=[
+            pl.BlockSpec((1, 1, k), lambda i, j, ids: (i, 0, 0)),
+            pl.BlockSpec((1, 1, k), lambda i, j, ids: (i, 0, 0)),
+        ],
+        scratch_shapes=[
+            pltpu.VMEM((m + 8, n), jnp.float32),      # widened rows + inf
+            pltpu.VMEM((bsz, n), jnp.float32),        # a block's rows
+            pltpu.VMEM((nq, n_blk, bsz), jnp.float32),  # every distance
+        ],
+    )
+    dists, pos = pl.pallas_call(
+        functools.partial(_fused_query_kernel, k=k, p=p, valid=valid,
+                          bsz=bsz),
+        grid_spec=grid_spec,
+        out_shape=(jax.ShapeDtypeStruct((nq, 1, k), jnp.float32),
+                   jax.ShapeDtypeStruct((nq, 1, k), jnp.int32)),
+        interpret=interpret,
+    )(ids.reshape(-1), q3, db)
+    pos = pos.reshape(nq, k)
+    out = jnp.take_along_axis(ids, jnp.maximum(pos, 0), axis=1)
+    return dists.reshape(nq, k), jnp.where(pos >= 0, out, -1)
+
+
+def _tile_kernel(ids_ref, q_ref, tile_ref, od_ref, oi_ref, dacc, iacc,
+                 *, k: int, p: float, valid: int, c_total: int, tr: int):
     i, c = pl.program_id(0), pl.program_id(1)
 
     @pl.when(c == 0)
@@ -104,8 +231,10 @@ def _fused_query_kernel(ids_ref, q_ref, tile_ref, od_ref, oi_ref, dacc, iacc,
         oi_ref[...] = jnp.stack(out_i).reshape(1, 1, k).astype(jnp.int32)
 
 
-def _call(q3: Array, db: Array, ids: Array, k: int, p: float, valid: int,
-          tr: int, interpret: bool) -> tuple[Array, Array]:
+def _tile_call(q3: Array, db: Array, ids: Array, k: int, p: float,
+               valid: int, interpret: bool, *, tr: int
+               ) -> tuple[Array, Array]:
+    """One tile-kernel call; returns (dists, ids)."""
     nq, _, n = q3.shape
     c = ids.shape[1]
     grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -127,8 +256,8 @@ def _call(q3: Array, db: Array, ids: Array, k: int, p: float, valid: int,
         ],
     )
     dists, out_ids = pl.pallas_call(
-        functools.partial(_fused_query_kernel, k=k, p=p, valid=valid,
-                          c_total=c, tr=tr),
+        functools.partial(_tile_kernel, k=k, p=p, valid=valid, c_total=c,
+                          tr=tr),
         grid_spec=grid_spec,
         out_shape=(jax.ShapeDtypeStruct((nq, 1, k), jnp.float32),
                    jax.ShapeDtypeStruct((nq, 1, k), jnp.int32)),
@@ -145,7 +274,9 @@ def fused_query_topk(q: Array, db: Array, ids: Array, k: int, p: float = 2.0,
     q: (nq, N) queries; db: (M, N) stored rows (f32, bf16 or int8); ids:
     (nq, C) int32 candidate ids, -1 = empty/deduped slot.  Returns (dists
     (nq, k) f32, ids (nq, k) int32) sorted ascending, id -1 / dist +inf
-    where fewer than k valid candidates exist.
+    where fewer than k valid candidates exist.  Among equal distances the
+    lower candidate position comes first when the rows fit the block
+    kernel (``_resident``).
     """
     nq, n = q.shape
     m, n2 = db.shape
@@ -153,16 +284,23 @@ def fused_query_topk(q: Array, db: Array, ids: Array, k: int, p: float = 2.0,
     assert n == n2 and ids.shape == (nq, c)
     assert k <= c, f"k={k} exceeds candidate count C={c}"
     assert k <= _KP, f"k={k} exceeds kernel top-k width {_KP}"
-    valid = m if valid_items is None else int(valid_items)
+    valid = m if valid_items is None else min(int(valid_items), m)
 
-    tr = 32 // db.dtype.itemsize       # one native tile of rows
-    if m % tr:                         # whole tiles only; pad rows are
-        db = jnp.pad(db, ((0, -m % tr), (0, 0)))   # never selected
-    q3 = q.astype(jnp.float32).reshape(nq, 1, n)
     ids = ids.astype(jnp.int32)
-    step = max(1, _SMEM_ID_BYTES // (4 * c))
-    parts = [_call(q3[s:s + step], db, ids[s:s + step], k, p, valid, tr,
-                   interpret) for s in range(0, nq, step)]
+    if _resident(m, n):
+        bsz = _block(c)
+        if c % bsz:                    # whole blocks; pads score +inf
+            ids = jnp.pad(ids, ((0, 0), (0, -c % bsz)), constant_values=-1)
+        call = functools.partial(_block_call, bsz=bsz)
+    else:
+        tr = 32 // db.dtype.itemsize   # one native tile of rows
+        if m % tr:                     # whole tiles only; pad rows are
+            db = jnp.pad(db, ((0, -m % tr), (0, 0)))   # never selected
+        call = functools.partial(_tile_call, tr=tr)
+    q3 = q.astype(jnp.float32).reshape(nq, 1, n)
+    step = max(1, _SMEM_ID_BYTES // (4 * ids.shape[1]))
+    parts = [call(q3[s:s + step], db, ids[s:s + step], k, p, valid,
+                  interpret) for s in range(0, nq, step)]
     if len(parts) == 1:
         return parts[0]
     return (jnp.concatenate([d for d, _ in parts]),

@@ -197,13 +197,15 @@ def _fused_query_impl(q, db, ids, k, p, valid_items, mode):
 def fused_query_topk(q, db, ids, k: int, p: float = 2.0,
                      valid_items: int | None = None,
                      backend: str | None = None):
-    """Fused gather + L^p re-rank + streaming top-k (the query hot path).
+    """Fused gather + L^p re-rank + top-k (the query hot path).
 
     Args:
         q: (nq, N) f32 queries.
-        db: (n_items, N) f32 stored embeddings (rows gathered HBM->VMEM by
-            a scalar-prefetch index map -- the (nq, C, N) candidate tensor
-            never exists in HBM).
+        db: (n_items, N) f32 stored embeddings (taken into VMEM whole
+            and each candidate's row gathered there, or past
+            ``fused_query._RESIDENT_BYTES`` one row tile DMA'd per
+            candidate -- the (nq, C, N) candidate tensor never exists in
+            HBM).
         ids: (nq, C) int32 candidate ids into ``db``; -1 = empty slot.
         k: results per query (static).
         p: L^p exponent (static).
@@ -213,9 +215,11 @@ def fused_query_topk(q, db, ids, k: int, p: float = 2.0,
 
     Returns:
         (dists (nq, k) f32 ascending, ids (nq, k) int32), -1/inf padded
-        where fewer than k valid candidates exist.
+        where fewer than k valid candidates exist; among equal distances
+        the lower candidate position first (``lax.top_k``'s order) when
+        the rows fit in VMEM.
 
-    The kernel's top-k scratch is ``fused_query._KP`` lanes wide; a larger
+    The kernel selects at most ``fused_query._KP`` results; a larger
     k raises in the kernel modes -- ask for ``backend="reference"`` (the
     memory-bound HBM-gather path) explicitly.
     """

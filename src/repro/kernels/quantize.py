@@ -19,12 +19,12 @@ is the exact ordering up to O(scale) distance ties -- which is why the
 serve layer treats the quantized top-m only as a *survivor set* and
 rescores it exactly from fp32 rows (:func:`rerank_survivors`).
 
-The Pallas path is the fused_query.py kernel itself, run on the codes:
-it gathers one candidate's native row tile per grid step through a
-scalar-prefetch index map, so the (nq, C, N) candidate tensor never
-exists in HBM.  A native tile is 32 bytes of row height at every
-precision (8 fp32 rows, 16 bf16, 32 int8), so per-step gather bytes match
-the fp32 path; the tier's win is capacity.
+The Pallas path is the fused_query.py kernels themselves, run on the
+codes: a segment's codes are taken into VMEM, widened to f32 once a call
+and a block of candidates' rows gathered per grid step (a database past
+VMEM gathers one native tile per candidate instead), so the (nq, C, N)
+candidate tensor never exists in HBM.  Past the one widening pass the
+work per candidate matches the fp32 path; the tier's win is capacity.
 """
 
 from __future__ import annotations
@@ -137,9 +137,9 @@ def quantized_query_topk(q: Array, codes: Array, scale: Array, ids: Array,
                          k: int, p: float = 2.0,
                          valid_items: int | None = None,
                          interpret: bool = True) -> tuple[Array, Array]:
-    """The fused_query kernel over a quantized db: scalar-prefetch tile
-    gather of int8/bf16 codes, code-space L^p, streaming top-k.  Distances
-    are scaled to the fp32 metric after the kernel.  Shapes/contract as
+    """The fused_query kernels over a quantized db: gather of int8/bf16
+    codes, code-space L^p, top-k.  Distances are scaled to the
+    fp32 metric after the kernel.  Shapes/contract as
     ``ops.fused_query_topk``.
     """
     qc, post = _code_query(q.astype(jnp.float32), codes.dtype, scale)

@@ -220,9 +220,8 @@ def _stacked_query_fn(cfg: IndexConfig, k: int, n_probes: int,
     count is traced, so headroom slots cost nothing and a seal into
     headroom reuses the program.  A slot's tables, gids and live mask are
     gathered from the stacks in place; its rows are sliced out, so the
-    query kernel gets the operands it gets per segment, rows XLA can stage
-    in fast memory for the kernel's per-step tile gathers (read from the
-    whole stack in HBM, the fp32 kernel ran 2.2x slower on a v5e).  Sealed
+    query kernel gets the operands it gets per segment: one segment's rows,
+    which it takes whole into VMEM and gathers its candidates from.  Sealed
     slots score in code space when ``quantized`` (int8/bf16 codes, one
     scale each); the delta always scores exactly.
 

@@ -92,6 +92,21 @@ def test_fused_query_topk_compiles(one_chip, nq, p):
     assert "tpu_custom_call" in txt
 
 
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.int8])
+def test_fused_query_topk_compiles_past_vmem(one_chip, dtype):
+    # a whole index's rows (query_index, query_distributed) are past the
+    # block kernel's VMEM budget: the tile kernel answers them
+    from repro.kernels import fused_query
+
+    m = 65536
+    assert not fused_query._resident(m, N)
+    fn = functools.partial(fused_query_topk, k=K, p=2.0, interpret=False)
+    txt = _compiled_text(fn, _spec(one_chip, (PALETTE[0], N)),
+                         _spec(one_chip, (m, N), dtype),
+                         _spec(one_chip, (PALETTE[0], C), jnp.int32))
+    assert "tpu_custom_call" in txt
+
+
 def test_fused_query_topk_compiles_past_one_smem_table(one_chip):
     # nq * C * 4 bytes > the per-call id-table budget: the wrapper splits
     # the queries into several kernel calls instead of overflowing SMEM
@@ -114,16 +129,9 @@ def test_quantized_query_topk_int8_compiles(one_chip, nq, k):
     assert "tpu_custom_call" in txt
 
 
-@pytest.mark.parametrize("precision,p,slots", [("fp32", 2.0, 510),
-                                               ("int8", 1.0, 255)])
-def test_stacked_fan_out_compiles(one_chip, precision, p, slots):
-    """The batch's one fan-out program over the stacked sealed segments
-    (255 slots: 2^18 items loaded; 510: grown once by a seal): the query
-    kernel runs once in the slot loop, on one segment's 2-D rows as per
-    segment, and once for the delta; the stacked tables are read in place,
-    neither copied nor sliced per slot."""
-    import re
-
+def _stacked_fan_out(sharding, precision, p, slots):
+    """The batch's one fan-out program over ``slots`` stacked sealed
+    segments, compiled for ``sharding``'s chip, and its top-k width."""
     from repro.core.index import IndexConfig, LSHIndexState
     from repro.serve import segments as segmod
 
@@ -135,22 +143,38 @@ def test_stacked_fan_out_compiles(one_chip, precision, p, slots):
 
     def state(*lead, db=jnp.float32):
         return LSHIndexState(
-            alpha=_spec(one_chip, lead + (N, lk)),
-            b=_spec(one_chip, lead + (lk,)),
-            mix=_spec(one_chip, lead + (tables, lk // tables), jnp.uint32),
-            table=_spec(one_chip, lead + (tables, buckets, 32), jnp.int32),
-            counts=_spec(one_chip, lead + (tables, buckets), jnp.int32),
-            db=_spec(one_chip, lead + (SEG, N), db))
+            alpha=_spec(sharding, lead + (N, lk)),
+            b=_spec(sharding, lead + (lk,)),
+            mix=_spec(sharding, lead + (tables, lk // tables), jnp.uint32),
+            table=_spec(sharding, lead + (tables, buckets, 32), jnp.int32),
+            counts=_spec(sharding, lead + (tables, buckets), jnp.int32),
+            db=_spec(sharding, lead + (SEG, N), db))
 
     fn = segmod._stacked_query_fn(cfg, width, 4, "compiled", quantized)
-    txt = fn.lower(
+    compiled = fn.lower(
         state(slots, db=jnp.int8 if quantized else jnp.float32),
-        _spec(one_chip, (slots, SEG), jnp.int32),
-        _spec(one_chip, (slots, SEG), jnp.bool_),
-        _spec(one_chip, (slots,)), _spec(one_chip, (), jnp.int32),
-        state(), _spec(one_chip, (SEG,), jnp.int32),
-        _spec(one_chip, (SEG,), jnp.bool_),
-        _spec(one_chip, (nq, N))).compile().as_text()
+        _spec(sharding, (slots, SEG), jnp.int32),
+        _spec(sharding, (slots, SEG), jnp.bool_),
+        _spec(sharding, (slots,)), _spec(sharding, (), jnp.int32),
+        state(), _spec(sharding, (SEG,), jnp.int32),
+        _spec(sharding, (SEG,), jnp.bool_),
+        _spec(sharding, (nq, N))).compile()
+    return compiled, width
+
+
+@pytest.mark.parametrize("precision,p,slots", [("fp32", 2.0, 510),
+                                               ("int8", 1.0, 255)])
+def test_stacked_fan_out_compiles(one_chip, precision, p, slots):
+    """The batch's one fan-out program over the stacked sealed segments
+    (255 slots: 2^18 items loaded; 510: grown once by a seal): the query
+    kernel runs once in the slot loop, on one segment's 2-D rows as per
+    segment, and once for the delta; the stacked tables are read in place,
+    neither copied nor sliced per slot."""
+    import re
+
+    nq, tables, buckets = PALETTE[0], 8, 1024
+    quantized = precision == "int8"
+    txt = _stacked_fan_out(one_chip, precision, p, slots)[0].as_text()
     calls = re.findall(
         r"%_(fused|quantized)_query_impl[.\d]* = .*operand_layout_"
         r"constraints=\{s32\[(\d+)\]\{0\}, f32\[(\d+),1,64\]\{2,1,0\}, "
@@ -164,3 +188,30 @@ def test_stacked_fan_out_compiles(one_chip, precision, p, slots):
                          r"copy\(", txt)
     assert not re.search(rf"s32\[(1,)?{tables},{buckets},32\]\{{[^}}]*\}} "
                          r"dynamic-slice\(", txt)
+
+
+@pytest.mark.parametrize("precision,p,slots", [("fp32", 2.0, 510),
+                                               ("int8", 1.0, 255)])
+def test_stacked_fan_out_kernel_calls_parse(one_chip, precision, p, slots):
+    """The roofline readers find the stacked program's query-kernel calls:
+    ``chipbench.work.fused_query.KERNEL`` parses each custom call's text
+    as a device trace names it (operands with their shapes) into the
+    shapes the work model counts."""
+    from jax._src.lib import xla_client
+
+    from chipbench.work import fused_query as work
+
+    compiled, width = _stacked_fan_out(one_chip, precision, p, slots)
+    opts = xla_client._xla.HloPrintOptions()
+    opts.print_operand_shape = True
+    opts.print_metadata = False
+    opts.print_backend_config = False
+    ops = [line.strip()
+           for module in compiled.runtime_executable().hlo_modules()
+           for line in module.to_string(opts).splitlines()
+           if "_query_impl" in line and "custom-call(" in line]
+    calls = work.calls([(op, 0, 1e6) for op in ops])
+    assert len(ops) == len(calls) == 2
+    stored = 1 if precision == "int8" else 4
+    assert sorted(c[:5] for c in calls) == sorted(
+        [(PALETTE[0], C, N, width, stored), (PALETTE[0], C, N, width, 4)])

@@ -59,24 +59,60 @@ def test_parity_with_valid_items_mask(rng_key):
     _assert_query_parity(state, cfg, q, 5, n_probes=2, valid_items=300)
 
 
-def test_fused_topk_op_unit(rng_key):
-    """ops.fused_query_topk on handcrafted ids: -1 slots, out-of-valid ids."""
-    nq, c, n, m = 4, 40, 24, 100
+# (C, M, k, valid_items, ids, duplicated rows): the block kernel scores
+# blocks of 128 candidates, so these cut C, the -1 runs and valid_items
+# across blocks; the last database is past the block kernel's VMEM budget,
+# so the tile kernel answers it
+_OP_CASES = {
+    "unit": (40, 100, 7, 60, "random", False),
+    "c_not_a_block_multiple": (300, 200, 10, None, "random", False),
+    "blocks_of_minus_one": (384, 200, 40, None, "empty_blocks", False),
+    "valid_items_mid_block": (256, 200, 1, 150, "dense", False),
+    "duplicate_rows_tie": (256, 200, 128, None, "dense", True),
+    "rows_past_vmem": (64, 4100, 10, 4090, "random", False),
+}
+
+
+@pytest.mark.parametrize("dtype", ["f32", "bf16", "int8"])
+@pytest.mark.parametrize("p", [1.0, 2.0])
+@pytest.mark.parametrize("case", sorted(_OP_CASES))
+def test_fused_topk_op_unit(rng_key, case, p, dtype):
+    """The query kernels on handcrafted ids against their jnp reference:
+    ids bit-identical (equal distances, in the block kernel: the lower
+    candidate position first, as ``lax.top_k``), distances to fp
+    tolerance.  Rows f32, or bf16 / int8 codes scored in code space."""
+    from repro.kernels import fused_query, quantize
+
+    c, m, k, valid, layout, dup = _OP_CASES[case]
+    nq, n = 4, 24
+    assert fused_query._resident(m, n) is (case != "rows_past_vmem")
     q = jax.random.normal(jax.random.fold_in(rng_key, 1), (nq, n))
     db = jax.random.normal(jax.random.fold_in(rng_key, 2), (m, n))
-    ids = jax.random.randint(jax.random.fold_in(rng_key, 3), (nq, c), -1, m)
-    for p in (1.0, 2.0):
-        for valid in (None, 60):
-            d_k, i_k = ops.fused_query_topk(q, db, ids, 7, p=p,
-                                            valid_items=valid,
-                                            backend="interpret")
-            d_r, i_r = ref.fused_query_topk_ref(q, db, ids, 7, p=p,
+    if dup:                          # rows 2r and 2r+1 equal: every id ties
+        db = db.at[1::2].set(db[0::2])
+    lo = -1 if layout == "random" else 0
+    ids = jax.random.randint(jax.random.fold_in(rng_key, 3), (nq, c), lo, m)
+    if layout == "empty_blocks":     # whole blocks of -1, and a row of them
+        ids = ids.at[:, :128].set(-1).at[:, 256:].set(-1).at[2].set(-1)
+    if dtype == "f32":
+        d_k, i_k = fused_query.fused_query_topk(q, db, ids, k, p=p,
                                                 valid_items=valid)
-            np.testing.assert_array_equal(np.asarray(i_k), np.asarray(i_r))
-            fin = np.isfinite(np.asarray(d_r))
-            np.testing.assert_allclose(np.asarray(d_k)[fin],
-                                       np.asarray(d_r)[fin],
-                                       atol=1e-5, rtol=1e-5)
+        d_r, i_r = ref.fused_query_topk_ref(q, db, ids, k, p=p,
+                                            valid_items=valid)
+    else:
+        codes, scale = quantize.encode(db, "int8" if dtype == "int8" else
+                                       "bf16")
+        d_k, i_k = quantize.quantized_query_topk(q, codes, scale, ids, k,
+                                                 p=p, valid_items=valid)
+        d_r, i_r = quantize.quantized_topk_ref(q, codes, scale, ids, k, p,
+                                               valid)
+    np.testing.assert_array_equal(np.asarray(i_k), np.asarray(i_r))
+    fin = np.isfinite(np.asarray(d_r))
+    assert (fin == np.isfinite(np.asarray(d_k))).all()
+    np.testing.assert_allclose(np.asarray(d_k)[fin], np.asarray(d_r)[fin],
+                               atol=1e-5, rtol=1e-5)
+    if layout == "empty_blocks":
+        assert (np.asarray(i_k)[2] == -1).all()
 
 
 def test_batched_query_matches_unbatched(rng_key):
