@@ -38,18 +38,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..kernels import dispatch, ops
+from ..kernels import dispatch, ops, ref
 from .hashes import PStableHash
 
 Array = jax.Array
 
 GOLDEN = np.uint32(0x9E3779B1)
-
-# Above this many scatter-table elements (nq * n_items) the exact dedup
-# falls back to the O(C log C) sort: the first-seen table costs
-# nq * n_items * 4 bytes of HBM (2**26 elements = 256 MB).
-DEDUP_SCATTER_MAX_ELEMS = 1 << 26
-
 
 @dataclasses.dataclass(frozen=True)
 class IndexConfig:
@@ -276,79 +270,39 @@ def probe_stage(mix: Array, cfg: IndexConfig, hashes: Array,
     return jnp.concatenate([base, pb], axis=-1)
 
 
-def _dedup_candidates(cands: Array, buckets: Array, cfg: IndexConfig,
-                      n_cap: int, live: Optional[Array] = None) -> Array:
-    """Mark duplicate candidate ids as -1 (first occurrence survives), and
-    with ``live`` ((n_cap,) bool) the tombstoned ones too.
+def _gather_candidates(table: Array, buckets: Array, cfg: IndexConfig,
+                       slot: Optional[Array] = None) -> Array:
+    """The probed buckets' slots as (nq, L*T*S) candidate ids, -1 =
+    empty, repeats and dead items included.
 
-    Replaces the old full sort of the (nq, C) id list (O(C log^2 C)
-    compare-exchange lanes on TPU) with two cheap passes:
-
-    1. *Bucket-local*: an item sits in exactly one bucket per table, so
-       within a table duplicates can only come from the same bucket being
-       probed twice (perturbed hash colliding with the base).  Comparing the
-       (L, T) probed bucket ids pairwise -- O(L*T^2), independent of S --
-       kills whole repeated buckets at once.
-    2. *Cross-table*: scatter-min each id's position into a (nq, n_cap)
-       first-seen table, keep a slot iff it scattered first.  O(C) work and
-       exact; falls back to the sort when the table itself (nq * n_cap)
-       would out-eat the memory it saves.  A dead item's entry starts
-       below every position, so none of its slots is kept: the tombstone
-       filter rides on the same gather instead of one of its own.
-    """
-    nq, c = cands.shape
-    dup_b = (buckets[..., :, None] == buckets[..., None, :])         # (nq,L,T,T)
-    earlier = jnp.tril(jnp.ones(dup_b.shape[-2:], bool), k=-1)
-    dup_b = (dup_b & earlier).any(axis=-1)                           # (nq, L, T)
-    cands = jnp.where(dup_b[..., None], -1,
-                      cands.reshape(nq, cfg.n_tables, -1, cfg.bucket_capacity)
-                      ).reshape(nq, c)
-
-    if nq * n_cap > DEDUP_SCATTER_MAX_ELEMS:
-        cs = jnp.sort(cands, axis=-1)
-        dup = jnp.concatenate([jnp.zeros_like(cs[:, :1], dtype=bool),
-                               cs[:, 1:] == cs[:, :-1]], axis=-1)
-        cs = jnp.where(dup, -1, cs)
-        if live is not None:
-            cs = jnp.where((cs >= 0) & live[jnp.clip(cs, 0, n_cap - 1)],
-                           cs, -1)
-        return cs
-
-    rows = jnp.arange(nq)[:, None]
-    pos = jnp.arange(c, dtype=jnp.int32)
-    # -1 slots must not scatter: negative indices WRAP in jnp.at, so send
-    # them to n_cap where mode="drop" discards them.
-    scat = jnp.where(cands >= 0, cands, n_cap)
-    unseen = (jnp.full((n_cap,), c, jnp.int32) if live is None
-              else jnp.where(live, c, -1).astype(jnp.int32))
-    first = jnp.broadcast_to(unseen, (nq, n_cap)).at[rows, scat].min(
-        pos, mode="drop")
-    seen_at = jnp.take_along_axis(first, jnp.clip(cands, 0, n_cap - 1), axis=1)
-    keep = (cands >= 0) & (seen_at == pos)
-    return jnp.where(keep, cands, -1)
+    With ``slot``, ``table`` is a stack of segments (n_slots, L, B, S) and
+    the one at ``slot`` is read in place: the slot is one more coordinate
+    of the gather, so no segment's tables are sliced out (a slice would be
+    a copy)."""
+    nq = buckets.shape[0]
+    at = (jnp.arange(cfg.n_tables)[:, None, None], buckets.transpose(1, 0, 2))
+    if slot is not None:
+        at = (jnp.full(at[1].shape, slot, jnp.int32),) + at
+    cands = table[at]                                                # (L, nq, T, S)
+    return cands.transpose(1, 0, 2, 3).reshape(nq, -1)               # (nq, L*T*S)
 
 
 def gather_stage(table: Array, buckets: Array, cfg: IndexConfig,
                  n_cap: int, live_mask: Optional[Array] = None,
                  slot: Optional[Array] = None) -> Array:
     """Stage 3: gather bucket slots + dedup (+ optional tombstone filter):
-    (nq, L*T*S) candidate ids, -1 = empty/dup/dead.  The live filter sits
-    here (not in rerank), inside the dedup's first-seen table.
+    (nq, L*T*S) candidate ids, -1 = empty/dup/dead, from the first-seen
+    table of ``kernels.ref.dedup_candidates`` (the tombstones ride in it).
 
     With ``slot``, ``table`` (n_slots, L, B, S) and ``live_mask``
     (n_slots, n_cap) are stacks of segments and the one at ``slot`` is
-    read in place: the slot is one more coordinate of the table gather, so
-    no segment's tables are sliced out (a slice would be a copy); only its
-    (n_cap,) live row is."""
-    nq = buckets.shape[0]
-    at = (jnp.arange(cfg.n_tables)[:, None, None], buckets.transpose(1, 0, 2))
-    if slot is not None:
-        at = (jnp.full(at[1].shape, slot, jnp.int32),) + at
-    cands = table[at]                                                # (L, nq, T, S)
-    cands = cands.transpose(1, 0, 2, 3).reshape(nq, -1)              # (nq, L*T*S)
+    read in place (only its (n_cap,) live row is sliced out).  The serve
+    layer's :func:`segment_topk` skips this table: the query kernel drops
+    repeats and dead items in its own candidate walk."""
+    cands = _gather_candidates(table, buckets, cfg, slot)
     if live_mask is not None and slot is not None:
         live_mask = live_mask[slot]                                  # (n_cap,)
-    return _dedup_candidates(cands, buckets, cfg, n_cap, live_mask)
+    return ref.dedup_candidates(cands, n_cap, live_mask)
 
 
 def probe_queries(family: Tuple[Array, Array, Array], cfg: IndexConfig,
@@ -367,8 +321,10 @@ def segment_topk(table: Array, db: Array, gids: Array, live: Array,
                  slot: Optional[Array] = None, scale: Optional[Array] = None,
                  backend: Optional[str] = None) -> Tuple[Array, Array]:
     """The serve layer's per-segment body, from probed ``buckets`` to the
-    segment's top-k: gather + dedup + tombstone filter (stage 3), the
-    query kernel over the candidates' rows ``db``, then slot -> global id.
+    segment's top-k: the table gather, the query kernel over the
+    candidates' rows ``db`` with the dedup and tombstone filter
+    (``dedup=True``: inside the block kernel's candidate walk on a
+    segment's rows, see ``ops.dedup_site``), then slot -> global id.
 
     Args:
         table, gids, live: one segment's (L, B, S) tables and (n_cap,)
@@ -393,14 +349,14 @@ def segment_topk(table: Array, db: Array, gids: Array, live: Array,
     ``core/distributed.py`` -- so their answers agree by construction.
     """
     n_cap = gids.shape[-1]
-    cands = gather_stage(table, buckets, cfg, n_cap, live_mask=live,
-                         slot=slot)
+    cands = _gather_candidates(table, buckets, cfg, slot)
+    if live is not None and slot is not None:
+        live = live[slot]                                            # (n_cap,)
+    kw = dict(p=cfg.p, backend=backend, live=live, dedup=True)
     if scale is None:
-        dist, ids = ops.fused_query_topk(q, db, cands, k, p=cfg.p,
-                                         backend=backend)
+        dist, ids = ops.fused_query_topk(q, db, cands, k, **kw)
     else:
-        dist, ids = ops.quantized_query_topk(q, db, scale, cands, k,
-                                             p=cfg.p, backend=backend)
+        dist, ids = ops.quantized_query_topk(q, db, scale, cands, k, **kw)
     at = jnp.clip(ids, 0, n_cap - 1)
     g = (gids[at] if slot is None
          else gids[jnp.full(at.shape, slot, jnp.int32), at])

@@ -20,6 +20,14 @@ whose rows, widened to f32, fit ``_RESIDENT_BYTES`` of VMEM):
   reads each of the block's ids and copies that candidate's row, or the
   +inf row where ``id < 0`` or ``id >= valid_items``, into a ``(B, N)``
   block; the block's L^p distances are then computed at once;
+* with ``dedup`` the same walk drops repeated and dead candidates: an
+  SMEM table ``seen`` of the M rows, filled at the call's first step
+  from the live mask (a fourth operand, after the rows: -1 live, nq
+  dead), holds the last query that took each row, and query i takes a
+  row iff ``seen[id] < i``.  The grid runs (i, j) in order and a block's
+  candidates in order, so each id is kept at its first position -- the
+  survivors of ``ref.dedup_candidates``' first-seen table, which the
+  tile kernel takes its ids through instead;
 * the distances land, in candidate order, in an ``(nq, ceil(C / B), B)``
   scratch; there is no per-candidate top-k update.  At a query's last
   step the epilogue takes its k smallest by k rounds of min, the lowest
@@ -69,6 +77,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from . import ref
+
 Array = jax.Array
 
 _KP = 128  # widest top-k either kernel selects; k <= _KP enforced by wrapper
@@ -99,8 +109,12 @@ def _lp_rows(diff: Array, p: float) -> Array:
     return jnp.sum(jnp.abs(diff) ** p, axis=1, keepdims=True) ** (1.0 / p)
 
 
-def _fused_query_kernel(ids_ref, q_ref, db_ref, od_ref, oi_ref, wide, buf,
-                        dacc, *, k: int, p: float, valid: int, bsz: int):
+def _fused_query_kernel(ids_ref, q_ref, db_ref, *refs, k: int, p: float,
+                        valid: int, bsz: int, dedup: bool):
+    if dedup:
+        live_ref, od_ref, oi_ref, wide, buf, dacc, seen = refs
+    else:
+        od_ref, oi_ref, wide, buf, dacc = refs
     i, j = pl.program_id(0), pl.program_id(1)
     n_blk = pl.num_programs(1)
     m = db_ref.shape[0]
@@ -112,6 +126,20 @@ def _fused_query_kernel(ids_ref, q_ref, db_ref, od_ref, oi_ref, wide, buf,
         wide[pl.ds(0, m), :] = db_ref[...].astype(jnp.float32)
         wide[pl.ds(m, 8), :] = jnp.full((8, wide.shape[1]), jnp.inf,
                                         jnp.float32)
+        if dedup:
+            # the first-seen table: the last query that took each row, -1
+            # for none yet; a dead row reads nq, taken by every query
+            dead = pl.num_programs(0)
+
+            def fill(u, carry):
+                for v in range(_UNROLL):
+                    r = u * _UNROLL + v
+                    seen[r] = jnp.where(live_ref[r] != 0, -1, dead)
+                return carry
+
+            jax.lax.fori_loop(0, m // _UNROLL, fill, 0)
+            for r in range(m - m % _UNROLL, m):
+                seen[r] = jnp.where(live_ref[r] != 0, -1, dead)
 
     base = (i * n_blk + j) * bsz
 
@@ -120,6 +148,14 @@ def _fused_query_kernel(ids_ref, q_ref, db_ref, od_ref, oi_ref, wide, buf,
             t = u * _UNROLL + v
             cid = ids_ref[base + t]
             ok = (cid >= 0) & (cid < valid)
+            if dedup:
+                # the grid walks (i, j) in order and t rises in a block, so
+                # a query meets its candidates in position order: keep a
+                # row the first time this query meets it, if live
+                at = jnp.where(ok, cid, 0)
+                s = seen[at]
+                ok = ok & (s < i)
+                seen[at] = jnp.where(ok, i, s)
             buf[pl.ds(t, 1), :] = wide[pl.ds(jnp.where(ok, cid, m), 1), :]
         return carry
 
@@ -152,38 +188,47 @@ def _fused_query_kernel(ids_ref, q_ref, db_ref, od_ref, oi_ref, wide, buf,
 
 
 def _block_call(q3: Array, db: Array, ids: Array, k: int, p: float,
-                valid: int, interpret: bool, *, bsz: int
-                ) -> tuple[Array, Array]:
+                valid: int, interpret: bool, *, bsz: int,
+                live: Array | None) -> tuple[Array, Array]:
     """One block-kernel call; returns (dists, ids) -- the kernel itself
-    answers candidate positions, mapped back to ids here."""
+    answers candidate positions, mapped back to ids here.  With ``live``
+    ((M,) int32, 0 = dead) the kernel drops repeated and dead candidates
+    in its walk."""
     nq, _, n = q3.shape
     m = db.shape[0]
     n_blk = ids.shape[1] // bsz
+    in_specs = [
+        pl.BlockSpec((1, 1, n), lambda i, j, ids: (i, 0, 0)),
+        pl.BlockSpec((m, n), lambda i, j, ids: (0, 0)),
+    ]
+    scratch = [
+        pltpu.VMEM((m + 8, n), jnp.float32),          # widened rows + inf
+        pltpu.VMEM((bsz, n), jnp.float32),            # a block's rows
+        pltpu.VMEM((nq, n_blk, bsz), jnp.float32),    # every distance
+    ]
+    args = (ids.reshape(-1), q3, db)
+    if live is not None:       # after the rows: a trace reads the first three
+        in_specs.append(pl.BlockSpec(memory_space=pltpu.SMEM))
+        scratch.append(pltpu.SMEM((m,), jnp.int32))   # first-seen table
+        args += (live,)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(nq, n_blk),
-        in_specs=[
-            pl.BlockSpec((1, 1, n), lambda i, j, ids: (i, 0, 0)),
-            pl.BlockSpec((m, n), lambda i, j, ids: (0, 0)),
-        ],
+        in_specs=in_specs,
         out_specs=[
             pl.BlockSpec((1, 1, k), lambda i, j, ids: (i, 0, 0)),
             pl.BlockSpec((1, 1, k), lambda i, j, ids: (i, 0, 0)),
         ],
-        scratch_shapes=[
-            pltpu.VMEM((m + 8, n), jnp.float32),      # widened rows + inf
-            pltpu.VMEM((bsz, n), jnp.float32),        # a block's rows
-            pltpu.VMEM((nq, n_blk, bsz), jnp.float32),  # every distance
-        ],
+        scratch_shapes=scratch,
     )
     dists, pos = pl.pallas_call(
         functools.partial(_fused_query_kernel, k=k, p=p, valid=valid,
-                          bsz=bsz),
+                          bsz=bsz, dedup=live is not None),
         grid_spec=grid_spec,
         out_shape=(jax.ShapeDtypeStruct((nq, 1, k), jnp.float32),
                    jax.ShapeDtypeStruct((nq, 1, k), jnp.int32)),
         interpret=interpret,
-    )(ids.reshape(-1), q3, db)
+    )(*args)
     pos = pos.reshape(nq, k)
     out = jnp.take_along_axis(ids, jnp.maximum(pos, 0), axis=1)
     return dists.reshape(nq, k), jnp.where(pos >= 0, out, -1)
@@ -267,16 +312,23 @@ def _tile_call(q3: Array, db: Array, ids: Array, k: int, p: float,
 
 
 def fused_query_topk(q: Array, db: Array, ids: Array, k: int, p: float = 2.0,
-                     valid_items: int | None = None, interpret: bool = True
+                     valid_items: int | None = None, interpret: bool = True,
+                     *, live: Array | None = None, dedup: bool = False
                      ) -> tuple[Array, Array]:
     """Top-k nearest candidates without materializing (nq, C, N).
 
     q: (nq, N) queries; db: (M, N) stored rows (f32, bf16 or int8); ids:
-    (nq, C) int32 candidate ids, -1 = empty/deduped slot.  Returns (dists
-    (nq, k) f32, ids (nq, k) int32) sorted ascending, id -1 / dist +inf
-    where fewer than k valid candidates exist.  Among equal distances the
-    lower candidate position comes first when the rows fit the block
-    kernel (``_resident``).
+    (nq, C) int32 candidate ids, -1 = empty slot.  Returns (dists (nq, k)
+    f32, ids (nq, k) int32) sorted ascending, id -1 / dist +inf where
+    fewer than k valid candidates exist.  Among equal distances the lower
+    candidate position comes first when the rows fit the block kernel
+    (``_resident``).
+
+    ``dedup`` scores each id once per query, at its first position, and
+    with ``live`` ((M,) bool) none of the dead rows: the block kernel does
+    it in its candidate walk, the tile kernel takes ids already passed
+    through ``ref.dedup_candidates``.  Either way the survivors, and so
+    the answers, are the same.
     """
     nq, n = q.shape
     m, n2 = db.shape
@@ -284,6 +336,7 @@ def fused_query_topk(q: Array, db: Array, ids: Array, k: int, p: float = 2.0,
     assert n == n2 and ids.shape == (nq, c)
     assert k <= c, f"k={k} exceeds candidate count C={c}"
     assert k <= _KP, f"k={k} exceeds kernel top-k width {_KP}"
+    assert dedup or live is None, "a live mask is applied by the dedup"
     valid = m if valid_items is None else min(int(valid_items), m)
 
     ids = ids.astype(jnp.int32)
@@ -291,8 +344,13 @@ def fused_query_topk(q: Array, db: Array, ids: Array, k: int, p: float = 2.0,
         bsz = _block(c)
         if c % bsz:                    # whole blocks; pads score +inf
             ids = jnp.pad(ids, ((0, 0), (0, -c % bsz)), constant_values=-1)
-        call = functools.partial(_block_call, bsz=bsz)
+        if dedup:                      # in the kernel's walk
+            live = (jnp.ones((m,), jnp.int32) if live is None
+                    else live.astype(jnp.int32))
+        call = functools.partial(_block_call, bsz=bsz, live=live)
     else:
+        if dedup:                      # a first-seen table ahead of it
+            ids = ref.dedup_candidates(ids, m, live)
         tr = 32 // db.dtype.itemsize   # one native tile of rows
         if m % tr:                     # whole tiles only; pad rows are
             db = jnp.pad(db, ((0, -m % tr), (0, 0)))   # never selected

@@ -28,6 +28,7 @@ import jax.numpy as jnp
 from . import dispatch, merge as merge_kernel, quantize, ref
 from .dct_mm import dct_mm
 from .fused_query import _KP as _FUSED_TOPK_WIDTH
+from .fused_query import _resident as _fused_resident
 from .fused_query import fused_query_topk as _fused_query_kernel_call
 from .hash_mm import hash_mm
 from .rerank import rerank_distances
@@ -186,17 +187,33 @@ def _check_topk_width(op: str, k: int, mode: str) -> None:
             "the memory-bound jnp path")
 
 
-@functools.partial(jax.jit, static_argnames=("k", "p", "valid_items", "mode"))
-def _fused_query_impl(q, db, ids, k, p, valid_items, mode):
+@functools.partial(jax.jit,
+                   static_argnames=("k", "p", "valid_items", "mode", "dedup"))
+def _fused_query_impl(q, db, ids, live, k, p, valid_items, mode, dedup):
     if mode == "reference":
+        if dedup:
+            ids = ref.dedup_candidates(ids, db.shape[0], live)
         return ref.fused_query_topk_ref(q, db, ids, k, p, valid_items)
     return _fused_query_kernel_call(q, db, ids, k, p=p, valid_items=valid_items,
-                                    interpret=_interp(mode))
+                                    interpret=_interp(mode), live=live,
+                                    dedup=dedup)
+
+
+def dedup_site(m: int, n: int, backend: str | None = None) -> str:
+    """Where ``fused_query_topk`` / ``quantized_query_topk`` with
+    ``dedup=True`` drop repeated and dead candidates of an (m, n) database:
+    ``"kernel"``, inside the block kernel's candidate walk, or ``"table"``,
+    in a first-seen table ahead of the tile kernel or the reference path.
+    The same test of mode and shape the wrappers make."""
+    mode = dispatch.query_backend(backend)
+    return ("kernel" if mode != "reference" and _fused_resident(m, n)
+            else "table")
 
 
 def fused_query_topk(q, db, ids, k: int, p: float = 2.0,
                      valid_items: int | None = None,
-                     backend: str | None = None):
+                     backend: str | None = None, *, live=None,
+                     dedup: bool = False):
     """Fused gather + L^p re-rank + top-k (the query hot path).
 
     Args:
@@ -212,6 +229,9 @@ def fused_query_topk(q, db, ids, k: int, p: float = 2.0,
         valid_items: optionally mask ids >= this as invalid.
         backend: fused/reference/compiled/interpret
             (see ``dispatch.query_backend``).
+        dedup: score each id once per query, at its first position, and
+            with ``live`` ((n_items,) bool) no dead row; where it happens
+            is :func:`dedup_site`.
 
     Returns:
         (dists (nq, k) f32 ascending, ids (nq, k) int32), -1/inf padded
@@ -225,25 +245,32 @@ def fused_query_topk(q, db, ids, k: int, p: float = 2.0,
     """
     mode = dispatch.query_backend(backend)
     _check_topk_width("fused_query_topk", k, mode)
-    return _fused_query_impl(q, db, ids, k, p, valid_items, mode)
+    return _fused_query_impl(q, db, ids, live, k, p, valid_items, mode,
+                             dedup)
 
 
 # -- quantized candidate scoring (the precision tier's query tail) -----------
 
 
-@functools.partial(jax.jit, static_argnames=("k", "p", "valid_items", "mode"))
-def _quantized_query_impl(q, codes, scale, ids, k, p, valid_items, mode):
+@functools.partial(jax.jit,
+                   static_argnames=("k", "p", "valid_items", "mode", "dedup"))
+def _quantized_query_impl(q, codes, scale, ids, live, k, p, valid_items, mode,
+                          dedup):
     if mode == "reference":
+        if dedup:
+            ids = ref.dedup_candidates(ids, codes.shape[0], live)
         return quantize.quantized_topk_ref(q, codes, scale, ids, k, p,
                                            valid_items)
     return quantize.quantized_query_topk(q, codes, scale, ids, k, p=p,
                                          valid_items=valid_items,
-                                         interpret=_interp(mode))
+                                         interpret=_interp(mode), live=live,
+                                         dedup=dedup)
 
 
 def quantized_query_topk(q, codes, scale, ids, k: int, p: float = 2.0,
                          valid_items: int | None = None,
-                         backend: str | None = None):
+                         backend: str | None = None, *, live=None,
+                         dedup: bool = False):
     """:func:`fused_query_topk` over a quantized (int8/bf16) database.
 
     Args as :func:`fused_query_topk`, plus ``codes`` (n_items, N) int8 or
@@ -257,8 +284,8 @@ def quantized_query_topk(q, codes, scale, ids, k: int, p: float = 2.0,
     """
     mode = dispatch.query_backend(backend)
     _check_topk_width("quantized_query_topk", k, mode)
-    return _quantized_query_impl(q, codes, scale, ids, k, p, valid_items,
-                                 mode)
+    return _quantized_query_impl(q, codes, scale, ids, live, k, p,
+                                 valid_items, mode, dedup)
 
 
 # -- cross-segment top-k merge (the streaming serve layer's fan-in) ----------

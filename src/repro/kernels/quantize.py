@@ -136,15 +136,18 @@ def quantized_topk_ref(q: Array, codes: Array, scale: Array, ids: Array,
 def quantized_query_topk(q: Array, codes: Array, scale: Array, ids: Array,
                          k: int, p: float = 2.0,
                          valid_items: int | None = None,
-                         interpret: bool = True) -> tuple[Array, Array]:
+                         interpret: bool = True, *,
+                         live: Array | None = None, dedup: bool = False
+                         ) -> tuple[Array, Array]:
     """The fused_query kernels over a quantized db: gather of int8/bf16
     codes, code-space L^p, top-k.  Distances are scaled to the
-    fp32 metric after the kernel.  Shapes/contract as
-    ``ops.fused_query_topk``.
+    fp32 metric after the kernel.  Shapes/contract (``live``, ``dedup``
+    too) as ``ops.fused_query_topk``.
     """
     qc, post = _code_query(q.astype(jnp.float32), codes.dtype, scale)
     dists, out_ids = fused_query.fused_query_topk(
-        qc, codes, ids, k, p=p, valid_items=valid_items, interpret=interpret)
+        qc, codes, ids, k, p=p, valid_items=valid_items, interpret=interpret,
+        live=live, dedup=dedup)
     return dists * post, out_ids
 
 

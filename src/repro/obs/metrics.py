@@ -105,6 +105,11 @@ CATALOG: Dict[str, MetricSpec] = _catalog(
                "Unsharded query batches by fan-out path (stacked: one "
                "program over the stacked sealed segments; per_segment: one "
                "program per segment)", ("tenant", "path")),
+    MetricSpec("serve_fanout_dedup_total", "counter",
+               "Query batches by where repeated and tombstoned candidates "
+               "are dropped (kernel: in the query kernel's candidate walk; "
+               "table: in a first-seen table ahead of it)",
+               ("tenant", "where")),
     MetricSpec("serve_device_wins_total", "counter",
                "Merged top-k slots won per device (sharded serve)",
                ("tenant", "device"), required=True),

@@ -1118,8 +1118,9 @@ class SegmentedIndex:
         instead dispatch one program per live segment under the lock, and
         their two concatenates and merge after it.  Sealed quantized
         segments score in code space (``width`` is then the survivor
-        width); fp32 segments score exactly.  Unsharded batches count in
-        ``serve_fanout_batches_total{path}``.
+        width); fp32 segments score exactly.  Every batch counts in
+        ``serve_fanout_dedup_total{where}``, unsharded ones in
+        ``serve_fanout_batches_total{path}`` too.
         """
         tr = obs_trace.tracer()
         quantized = self.precision != "fp32"
@@ -1144,6 +1145,7 @@ class SegmentedIndex:
                             backend=self.backend,
                             active=None if plan is None else plan.active,
                             quantized=quantized)
+                    self._count_fanout(None)
                     return _Fanout(g, d, plan=plan)
                 if self.n_live == 0:
                     return None
@@ -1197,9 +1199,16 @@ class SegmentedIndex:
                 q.shape[0], len(seg_ids), width).sum(axis=(0, 2))
         return _Fanout(g, d, counts, seg_ids)
 
-    def _count_fanout(self, path: str) -> None:
-        obs_metrics.registry().inc("serve_fanout_batches_total",
-                                   tenant=self.tenant, path=path)
+    def _count_fanout(self, path: Optional[str]) -> None:
+        """Count a batch by where its segments dedup their candidates
+        and, unsharded, by its fan-out ``path``."""
+        reg = obs_metrics.registry()
+        reg.inc("serve_fanout_dedup_total", tenant=self.tenant,
+                where=ops.dedup_site(self.segment_capacity, self.cfg.n_dims,
+                                     self.backend))
+        if path is not None:
+            reg.inc("serve_fanout_batches_total", tenant=self.tenant,
+                    path=path)
 
     def _query_quantized(self, q: Array, k: int, n_probes: int
                          ) -> Tuple[Array, Array]:

@@ -16,6 +16,11 @@ def fanout_batches(tenant: str, path: str) -> float:
                                         tenant=tenant, path=path) or 0
 
 
+def fanout_dedups(tenant: str, where: str) -> float:
+    return obs_metrics.registry().value("serve_fanout_dedup_total",
+                                        tenant=tenant, where=where) or 0
+
+
 def both_paths(si, q, k=10, n_probes=4):
     """``(stacked, per_segment)`` answers to ``q``, each as host arrays;
     asserts each batch took its path exactly once."""

@@ -168,7 +168,9 @@ def test_stacked_fan_out_compiles(one_chip, precision, p, slots):
     """The batch's one fan-out program over the stacked sealed segments
     (255 slots: 2^18 items loaded; 510: grown once by a seal): the query
     kernel runs once in the slot loop, on one segment's 2-D rows as per
-    segment, and once for the delta; the stacked tables are read in place,
+    segment, and once for the delta, each with the segment's live row
+    after the rows (it drops repeated and dead candidates itself, so no
+    first-seen table is scattered); the stacked tables are read in place,
     neither copied nor sliced per slot."""
     import re
 
@@ -178,10 +180,12 @@ def test_stacked_fan_out_compiles(one_chip, precision, p, slots):
     calls = re.findall(
         r"%_(fused|quantized)_query_impl[.\d]* = .*operand_layout_"
         r"constraints=\{s32\[(\d+)\]\{0\}, f32\[(\d+),1,64\]\{2,1,0\}, "
-        r"(f32|s8)\[(\d+),64\]\{1,0\}\}", txt)
+        r"(f32|s8)\[(\d+),64\]\{1,0\}, s32\[(\d+)\]\{0\}\}", txt)
     assert txt.count("tpu_custom_call") == len(calls) == 2
-    for _, ids, q_rows, _, n_rows in calls:
-        assert (int(ids), int(q_rows), int(n_rows)) == (nq * C, nq, SEG)
+    for _, ids, q_rows, _, n_rows, n_live in calls:
+        assert (int(ids), int(q_rows), int(n_rows), int(n_live)) == (
+            nq * C, nq, SEG, SEG)
+    assert not re.search(rf"s32\[{nq * SEG}\]\{{[^}}]*\}} scatter\(", txt)
     assert {c[0] for c in calls} == (
         {"quantized", "fused"} if quantized else {"fused"})
     assert not re.search(rf"s32\[{slots},{tables},{buckets},32\]\{{[^}}]*\}} "

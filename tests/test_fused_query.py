@@ -115,6 +115,132 @@ def test_fused_topk_op_unit(rng_key, case, p, dtype):
         assert (np.asarray(i_k)[2] == -1).all()
 
 
+# In-walk dedup (``dedup=True``): (layout, M, valid_items, nq).  C = 8
+# tables x 32 slots, as a served segment's candidates come in table order.
+_DEDUP_CASES = {
+    "mixed": ("mixed", 200, None, 4),
+    "repeated_across_tables": ("tables", 200, None, 4),
+    "all_dead": ("all_dead", 200, None, 4),
+    "all_empty": ("all_empty", 200, None, 4),
+    "valid_items_lt_m": ("mixed", 200, 120, 4),
+    "several_smem_calls": ("mixed", 200, None, 8),
+}
+
+
+def _dedup_inputs(key, layout, m, nq, c=256, n=24):
+    """Queries, rows (rows 2r and 2r+1 equal, so every distance ties with
+    another id's), candidate ids with repeats and -1 slots, a live mask."""
+    q = jax.random.normal(jax.random.fold_in(key, 1), (nq, n))
+    db = jax.random.normal(jax.random.fold_in(key, 2), (m, n))
+    db = db.at[1::2].set(db[0::2])
+    ids = jax.random.randint(jax.random.fold_in(key, 3), (nq, c), -1, m // 2)
+    live = jax.random.uniform(jax.random.fold_in(key, 4), (m,)) >= 0.3
+    if layout == "tables":        # each table's 32 slots in every table
+        ids = jnp.tile(ids[:, :c // 8], (1, 8))
+    elif layout == "all_dead":
+        live = jnp.zeros((m,), bool)
+    elif layout == "all_empty":
+        ids = jnp.full((nq, c), -1, jnp.int32)
+    return q, db, ids, live
+
+
+def _assert_dedup_parity(q, db, ids, live, k, p, dtype, valid=None):
+    """The kernel's in-walk dedup against the first-seen table: ids equal
+    the reference top-k over ``ref.dedup_candidates``' survivors, and
+    distances equal, bit for bit, the kernel's over those survivors (the
+    path the table fed before); returns the ids."""
+    from repro.kernels import fused_query, quantize
+
+    m = db.shape[0]
+    kept = ref.dedup_candidates(ids, m, live)
+    if dtype == "f32":
+        d_k, i_k = fused_query.fused_query_topk(q, db, ids, k, p=p,
+                                                valid_items=valid, live=live,
+                                                dedup=True)
+        d_t, i_t = fused_query.fused_query_topk(q, db, kept, k, p=p,
+                                                valid_items=valid)
+        d_r, i_r = ref.fused_query_topk_ref(q, db, kept, k, p, valid)
+    else:
+        codes, scale = quantize.encode(db, dtype)
+        d_k, i_k = quantize.quantized_query_topk(q, codes, scale, ids, k,
+                                                 p=p, valid_items=valid,
+                                                 live=live, dedup=True)
+        d_t, i_t = quantize.quantized_query_topk(q, codes, scale, kept, k,
+                                                 p=p, valid_items=valid)
+        d_r, i_r = quantize.quantized_topk_ref(q, codes, scale, kept, k, p,
+                                               valid)
+    i_k, d_k = np.asarray(i_k), np.asarray(d_k)
+    np.testing.assert_array_equal(i_k, np.asarray(i_r))
+    np.testing.assert_array_equal(i_k, np.asarray(i_t))
+    np.testing.assert_array_equal(d_k, np.asarray(d_t))
+    fin = np.isfinite(np.asarray(d_r))
+    assert (fin == np.isfinite(d_k)).all()
+    np.testing.assert_allclose(d_k[fin], np.asarray(d_r)[fin], atol=1e-5,
+                               rtol=1e-5)
+    return i_k
+
+
+@pytest.mark.parametrize("dtype", ["f32", "bf16", "int8"])
+@pytest.mark.parametrize("p", [1.0, 2.0])
+@pytest.mark.parametrize("k", [10, 40])
+def test_block_kernel_dedup_matches_first_seen_table(rng_key, k, p, dtype):
+    """Repeats, 30% tombstones, -1 slots and tied distances: the block
+    kernel keeps exactly the first-seen table's survivors."""
+    q, db, ids, live = _dedup_inputs(rng_key, "mixed", 200, 4)
+    got = _assert_dedup_parity(q, db, ids, live, k, p, dtype)
+    dead = np.flatnonzero(~np.asarray(live))
+    assert (got >= 0).any() and not np.isin(got, dead).any()
+    for row in got:
+        real = row[row >= 0]
+        assert len(real) == len(set(real.tolist()))
+
+
+@pytest.mark.parametrize("dtype", ["f32", "bf16", "int8"])
+@pytest.mark.parametrize("case", sorted(_DEDUP_CASES))
+def test_block_kernel_dedup_layouts(rng_key, monkeypatch, case, dtype):
+    """Every id repeated across the 8 tables, every row dead, every slot
+    empty, ``valid_items < M``, and queries split over several kernel
+    calls (each call fills its own first-seen table)."""
+    from repro.kernels import fused_query
+
+    layout, m, valid, nq = _DEDUP_CASES[case]
+    q, db, ids, live = _dedup_inputs(rng_key, layout, m, nq)
+    assert fused_query._resident(m, q.shape[1])
+    if case == "several_smem_calls":   # three queries' ids a call
+        monkeypatch.setattr(fused_query, "_SMEM_ID_BYTES",
+                            3 * 4 * ids.shape[1])
+    got = _assert_dedup_parity(q, db, ids, live, 10, 2.0, dtype, valid)
+    if layout in ("all_dead", "all_empty"):
+        assert (got == -1).all()
+
+
+@pytest.mark.parametrize("where", ["tile", "reference"])
+def test_dedup_outside_the_block_kernel(rng_key, where):
+    """Rows past the block kernel's VMEM budget (the tile kernel) and the
+    reference backend dedup through the first-seen table ahead of the
+    scoring: the same answers as scoring its survivors."""
+    from repro.kernels import fused_query
+
+    m = 4100 if where == "tile" else 200
+    q, db, ids, live = _dedup_inputs(rng_key, "mixed", m, 4)
+    assert fused_query._resident(m, q.shape[1]) is (where != "tile")
+    backend = "interpret" if where == "tile" else "reference"
+    site = ops.dedup_site(m, q.shape[1], backend)
+    assert site == "table"
+    d, i = ops.fused_query_topk(q, db, ids, 10, backend=backend, live=live,
+                                dedup=True)
+    kept = ref.dedup_candidates(ids, m, live)
+    d_t, i_t = ops.fused_query_topk(q, db, kept, 10, backend=backend)
+    np.testing.assert_array_equal(np.asarray(i), np.asarray(i_t))
+    np.testing.assert_array_equal(np.asarray(d), np.asarray(d_t))
+    got = np.asarray(i)
+    assert not np.isin(got, np.flatnonzero(~np.asarray(live))).any()
+    for row in got:
+        real = row[row >= 0]
+        assert len(real) == len(set(real.tolist()))
+    assert ops.dedup_site(200, q.shape[1], "interpret") == "kernel"
+
+
 def test_batched_query_matches_unbatched(rng_key):
     cfg, db, state = _build(rng_key)
     q = jax.random.normal(jax.random.fold_in(rng_key, 3), (37, 32))

@@ -5,6 +5,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import functional, index as lidx, wasserstein
+from repro.kernels import ref
 
 
 def _build(key, n_db=1024, n_dims=32, **kw):
@@ -120,7 +121,7 @@ def test_dedup_drops_duplicates_and_tombstones_on_both_paths(rng_key,
 
     scatter = kept()
     assert kept(slot=jnp.int32(1)) == scatter
-    monkeypatch.setattr(lidx, "DEDUP_SCATTER_MAX_ELEMS", 0)
+    monkeypatch.setattr(ref, "DEDUP_SCATTER_MAX_ELEMS", 0)
     assert kept() == scatter
     raw = np.asarray(state.table)[np.arange(cfg.n_tables)[:, None, None],
                                   np.asarray(buckets).transpose(1, 0, 2)]

@@ -18,7 +18,7 @@ import numpy as np
 import pytest
 
 from _fanout_support import (assert_paths_agree, both_paths,
-                             fanout_batches, lifecycle_parity)
+                             fanout_batches, fanout_dedups, lifecycle_parity)
 from repro.core import index as lidx
 from repro.kernels import ops
 from repro.serve import (MicroBatcher, SegmentedIndex, ServableRegistry,
@@ -154,6 +154,32 @@ def test_one_fan_out_program_per_batch():
     sv.query(_data(8, seed=3), 10, n_probes=4)
     assert fanout_batches("one-program", "stacked") == before + 2
     assert fanout_batches("one-program", "per_segment") == 0
+
+
+def test_fanout_counts_where_candidates_are_deduped():
+    """A stacked batch over segments whose rows fit the block kernel
+    drops repeated and tombstoned candidates in the kernel's walk
+    (``where="kernel"``) and answers as an index on the reference
+    backend, whose first-seen table does it (``where="table"``)."""
+    data, q = _data(300, seed=1), _data(5, seed=2, scale=0.9)
+    out = {}
+    for backend, where in (("interpret", "kernel"), ("reference", "table")):
+        si = SegmentedIndex(_cfg(), segment_capacity=64, insert_chunk=32,
+                            seed=3, tenant=f"dedup-{where}", backend=backend)
+        gids = si.insert(data)
+        si.delete(gids[::7])
+        before = fanout_batches(si.tenant, "stacked")
+        out[where] = [np.asarray(x) for x in si.query(q, 10, n_probes=2)]
+        assert fanout_batches(si.tenant, "stacked") == before + 1
+        assert fanout_dedups(si.tenant, where) == 1
+        assert fanout_dedups(si.tenant, {"kernel": "table",
+                                         "table": "kernel"}[where]) == 0
+    (g_k, d_k), (g_t, d_t) = out["kernel"], out["table"]
+    np.testing.assert_array_equal(g_k, g_t)
+    np.testing.assert_allclose(d_k, d_t, rtol=1e-6)
+    assert (g_k >= 0).any() and not np.isin(g_k, gids[::7]).any()
+    for row in g_k:
+        assert len(set(row[row >= 0].tolist())) == int((row >= 0).sum())
 
 
 def test_fanout_telemetry_matches_per_segment_path():
